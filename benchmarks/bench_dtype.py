@@ -45,6 +45,7 @@ import jax
 import numpy as np
 
 from repro.core.plan import build_plan
+from repro.launch.mesh import make_mesh
 from repro.models.gcn import make_paper_model
 from repro.profile.bench import BenchSpec, run_specs
 from repro.profile.machine import (A100, TPU_V5E, V100, choose_dtype,
@@ -206,7 +207,7 @@ def _dtype_child(csv_out: str):
     params = m.init(jax.random.PRNGKey(0))
     ref = build_plan(g, m.cfg, spec.feature_len,
                      spec.num_classes).run_model(params, x)
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = make_mesh((8,), ("data",))
     kw = dict(mesh=mesh, num_shards=8, strategy="ring")
     d32 = build_plan(g, m.cfg, spec.feature_len, spec.num_classes, **kw)
     dbf = build_plan(g, m.cfg, spec.feature_len, spec.num_classes,
@@ -245,6 +246,7 @@ def _halo(ctx, _):
         out = Path(td) / "dtype_child.csv"
         env = dict(os.environ)
         env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+        env["JAX_PLATFORMS"] = "cpu"   # a virtual-CPU matrix: never the TPU
         env["PYTHONPATH"] = os.pathsep.join(
             [str(Path(__file__).resolve().parents[1] / "src"),
              str(Path(__file__).resolve().parents[1])])

@@ -45,6 +45,7 @@ import jax
 import numpy as np
 
 from repro.core.plan import build_plan
+from repro.launch.mesh import make_mesh
 from repro.models.gcn import make_paper_model
 from repro.profile.bench import BenchSpec, run_specs
 from repro.profile.machine import TPU_V5E
@@ -118,7 +119,7 @@ def _overlap_child(csv_out: str):
     ctx = BenchContext(bench=None, machine=TPU_V5E, dry=True)
 
     for kind, shape, names in PARTITIONS:
-        mesh = jax.make_mesh(shape, names)
+        mesh = make_mesh(shape, names)
         baselines = {}          # strategy -> overlap="none" output
         for strategy, overlap in CELLS:
             name = _cell_name(kind, shape, strategy, overlap)
@@ -176,6 +177,7 @@ def _overlap_matrix(ctx, _):
         out = Path(td) / "overlap_child.csv"
         env = dict(os.environ)
         env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+        env["JAX_PLATFORMS"] = "cpu"   # a virtual-CPU matrix: never the TPU
         env["PYTHONPATH"] = os.pathsep.join(
             [str(Path(__file__).resolve().parents[1] / "src"),
              str(Path(__file__).resolve().parents[1])])

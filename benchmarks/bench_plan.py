@@ -48,6 +48,7 @@ import numpy as np
 from repro.core.backend import interpret_for, platform
 from repro.core.plan import build_plan
 from repro.core.scheduler import AGGREGATE_FIRST, COMBINE_FIRST
+from repro.launch.mesh import make_mesh
 from repro.models.gcn import make_paper_model
 from repro.profile.bench import BenchSpec, run_specs
 from repro.profile.machine import TPU_V5E
@@ -247,7 +248,7 @@ def _partition_child(csv_out: str):
                      spec.num_classes).run_model(params, x)
     ctx = BenchContext(bench=None, machine=TPU_V5E, dry=True)
     for kind, shape, names, strategy, reorder in PARTITIONS:
-        mesh = jax.make_mesh(shape, names)
+        mesh = make_mesh(shape, names)
         plan = build_plan(g, m.cfg, spec.feature_len, spec.num_classes,
                           mesh=mesh, strategy=strategy, reorder=reorder)
         assert plan.partition_kind == kind, (plan.partition_kind, kind)
@@ -286,6 +287,7 @@ def _partitions(ctx, _):
         out = Path(td) / "partition_child.csv"
         env = dict(os.environ)
         env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+        env["JAX_PLATFORMS"] = "cpu"   # a virtual-CPU matrix: never the TPU
         env["PYTHONPATH"] = os.pathsep.join(
             [str(Path(__file__).resolve().parents[1] / "src"),
              str(Path(__file__).resolve().parents[1])])

@@ -61,6 +61,9 @@ def main() -> None:
     dry = "--dry-run" in argv
     argv = [a for a in argv if a != "--dry-run"]
 
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
+
     from benchmarks import (bench_agg_vs_pgr, bench_breakdown, bench_dedup,
                             bench_dtype, bench_feature_length,
                             bench_kernels, bench_ordering, bench_overlap,

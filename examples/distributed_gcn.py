@@ -27,6 +27,7 @@ from repro.core.distributed import halo_bytes, halo_bytes_2d  # noqa: E402
 from repro.core.plan import build_plan  # noqa: E402
 from repro.graph.datasets import (make_features, make_labels,  # noqa: E402
                                   make_synthetic_graph)
+from repro.launch.mesh import make_mesh  # noqa: E402
 from repro.models.gcn import PAPER_MODELS  # noqa: E402
 from repro.optim.compression import (compression_wire_bytes,  # noqa: E402
                                      init_residuals,
@@ -41,7 +42,7 @@ def main():
     x = x.at[:, :spec.num_classes].add(
         4.0 * jax.nn.one_hot(y, spec.num_classes))
 
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = make_mesh((8,), ("data",))
     cfg = dataclasses.replace(PAPER_MODELS["gcn"], hidden_dims=(16,))
     plan = build_plan(g, cfg, spec.feature_len, spec.num_classes,
                       mesh=mesh, num_shards=8, strategy="ring")
@@ -91,7 +92,7 @@ def main():
     # The multi-host shape: node axis across hosts (halo bytes / Q), the
     # feature axis across intra-host links (the combine reduce-scatter stays
     # local).
-    mesh2 = jax.make_mesh((4, 2), ("node", "feat"))
+    mesh2 = make_mesh((4, 2), ("node", "feat"))
     plan2 = build_plan(g, cfg, spec.feature_len, spec.num_classes,
                        mesh=mesh2, strategy="ring")
     hb1 = halo_bytes(plan.partition, 16)["min_halo_bytes"]

@@ -46,6 +46,7 @@ def _build_matrix():
     from repro.config import CORA, reduced_graph
     from repro.core.plan import build_plan
     from repro.graph.datasets import make_synthetic_graph
+    from repro.launch.mesh import make_mesh
     from repro.models.gcn import PAPER_MODELS
 
     spec = reduced_graph(CORA, 64, 16)
@@ -94,7 +95,7 @@ def _build_matrix():
         yield plan, {}
 
     # -- 1-D halo: strategy x overlap x dtype on an (8,) mesh
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = make_mesh((8,), ("data",))
     for overlap in OVERLAPS:
         for dtype in DTYPES:
             plan = build_plan(g, cfg, spec.feature_len, spec.num_classes,
@@ -105,7 +106,7 @@ def _build_matrix():
     yield plan, {}
 
     # -- 2-D node x feature partition on a (4, 2) mesh
-    mesh2 = jax.make_mesh((4, 2), ("node", "feat"))
+    mesh2 = make_mesh((4, 2), ("node", "feat"))
     for overlap in OVERLAPS:
         for dtype in DTYPES:
             plan = build_plan(g, cfg, spec.feature_len, spec.num_classes,

@@ -137,8 +137,8 @@ CONTENT_REQUIREMENTS = {
 }
 
 REQUIRED_FILES = {
-    ROOT / "README.md": ["Quickstart", "smoke.sh",
-                         "test_ctx_parallel_attention_sharded"],
+    ROOT / "README.md": ["Quickstart", "smoke.sh", "chip_smoke.py",
+                         "JAX_PLATFORMS=cpu"],
     ROOT / "docs" / "planner.md": ["decision table", "pallas-gpu",
                                    "partition_2d", "characterization.md",
                                    "plan.compile", "reorder",

@@ -59,13 +59,7 @@ cd "$(dirname "$0")/.."
 export PYTHONPATH="src${PYTHONPATH:+:$PYTHONPATH}"
 
 echo "== tier-1 tests =="
-# test_ctx_parallel_attention_sharded hits a known jax-0.4.x shard_map x
-# custom_vjp incompatibility (pre-existing since the seed; fails identically
-# there) -- deselected until the LM attention substrate gains a compat path.
-# Rationale documented in README.md "Known failure".
-python -m pytest -x -q \
-  --deselect tests/test_distributed.py::test_ctx_parallel_attention_sharded \
-  "$@"
+JAX_PLATFORMS=cpu python -m pytest -x -q "$@"
 
 echo "== planner + overlap + serving + dtype dry-run (backend x ordering x"
 echo "   fusion x reorder x partition; instrumented: one schema-validated"

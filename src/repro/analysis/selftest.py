@@ -81,14 +81,15 @@ def plant_collective_bytes() -> AnalysisReport:
     """
     import jax
     import jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
-    mesh = jax.make_mesh((1,), ("x",))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((1,), ("x",))
 
     def body(v):
         return jax.lax.ppermute(v, "x", [(0, 0)])
 
-    fn = shard_map(body, mesh=mesh, in_specs=P("x"), out_specs=P("x"))
+    fn = jax.shard_map(body, mesh=mesh, in_specs=P("x"), out_specs=P("x"),
+                       check_vma=False)
     x = jnp.ones((4, 8), jnp.float32)
     closed = jax.make_jaxpr(fn)(x)
     report = AnalysisReport()

@@ -178,6 +178,7 @@ def fused_gcn_layer(bg: BlockedGraph, x: jnp.ndarray, w: jnp.ndarray,
 
     x: (V, F_in) padded to block multiple internally.  w: (F_in, F_out).
     """
+    from repro.core.phases import _mm
     if is_pallas(backend):
         from repro.kernels import ops as kops
         out = kops.fused_agg_combine(bg.src, bg.dstl, bg.mask, x, w,
@@ -187,7 +188,7 @@ def fused_gcn_layer(bg: BlockedGraph, x: jnp.ndarray, w: jnp.ndarray,
             src, dstl, mask = blk
             rows = jnp.take(x, src, axis=0) * mask[:, None]      # gather
             agg = jax.ops.segment_sum(rows, dstl, num_segments=bg.tile_m)
-            out_blk = agg @ w                                     # fuse: GEMM now
+            out_blk = _mm(agg, w)                                 # fuse: GEMM now
             return carry, out_blk
         _, blocks = jax.lax.scan(body, 0, (bg.src, bg.dstl, bg.mask))
         out = blocks.reshape(bg.nblocks * bg.tile_m, w.shape[1])
@@ -196,8 +197,7 @@ def fused_gcn_layer(bg: BlockedGraph, x: jnp.ndarray, w: jnp.ndarray,
     # self contribution + mean normalization (linear, applied post-GEMM;
     # reciprocal-multiply keeps eager == compiled bitwise -- see
     # phases.aggregate).  The self matmul goes through phases._mm so bf16
-    # plan operands accumulate f32; f32 inputs take the identical `@`.
-    from repro.core.phases import _mm
+    # plan operands accumulate f32.
     if agg_op == "mean":
         assert in_deg is not None
         self_term = _mm(x[: bg.num_vertices], w)

@@ -58,7 +58,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.graph.partition import Partition2D, PartitionedGraph
@@ -108,7 +107,11 @@ def _hop_partial(buf, k, p, srcl, dstl, mskl, block, nsh):
     local_src = srcl - owner * block
     rows = jnp.take(buf, jnp.clip(local_src, 0, block - 1), axis=0)
     rows = rows * (mskl * sel)[:, None]
-    return jax.ops.segment_sum(rows, dstl, num_segments=block)
+    # the barrier pins each partial's rounding: the pipelined schedule's
+    # last partial sits outside the scan, where XLA would otherwise fuse
+    # it with the accumulate differently (a 1-ulp drift between schedules)
+    return jax.lax.optimization_barrier(
+        jax.ops.segment_sum(rows, dstl, num_segments=block))
 
 
 def _ring_local(x_loc, srcl, dstl, mskl, block, nsh, axis):
@@ -211,11 +214,11 @@ def aggregate_allgather(pg: PartitionedGraph, x: jnp.ndarray, mesh: Mesh,
                                block, pg.num_shards, axis)
         return out[None]
 
-    return shard_map(
+    return jax.shard_map(
         fn, mesh=mesh,
         in_specs=(P(axis, None), P(axis, None), P(axis, None), P(axis, None),
                   P(axis)),
-        out_specs=P(axis, None), check_rep=False,
+        out_specs=P(axis, None), check_vma=False,
     )(x.reshape(pg.num_shards, -1, x.shape[-1]), pg.src, pg.dst_local,
       pg.mask, pg.vtx_start).reshape(x.shape[0], x.shape[-1])
 
@@ -238,10 +241,10 @@ def aggregate_ring(pg: PartitionedGraph, x: jnp.ndarray, mesh: Mesh,
                     block, nsh, axis)
         return out[None]
 
-    return shard_map(
+    return jax.shard_map(
         fn, mesh=mesh,
         in_specs=(P(axis, None), P(axis, None), P(axis, None), P(axis, None)),
-        out_specs=P(axis, None), check_rep=False,
+        out_specs=P(axis, None), check_vma=False,
     )(x.reshape(nsh, -1, x.shape[-1]), pg.src, pg.dst_local,
       pg.mask).reshape(x.shape[0], x.shape[-1])
 
@@ -581,12 +584,12 @@ def distributed_gcn_layer_2d(p2: Partition2D, x, w, bias, in_deg,
         out = out + jax.lax.dynamic_slice(bp_, (qi * fb_out,), (fb_out,))
         return out.reshape(1, block, 1, fb_out)
 
-    out = shard_map(
+    out = jax.shard_map(
         fn, mesh=mesh,
         in_specs=(P(node_ax, None, feat_ax, None), P(node_ax, None),
                   P(node_ax, None), P(node_ax, None), P(node_ax, None, None),
                   P(None, None), P(None)),
-        out_specs=P(node_ax, None, feat_ax, None), check_rep=False,
+        out_specs=P(node_ax, None, feat_ax, None), check_vma=False,
     )(x.reshape(nsh, block, q_sh, fb_in), pg.src, pg.dst_local, pg.mask,
       rdeg.reshape(nsh, block, 1), wp, bp)
     out = out.reshape(nsh * block, q_sh * fb_out)

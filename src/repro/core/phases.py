@@ -35,13 +35,14 @@ AGGREGATORS = ("sum", "mean", "max")
 def _mm(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
     """Matmul that accumulates f32 for reduced-precision operands.
 
-    f32 x f32 stays the plain ``@`` (bitwise-identical to the pre-dtype
-    path -- the guard is what keeps f32 plans golden); anything narrower
+    f32 x f32 runs at ``"highest"`` precision: on a TPU the default would
+    round both operands to bf16 for one MXU pass, so an f32 plan would not
+    compute in f32 (on CPU the product is unchanged).  Anything narrower
     (bf16 plan operands) runs with ``preferred_element_type=float32`` so
     the MXU/tensor-core accumulator is full precision.
     """
     if a.dtype == jnp.float32 and b.dtype == jnp.float32:
-        return a @ b
+        return jnp.matmul(a, b, precision=jax.lax.Precision.HIGHEST)
     return jnp.matmul(a, b, preferred_element_type=jnp.float32)
 
 

@@ -608,6 +608,15 @@ class CompiledPlan:
         """How many times the callable has been traced (compiled)."""
         return self._num_traces
 
+    def lower(self, params, x):
+        """Ahead-of-time lowering of this static forward for ``(params,
+        x)`` -- ``.compile()`` on the result gives the executable a call
+        runs, for inspecting its HLO (``as_text()``) and its device memory
+        (``memory_analysis()``)."""
+        if self.dynamic:
+            raise ValueError("lower() covers static compiled plans")
+        return self._fn.lower(params, x)
+
     @staticmethod
     def _signature(params, *arrays):
         leaves, treedef = jax.tree_util.tree_flatten(params)
@@ -1056,6 +1065,15 @@ def _plan_layer(g: Graph, index: int, kind: str, dims: Tuple[int, ...], *,
         # a tile larger than the graph only pads; clamp to |V| rounded up,
         # keeping the tier's alignment (warp rows on GPU, sublanes on TPU)
         tile_m = max(align, min(tile_m, -(-g.num_vertices // align) * align))
+        if backend == PALLAS_TPU:
+            # the kernel's own working set against the VMEM limit it is
+            # compiled with, at 4-byte rows (a dedup layer gathers f32
+            # partials even in a bf16 plan); refuses fused=True where no
+            # tile fits
+            from repro.kernels.ops import fit_fused_tile_m, tpu_vmem_budget
+            tile_m = fit_fused_tile_m(tile_m, dims[0], dims[1], 4,
+                                      budget=tpu_vmem_budget(backend),
+                                      align=align)
         blocked = _blocked_for(g, tile_m)
     agg_layout = None
     if backend in (PALLAS_TPU, PALLAS_GPU):
@@ -1240,6 +1258,9 @@ def build_plan(g: Graph, cfg, in_dim: int, num_classes: int, *,
         ...     out = plan.run_model(params, x)         # (V, num_classes)
     """
     agg = cfg.aggregator
+    if mesh is not None:
+        from repro.launch.mesh import auto_axes
+        mesh = auto_axes(mesh)
     use_fused = cfg.fused if fused is None else bool(fused)
     req_order = cfg.ordering if ordering is None else ordering
     if machine is not None:

@@ -18,9 +18,10 @@ across all destination blocks -- the software realization of the paper's
 "degree- & length-aware replacement policy" (the hottest data, W, is made
 cache-permanent; DESIGN.md §2).
 
-VMEM per step (tile_m=128, tile_e=512, F_in<=4096, F_out=128, fp32):
-rows 8 MiB + W 2 MiB + acc 2 MiB + out 64 KiB -- fits the ~64 MiB half-VMEM
-budget used by ops.py's tile picker.
+The working set per grid step (edge slab, W, accumulator, one-hot) is what
+``kernels.ops.tpu_vmem_bytes`` models; the plan sizes ``tile_m``/``tile_e``
+against the same VMEM limit the caller passes here, and refuses
+``fused=True`` where no tile fits.
 """
 
 from __future__ import annotations
@@ -33,9 +34,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import compat
-
 from repro.core.backend import resolve_interpret
+from repro.kernels.seg_agg import _dot, _onehot, _reduce_chunk
 
 
 def _fused_kernel(seg_ref, mask_ref, rows_ref, w_ref, out_ref, acc_ref, *,
@@ -50,63 +50,62 @@ def _fused_kernel(seg_ref, mask_ref, rows_ref, w_ref, out_ref, acc_ref, *,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    seg = seg_ref[0, :]
-    mask = mask_ref[0, :]
-    rows = rows_ref[0]
-    row_ids = jax.lax.broadcasted_iota(jnp.int32, (tile_m, tile_e), 0)
-    onehot = jnp.where(row_ids == seg[None, :], mask[None, :], 0.0)
-    acc_ref[...] += jax.lax.dot(
-        onehot.astype(acc_dtype), rows.astype(acc_dtype),
-        preferred_element_type=acc_dtype)
+    acc_ref[...] += _reduce_chunk(_onehot(seg_ref, mask_ref, tile_m, tile_e),
+                                  rows_ref[0], acc_dtype)
 
     @pl.when(ei == n_e - 1)
     def _combine():
         # Phase fusion point: aggregate tile -> GEMM without leaving VMEM.
-        out_ref[0] = jax.lax.dot(
-            acc_ref[...], w_ref[...].astype(acc_dtype),
-            preferred_element_type=acc_dtype).astype(out_ref.dtype)
+        out_ref[0] = _dot(acc_ref[...], w_ref[...],
+                          acc_dtype).astype(out_ref.dtype)
 
 
 @functools.partial(jax.jit,
                    static_argnames=("tile_m", "tile_e", "interpret",
-                                    "acc_dtype"))
+                                    "acc_dtype", "vmem_limit_bytes"))
 def fused_agg_combine_blocked(rows: jnp.ndarray, seg_local: jnp.ndarray,
                               mask: jnp.ndarray, w: jnp.ndarray, *,
                               tile_m: int, tile_e: int = 512,
                               interpret: Optional[bool] = None,
-                              acc_dtype=jnp.float32) -> jnp.ndarray:
+                              acc_dtype=jnp.float32,
+                              vmem_limit_bytes: Optional[int] = None
+                              ) -> jnp.ndarray:
     """out[block b] = (sum_seg rows[b]) @ w, fused in VMEM.
 
     rows: (nblocks, emax, F_in) destination-block-grouped gathered rows.
-    seg_local/mask: (nblocks, emax).
+    seg_local/mask: (nblocks, 1, emax) (the ``kernels.ops`` edge layout).
     w: (F_in, F_out).
     interpret: None = auto-detect (core.backend.default_interpret).
     acc_dtype: static VMEM accumulator dtype; stays f32 for reduced (bf16)
     rows/W -- storage is reduced, the accumulate is not.
+    vmem_limit_bytes: scoped VMEM for one grid step (None = compiler
+    default).
     Returns (nblocks * tile_m, F_out) in w.dtype.
     """
     interpret = resolve_interpret(interpret)
     nblocks, emax, f_in = rows.shape
     f_out = w.shape[1]
     assert w.shape[0] == f_in, (w.shape, f_in)
+    assert seg_local.shape == mask.shape == (nblocks, 1, emax), \
+        (seg_local.shape, mask.shape, rows.shape)
     assert emax % tile_e == 0, (emax, tile_e)
-    grid = (nblocks, emax // tile_e)
 
     out = pl.pallas_call(
         functools.partial(_fused_kernel, tile_m=tile_m, tile_e=tile_e,
                           acc_dtype=acc_dtype),
-        grid=grid,
+        grid=(nblocks, emax // tile_e),
         in_specs=[
-            pl.BlockSpec((1, tile_e), lambda b, e: (b, e)),
-            pl.BlockSpec((1, tile_e), lambda b, e: (b, e)),
+            pl.BlockSpec((1, 1, tile_e), lambda b, e: (b, 0, e)),
+            pl.BlockSpec((1, 1, tile_e), lambda b, e: (b, 0, e)),
             pl.BlockSpec((1, tile_e, f_in), lambda b, e: (b, e, 0)),
             pl.BlockSpec((f_in, f_out), lambda b, e: (0, 0)),  # W: VMEM-pinned
         ],
         out_specs=pl.BlockSpec((1, tile_m, f_out), lambda b, e: (b, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((nblocks, tile_m, f_out), w.dtype),
         scratch_shapes=[pltpu.VMEM((tile_m, f_in), acc_dtype)],
-        compiler_params=compat.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=vmem_limit_bytes),
         interpret=interpret,
         name="fused_agg_combine",
     )(seg_local, mask, rows, w)

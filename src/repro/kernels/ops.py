@@ -9,8 +9,6 @@ override with ``REPRO_PALLAS_INTERPRET=0/1``).
 
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -44,20 +42,151 @@ SEG_AGG_REMEDIATION = (
 
 
 # ---------------------------------------------------------------------------
+# The kernels' edge layout and VMEM budget (owned here, nowhere else)
+# ---------------------------------------------------------------------------
+
+#: widest edge chunk each TPU kernel streams per grid step.  Candidates are
+#: lane multiples, so a ``(1, 1, tile_e)`` seg/mask block is (8, 128)-legal.
+SEG_TILE_E_MAX = 512
+FUSED_TILE_E_MAX = 2048
+_LANE = 128
+
+#: GPU tier: the edge chunk shares the SM with ``A100.target_ctas`` peers,
+#: so it stays a small warp-aligned slab, not a VMEM-budgeted one.
+_GPU_TILE_E = 128
+
+
+def tpu_vmem_bytes(tile_m: int, tile_e: int, f_in: int, f_out: int = 0,
+                   itemsize: int = 4) -> int:
+    """VMEM working set of one grid step of the TPU aggregation kernels.
+
+    ``f_out=0`` is ``seg_agg`` (output as wide as the input); otherwise the
+    fused kernel with a ``(f_in, f_out)`` weight.  Counts what Mosaic
+    allocates: two buffers per blocked operand (edge slab, seg/mask padded
+    to 8 sublanes, W, output), the f32 accumulator scratch, and the
+    chunk's in-kernel temporaries (four ``(tile_m, tile_e)`` arrays for
+    iota, compare, one-hot and its cast; the f32 product; the f32 image
+    of a reduced edge slab).  Feature widths are padded to the 128-lane
+    tile.  Checked against the smallest ``vmem_limit_bytes`` a v5e compile
+    accepts (tests/test_tpu_compile.py): the model errs high.
+    """
+    fi = _round_up(f_in, _LANE)
+    fo = _round_up(f_out, _LANE) if f_out else fi
+    slab = tile_e * fi
+    total = 2 * slab * itemsize                # edge slab, double-buffered
+    total += 2 * 2 * 8 * tile_e * 4            # seg ids + mask blocks
+    total += 2 * tile_m * fo * itemsize        # output block
+    total += tile_m * fi * 4                   # accumulator scratch
+    total += 4 * tile_m * tile_e * 4           # one-hot temporaries
+    total += tile_m * fi * 4                   # chunk product
+    if itemsize != 4:
+        total += slab * 4                      # f32 image of the slab
+    if f_out:
+        total += 2 * fi * fo * itemsize        # W, pinned
+        total += tile_m * fo * 4               # combine product
+    return total
+
+
+def tpu_vmem_budget(backend: str = PALLAS_TPU) -> int:
+    """Scoped VMEM the TPU kernels request and their tiles are sized
+    against: the device's ``Machine.tile_budget()``."""
+    return machine_for_backend(backend).tile_budget()
+
+
+def pick_tile_e(tile_m: int, f_in: int, f_out: int = 0, itemsize: int = 4,
+                *, budget: int, cap: int = FUSED_TILE_E_MAX):
+    """Widest lane-multiple edge chunk (<= ``cap``) whose working set
+    (``tpu_vmem_bytes``) fits ``budget``; None when even 128 does not."""
+    tile_e = cap
+    while tile_e >= _LANE:
+        if tpu_vmem_bytes(tile_m, tile_e, f_in, f_out, itemsize) <= budget:
+            return tile_e
+        tile_e //= 2
+    return None
+
+
+def fit_fused_tile_m(tile_m: int, f_in: int, f_out: int, itemsize: int,
+                     *, budget: int, align: int = 8) -> int:
+    """Largest ``tile_m`` (the request, halved while needed, kept a
+    multiple of ``align``) at which some edge chunk of the fused kernel
+    fits ``budget``.  Raises ValueError where none does: the pinned W and
+    one 128-edge slab alone overflow VMEM, so ``fused=True`` cannot run."""
+    m = tile_m
+    while pick_tile_e(m, f_in, f_out, itemsize, budget=budget) is None:
+        if m <= align:
+            raise ValueError(
+                f"fused=True refused: no fused tile fits VMEM at F_in={f_in},"
+                f" F_out={f_out} with {itemsize}-byte operands (W alone "
+                f"takes {2 * f_in * f_out * itemsize} of the {budget}-byte "
+                "budget); plan this layer with fused=False")
+        m = max(align, (m // 2) // align * align)
+    return m
+
+
+def _tile_e(backend: str, tile_m: int, f_in: int, f_out: int,
+            itemsize: int, cap: int) -> int:
+    if backend == PALLAS_GPU:
+        return _GPU_TILE_E
+    tile_e = pick_tile_e(tile_m, f_in, f_out, itemsize,
+                         budget=tpu_vmem_budget(backend), cap=cap)
+    if tile_e is None:
+        raise ValueError(
+            f"no TPU aggregation tile fits VMEM: tile_m={tile_m}, "
+            f"F_in={f_in}, F_out={f_out or f_in}, {itemsize}-byte operands "
+            f"exceed the {tpu_vmem_budget(backend)}-byte budget even at "
+            f"tile_e={_LANE}")
+    return tile_e
+
+
+def kernel_edges(seg_local, mask, tile_e: int, *same_padding):
+    """The edge layout every aggregation kernel reads.
+
+    Pads the per-block edge axis of the ``(nblocks, emax)`` BlockedGraph
+    arrays to a ``tile_e`` multiple (pad slots carry mask 0) and lifts seg
+    ids and mask to ``(nblocks, 1, emax_p)``.  A ``(1, 1, tile_e)`` block
+    then ends in (full dim, lane multiple), which Mosaic accepts; a
+    ``(1, tile_e)`` block over ``(nblocks, emax)`` is refused.
+    ``same_padding`` arrays (source ids, pre-gathered rows) get the same
+    edge-axis padding and keep their rank.
+    """
+    nblocks, emax = seg_local.shape
+    emax_p = _round_up(emax, tile_e)
+
+    def pad(a):
+        if emax_p == emax:
+            return a
+        return jnp.pad(a, ((0, 0), (0, emax_p - emax))
+                       + ((0, 0),) * (a.ndim - 2))
+
+    return (pad(seg_local).reshape(nblocks, 1, emax_p),
+            pad(mask).reshape(nblocks, 1, emax_p),
+            *(pad(a) for a in same_padding))
+
+
+# ---------------------------------------------------------------------------
 # Segmented aggregation over a destination-sorted edge list
 # ---------------------------------------------------------------------------
 
 
-def _seg_agg_entry(backend: str):
-    """Pick the tier's blocked kernel (TPU sequential-grid vs GPU row-owned).
-    ``backend`` must already be resolved (the callers below resolve the
-    legacy "pallas" alias so entry and interpret mode can never disagree)."""
-    return seg_agg_gpu_blocked if backend == PALLAS_GPU else seg_agg_blocked
+def _seg_agg_call(backend: str, rows, seg_local, mask, tile_m: int):
+    """Lay out the edge operands and run the tier's blocked kernel (TPU
+    sequential-grid vs GPU row-owned).  ``backend`` must already be
+    resolved, so entry and interpret mode can never disagree."""
+    f = rows.shape[-1]
+    tile_e = _tile_e(backend, tile_m, f, 0, jnp.dtype(rows.dtype).itemsize,
+                     SEG_TILE_E_MAX)
+    seg3, mask3, rows = kernel_edges(seg_local, mask, tile_e, rows)
+    if backend == PALLAS_GPU:
+        return seg_agg_gpu_blocked(rows, seg3, mask3, tile_m=tile_m,
+                                   tile_e=tile_e,
+                                   interpret=interpret_for(backend))
+    return seg_agg_blocked(rows, seg3, mask3, tile_m=tile_m, tile_e=tile_e,
+                           interpret=interpret_for(backend),
+                           vmem_limit_bytes=tpu_vmem_budget(backend))
 
 
 def seg_agg(rows: jnp.ndarray, seg_ids: jnp.ndarray, num_segments: int,
-            tile_m: int = 128, tile_e: int = 512,
-            backend: str = PALLAS_TPU) -> jnp.ndarray:
+            tile_m: int = 128, backend: str = PALLAS_TPU) -> jnp.ndarray:
     """Drop-in segment_sum(rows, seg_ids) -- the SLOW ad-hoc fallback.
 
     Requires ``seg_ids`` sorted (destination-sorted edges -- the framework
@@ -75,8 +204,6 @@ def seg_agg(rows: jnp.ndarray, seg_ids: jnp.ndarray, num_segments: int,
     see core/backend.py).
     """
     backend = resolve_backend(backend)
-    if backend == PALLAS_GPU:
-        tile_e = min(tile_e, 128)  # SM-resident chunk, not a VMEM slab
     e, f = rows.shape
     if isinstance(seg_ids, jax.core.Tracer):
         raise ValueError(SEG_AGG_REMEDIATION)
@@ -85,35 +212,29 @@ def seg_agg(rows: jnp.ndarray, seg_ids: jnp.ndarray, num_segments: int,
     nblocks = _round_up(num_segments, tile_m) // tile_m
     blk = seg_np // tile_m
     counts = np.bincount(blk, minlength=nblocks)
-    emax = _round_up(max(int(counts.max()) if len(counts) else 1, 1), tile_e)
-    bs_rows = jnp.zeros((nblocks, emax, f), rows.dtype)
+    emax = max(int(counts.max()) if len(counts) else 1, 1)
     seg_l = np.zeros((nblocks, emax), np.int32)
     mask = np.zeros((nblocks, emax), np.float32)
     from repro.core.dataflow import block_offsets
     _, offs = block_offsets(blk, nblocks)
     seg_l[blk, offs] = seg_np - blk * tile_m
     mask[blk, offs] = 1.0
-    bs_rows = bs_rows.at[jnp.asarray(blk), jnp.asarray(offs)].set(rows)
-    out = _seg_agg_entry(backend)(
-        bs_rows, jnp.asarray(seg_l), jnp.asarray(mask),
-        tile_m=tile_m, tile_e=tile_e, interpret=interpret_for(backend))
+    bs_rows = jnp.zeros((nblocks, emax, f), rows.dtype).at[
+        jnp.asarray(blk), jnp.asarray(offs)].set(rows)
+    out = _seg_agg_call(backend, bs_rows, jnp.asarray(seg_l),
+                        jnp.asarray(mask), tile_m)
     return out[:num_segments]
 
 
 def seg_agg_pregrouped(rows_blocked, seg_local, mask, tile_m: int,
-                       tile_e: int = 512,
                        backend: str = PALLAS_TPU) -> jnp.ndarray:
-    """Kernel entry for already block-grouped inputs (BlockedGraph layout)."""
-    backend = resolve_backend(backend)
-    if backend == PALLAS_GPU:
-        tile_e = min(tile_e, 128)
-    return _seg_agg_entry(backend)(
-        rows_blocked, seg_local, mask, tile_m=tile_m, tile_e=tile_e,
-        interpret=interpret_for(backend))
+    """Kernel entry for already block-grouped inputs (BlockedGraph layout:
+    ``(nblocks, emax[, F])``)."""
+    return _seg_agg_call(resolve_backend(backend), rows_blocked, seg_local,
+                         mask, tile_m)
 
 
 def seg_agg_planned(bg, x: jnp.ndarray, edge_weight=None, *,
-                    tile_e: int = 512,
                     backend: str = PALLAS_TPU) -> jnp.ndarray:
     """Trace-pure segmented aggregation over a plan-owned blocked layout.
 
@@ -134,8 +255,6 @@ def seg_agg_planned(bg, x: jnp.ndarray, edge_weight=None, *,
     first-dim bound differs, never the kernel body.
     """
     backend = resolve_backend(backend)
-    if backend == PALLAS_GPU:
-        tile_e = min(tile_e, 128)
     nblocks, emax = bg.src.shape
     rows = jnp.take(x, bg.src.reshape(-1), axis=0).reshape(
         nblocks, emax, x.shape[-1])
@@ -146,16 +265,7 @@ def seg_agg_planned(bg, x: jnp.ndarray, edge_weight=None, *,
         w_blk = jnp.take(edge_weight, bg.eidx.reshape(-1),
                          axis=0).reshape(nblocks, emax)
         rows = rows * w_blk[..., None].astype(rows.dtype)
-    emax_p = _round_up(emax, tile_e)
-    seg_l, mask = bg.dstl, bg.mask
-    if emax_p != emax:
-        pad = ((0, 0), (0, emax_p - emax))
-        rows = jnp.pad(rows, pad + ((0, 0),))
-        seg_l = jnp.pad(seg_l, pad)
-        mask = jnp.pad(mask, pad)
-    out = _seg_agg_entry(backend)(
-        rows, seg_l, mask, tile_m=bg.tile_m, tile_e=tile_e,
-        interpret=interpret_for(backend))
+    out = _seg_agg_call(backend, rows, bg.dstl, bg.mask, bg.tile_m)
     return out[:bg.num_vertices]
 
 
@@ -165,48 +275,32 @@ def seg_agg_planned(bg, x: jnp.ndarray, edge_weight=None, *,
 
 
 def fused_agg_combine(src, dst_local, mask, x, w, *, tile_m: int,
-                      tile_e: int = 0,
                       backend: str = PALLAS_TPU) -> jnp.ndarray:
     """Gather x rows by ``src`` (XLA DMA gather), then fused reduce+GEMM.
 
     src/dst_local/mask: (nblocks, emax) BlockedGraph layout.
     x: (V, F_in); w: (F_in, F_out).  Returns (nblocks*tile_m, F_out).
     ``backend`` selects the kernel tier: "pallas-tpu" (sequential edge-chunk
-    grid + VMEM scratch) or "pallas-gpu" (one CTA per block, register
-    accumulator -- kernels/gpu_agg.py); "pallas"/"auto" resolve per platform.
+    grid + VMEM scratch, ``tile_e`` sized by ``pick_tile_e`` against the
+    same budget passed to the compiler) or "pallas-gpu" (one CTA per block,
+    register accumulator -- kernels/gpu_agg.py); "pallas"/"auto" resolve
+    per platform.
     """
     backend = resolve_backend(backend)
-    nblocks, emax = src.shape
+    nblocks = src.shape[0]
     f_in, f_out = w.shape
-    if tile_e == 0:
-        if backend == PALLAS_GPU:
-            # edge chunk shares the SM with A100.target_ctas peers;
-            # keep the (tile_e, F_in) slab small and warp-aligned
-            tile_e = 128
-        else:
-            # VMEM budget: rows chunk + W + acc within half VMEM
-            # (the TPU tier's Machine tile budget).  The streamed rows slab
-            # and W are sized at the INPUT element width (2 for bf16 plan
-            # operands -- wider edge chunks fit), the accumulator stays 4
-            # bytes (acc_dtype=f32 regardless of storage dtype).
-            elt = jnp.dtype(x.dtype).itemsize
-            budget = machine_for_backend(backend).tile_budget()
-            fixed = (f_in * f_out * elt
-                     + (tile_m * f_in + tile_m * f_out) * 4)
-            tile_e = max(256, min(2048,
-                                  (budget - fixed) // max(f_in * elt, 1)))
-            tile_e = max(256, (tile_e // 256) * 256)
-    emax_p = _round_up(emax, tile_e)
-    if emax_p != emax:
-        pad = ((0, 0), (0, emax_p - emax))
-        src = jnp.pad(src, pad)
-        dst_local = jnp.pad(dst_local, pad)
-        mask = jnp.pad(mask, pad)
-    rows = jnp.take(x, src.reshape(-1), axis=0).reshape(nblocks, emax_p, -1)
-    entry = (fused_agg_combine_gpu_blocked if backend == PALLAS_GPU
-             else fused_agg_combine_blocked)
-    return entry(rows, dst_local, mask, w, tile_m=tile_m, tile_e=tile_e,
-                 interpret=interpret_for(backend))
+    tile_e = _tile_e(backend, tile_m, f_in, f_out,
+                     jnp.dtype(x.dtype).itemsize, FUSED_TILE_E_MAX)
+    seg3, mask3, src = kernel_edges(dst_local, mask, tile_e, src)
+    rows = jnp.take(x, src.reshape(-1), axis=0).reshape(nblocks, -1, f_in)
+    if backend == PALLAS_GPU:
+        return fused_agg_combine_gpu_blocked(
+            rows, seg3, mask3, w, tile_m=tile_m, tile_e=tile_e,
+            interpret=interpret_for(backend))
+    return fused_agg_combine_blocked(
+        rows, seg3, mask3, w, tile_m=tile_m, tile_e=tile_e,
+        interpret=interpret_for(backend),
+        vmem_limit_bytes=tpu_vmem_budget(backend))
 
 
 # ---------------------------------------------------------------------------

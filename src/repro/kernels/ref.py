@@ -69,3 +69,44 @@ def mha_ref(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     p = jax.nn.softmax(logits, axis=-1)
     out = jnp.einsum("bhqk,bhkd->bhqd", p, vq.astype(jnp.float32))
     return out.astype(q.dtype)
+
+
+def gcn_forward_ref(src, dst, num_vertices: int, cfg, params,
+                    x: jnp.ndarray) -> jnp.ndarray:
+    """Plain float32 forward of a planned GCN-family model (the oracle a
+    ``GraphExecutionPlan`` is compared against on any device).
+
+    Every layer reduces over ``{N(v)} ∪ {v}`` with one dense segment sum
+    (``mean`` divides by in-degree + 1), then applies its combination:
+    ``{"lin": ...}`` is one affine map (GCN/SAGE), ``{"mlp1": ...,
+    "mlp2": ...}`` an MLP with ReLU between its matmuls (GIN).  ReLU joins
+    the layers.  Matmuls run at ``"highest"`` precision, so on a TPU the
+    result is f32-exact rather than one bf16 MXU pass.
+
+    src/dst: the (E,) edge list; ``params``: ``plan.init`` layout
+    (``{"conv<i>": ...}``); ``cfg``: the ``GCNModelConfig`` (its
+    ``aggregator`` and ``num_layers``).
+    """
+    if cfg.aggregator not in ("mean", "sum"):
+        raise ValueError(f"no reference for aggregator {cfg.aggregator!r}")
+    src, dst = jnp.asarray(src), jnp.asarray(dst)
+    with jax.default_matmul_precision("highest"):
+        h = jnp.asarray(x, jnp.float32)
+        deg = jax.ops.segment_sum(jnp.ones(dst.shape, jnp.float32), dst,
+                                  num_segments=num_vertices)
+        for i in range(cfg.num_layers):
+            p = params[f"conv{i}"]
+            agg = jax.ops.segment_sum(h[src], dst,
+                                      num_segments=num_vertices) + h
+            if cfg.aggregator == "mean":
+                agg = agg / (deg + 1.0)[:, None]  # analysis: allow(broadcast-div)
+            mlp = [p["lin"]] if "lin" in p else \
+                [p[f"mlp{j + 1}"] for j in range(len(p))]
+            h = agg
+            for j, lin in enumerate(mlp):
+                h = h @ lin["w"].astype(jnp.float32) + lin["b"]
+                if j < len(mlp) - 1:
+                    h = jax.nn.relu(h)
+            if i < cfg.num_layers - 1:
+                h = jax.nn.relu(h)
+    return h

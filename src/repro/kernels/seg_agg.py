@@ -20,8 +20,9 @@ Inputs are pre-gathered edge rows (the ``indexSelect`` product).  The gather
 itself is XLA's native dynamic-gather (DMA-based on TPU); what the paper's
 scatter kernel loses to atomics, this kernel recovers with dense MXU math.
 
-VMEM working set per grid step (defaults tile_m=128, tile_e=512, f=128,
-fp32): rows 256 KiB + onehot 256 KiB + acc 64 KiB << 128 MiB VMEM.
+The edge operands arrive in the layout ``kernels/ops.py`` owns (seg ids and
+mask as ``(nblocks, 1, emax)``), and the VMEM limit the caller passes is the
+budget ``ops.pick_tile_e`` sized ``tile_e`` against.
 """
 
 from __future__ import annotations
@@ -34,9 +35,34 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import compat
-
 from repro.core.backend import resolve_interpret
+
+
+def _dot(a, b, acc_dtype):
+    """MXU product at full f32 precision (one bf16 pass would round f32
+    operands), accumulated in ``acc_dtype``."""
+    return jax.lax.dot(a.astype(acc_dtype), b.astype(acc_dtype),
+                       precision=jax.lax.Precision.HIGHEST,
+                       preferred_element_type=acc_dtype)
+
+
+def _reduce_chunk(onehot, rows, acc_dtype):
+    """The segmented reduce of one edge chunk on the MXU.  A bf16 slab
+    takes one exact bf16 pass (the 0/1 one-hot is exact in bf16); an f32
+    slab needs full precision."""
+    if rows.dtype == jnp.bfloat16:
+        return jax.lax.dot(onehot.astype(jnp.bfloat16), rows,
+                           preferred_element_type=acc_dtype)
+    return _dot(onehot, rows, acc_dtype)
+
+
+def _onehot(seg_ref, mask_ref, tile_m: int, tile_e: int):
+    """(tile_m, tile_e) one-hot of the chunk's local destination rows;
+    masked (padding) edges contribute nothing."""
+    seg = seg_ref[0]              # (1, tile_e) int32, block-local row ids
+    mask = mask_ref[0]            # (1, tile_e) float32
+    row_ids = jax.lax.broadcasted_iota(jnp.int32, (tile_m, tile_e), 0)
+    return jnp.where(row_ids == seg, mask, 0.0)
 
 
 def _seg_agg_kernel(seg_ref, mask_ref, rows_ref, out_ref, acc_ref, *,
@@ -54,15 +80,8 @@ def _seg_agg_kernel(seg_ref, mask_ref, rows_ref, out_ref, acc_ref, *,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    seg = seg_ref[0, :]           # (tile_e,) int32, local row ids of dest block
-    mask = mask_ref[0, :]         # (tile_e,) float32
-    rows = rows_ref[0]            # (tile_e, F)
-    # one-hot: (tile_m, tile_e); rows with mask==0 contribute nothing
-    row_ids = jax.lax.broadcasted_iota(jnp.int32, (tile_m, tile_e), 0)
-    onehot = jnp.where(row_ids == seg[None, :], mask[None, :], 0.0)
-    acc_ref[...] += jax.lax.dot(
-        onehot.astype(acc_dtype), rows.astype(acc_dtype),
-        preferred_element_type=acc_dtype)
+    acc_ref[...] += _reduce_chunk(_onehot(seg_ref, mask_ref, tile_m, tile_e),
+                                  rows_ref[0], acc_dtype)
 
     @pl.when(ei == n_e - 1)
     def _flush():
@@ -70,58 +89,56 @@ def _seg_agg_kernel(seg_ref, mask_ref, rows_ref, out_ref, acc_ref, *,
 
 
 @functools.partial(jax.jit, static_argnames=("tile_m", "tile_e", "interpret",
-                                             "acc_dtype"))
+                                             "acc_dtype", "vmem_limit_bytes"))
 def seg_agg_blocked(rows: jnp.ndarray, seg_local: jnp.ndarray,
                     mask: jnp.ndarray, *, tile_m: int, tile_e: int = 512,
                     interpret: Optional[bool] = None,
-                    acc_dtype=jnp.float32) -> jnp.ndarray:
+                    acc_dtype=jnp.float32,
+                    vmem_limit_bytes: Optional[int] = None) -> jnp.ndarray:
     """Blocked segmented sum.
 
     Args:
       rows:      (nblocks, emax, F) pre-gathered edge rows, grouped by
                  destination block (see core.dataflow.block_graph).
-      seg_local: (nblocks, emax) int32 destination row id LOCAL to the block.
-      mask:      (nblocks, emax) 1/0 edge validity.
+      seg_local: (nblocks, 1, emax) int32 destination row id LOCAL to the
+                 block (the ``kernels.ops`` edge layout).
+      mask:      (nblocks, 1, emax) 1/0 edge validity.
       tile_m:    output rows per block (static).
-      tile_e:    edge chunk per grid step (static; emax must be a multiple).
+      tile_e:    edge chunk per grid step (static; a multiple of 128 that
+                 divides emax).
       interpret: None = auto (compiled on TPU, interpreted elsewhere --
                  core.backend.default_interpret).
       acc_dtype: VMEM accumulator dtype (static).  Stays f32 even when
                  ``rows`` is bf16 (the plan's reduced-precision contract:
                  reduced storage, full-precision accumulate); the output is
                  rounded once at flush to ``rows.dtype``.
+      vmem_limit_bytes: scoped VMEM the compiler may give one grid step
+                 (None = the compiler's default).
 
     Returns (nblocks * tile_m, F) in ``rows.dtype``.
     """
     interpret = resolve_interpret(interpret)
     nblocks, emax, f = rows.shape
+    assert seg_local.shape == mask.shape == (nblocks, 1, emax), \
+        (seg_local.shape, mask.shape, rows.shape)
     assert emax % tile_e == 0, (emax, tile_e)
-    n_e = emax // tile_e
-    grid = (nblocks, n_e)
 
     out = pl.pallas_call(
         functools.partial(_seg_agg_kernel, tile_m=tile_m, tile_e=tile_e,
                           acc_dtype=acc_dtype),
-        grid=grid,
+        grid=(nblocks, emax // tile_e),
         in_specs=[
-            pl.BlockSpec((1, tile_e), lambda b, e: (b, e)),       # seg ids
-            pl.BlockSpec((1, tile_e), lambda b, e: (b, e)),       # mask
+            pl.BlockSpec((1, 1, tile_e), lambda b, e: (b, 0, e)),  # seg ids
+            pl.BlockSpec((1, 1, tile_e), lambda b, e: (b, 0, e)),  # mask
             pl.BlockSpec((1, tile_e, f), lambda b, e: (b, e, 0)),  # rows
         ],
         out_specs=pl.BlockSpec((1, tile_m, f), lambda b, e: (b, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((nblocks, tile_m, f), rows.dtype),
         scratch_shapes=[pltpu.VMEM((tile_m, f), acc_dtype)],
-        compiler_params=compat.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=vmem_limit_bytes),
         interpret=interpret,
         name="seg_agg",
-    )(seg_local.reshape(nblocks, emax),
-      mask.reshape(nblocks, emax),
-      rows)
+    )(seg_local, mask, rows)
     return out.reshape(nblocks * tile_m, f)
-
-
-def _squeeze_kernel_wrapper():  # pragma: no cover - doc helper
-    """The (1, tile_e)/(1, tile_e, f) leading block dims arrive squeezed or
-    not depending on BlockSpec semantics; the kernel body indexes with [...]
-    and reshapes, so both layouts work."""

@@ -170,7 +170,6 @@ def _moe_sharded(params: Dict, x: jnp.ndarray, cfg: MoEConfig,
     alternative replicates the dispatch buffer (observed 0.5 TiB/device at
     kimi-k2 train_4k); this path wires the canonical a2a instead.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     bp = batch_axes if len(batch_axes) > 1 else (
@@ -193,7 +192,7 @@ def _moe_sharded(params: Dict, x: jnp.ndarray, cfg: MoEConfig,
     dense = params.get("dense", jnp.zeros((), x.dtype))
     dense_spec = jax.tree.map(lambda _: P(None, None), dense) \
         if cfg.dense_residual else P()
-    out, aux = shard_map(
+    out, aux = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(P(bp, sp, None),           # x: tokens sharded dp x seq
                   P(None, None),             # router (gathered)
@@ -202,7 +201,7 @@ def _moe_sharded(params: Dict, x: jnp.ndarray, cfg: MoEConfig,
                   P("model", None, None) if gated else P(),
                   dense_spec),
         out_specs=(P(bp, sp, None), P()),
-        check_rep=False,
+        check_vma=False,
     )(x, params["router"]["w"], params["wi"], params["wo"], wg, dense)
     return out, aux
 

@@ -186,15 +186,14 @@ def flash_attention_xla(q, k, v, *, causal: bool = True, window: int = 0,
                              qc, kc)
 
         from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
         bp = batch_axes if batch_axes else None
-        out = shard_map(
+        out = jax.shard_map(
             local_attn, mesh=mesh,
             in_specs=(P(bp, None, None, "model", None),
                       P(bp, None, None, None),
                       P(bp, None, None, None)),
             out_specs=P(bp, None, None, "model", None),
-            check_rep=False)(qg, k, v)
+            check_vma=False)(qg, k, v)
         return out.reshape(b, hq, sq, d)
 
     qc = min(q_chunk, sq)

@@ -59,7 +59,6 @@ def make_compressed_allreduce(mesh: Mesh, axis: str = "data"):
     usual DP layout after per-shard backward).  Used by the GCN distributed
     trainer; the pjit LM path keeps XLA-native reductions (documented).
     """
-    from jax.experimental.shard_map import shard_map
 
     def leaf_fn(g, r):
         return compressed_psum_leaf(g, r, axis)
@@ -70,8 +69,8 @@ def make_compressed_allreduce(mesh: Mesh, axis: str = "data"):
         outs_g, outs_r = [], []
         for g, r in zip(flat_g, flat_r):
             spec = P(*(None,) * g.ndim)
-            fn = shard_map(leaf_fn, mesh=mesh, in_specs=(spec, spec),
-                           out_specs=(spec, spec), check_rep=False)
+            fn = jax.shard_map(leaf_fn, mesh=mesh, in_specs=(spec, spec),
+                               out_specs=(spec, spec), check_vma=False)
             og, orr = fn(g, r)
             outs_g.append(og)
             outs_r.append(orr)

@@ -218,15 +218,36 @@ def get_machine(name_or_machine) -> Machine:
                          f"known: {sorted(MACHINES)}") from None
 
 
+#: ``device_kind`` (as JAX reports it) -> preset, for code running on a
+#: real TPU.  A TPU whose kind is missing here is an error, not a v5e.
+TPU_KINDS: Dict[str, Machine] = {"TPU v5 lite": TPU_V5E, "TPU v5": TPU_V5P}
+
+
+def tpu_machine(device_kind: str) -> Machine:
+    """The preset of a real TPU, keyed by its ``device_kind``."""
+    try:
+        return TPU_KINDS[device_kind]
+    except KeyError:
+        raise ValueError(f"no Machine preset for TPU kind {device_kind!r}; "
+                         f"known: {sorted(TPU_KINDS)}") from None
+
+
 def machine_for_backend(backend: Optional[str]) -> Machine:
     """Natural Machine preset for a resolved backend tier.
 
     ``pallas-gpu`` -> A100 (GPU occupancy math must never mix TPU balance
-    points -- the bug this replaces); everything else -> TPU_V5E, the repo's
-    default modeling target.  Callers wanting the paper's machine pass
-    ``V100`` explicitly.
+    points).  Every other tier on a real TPU -> that chip's preset by
+    ``device_kind`` (``tpu_machine``; an unknown kind raises); off-TPU ->
+    TPU_V5E, the repo's explicit modeling target.  Callers wanting the
+    paper's machine pass ``V100`` explicitly.
     """
-    return A100 if backend == "pallas-gpu" else TPU_V5E
+    if backend == "pallas-gpu":
+        return A100
+    import jax
+    device = jax.devices()[0]
+    if device.platform == "tpu":
+        return tpu_machine(device.device_kind)
+    return TPU_V5E
 
 
 # --------------------------------------------------------------------------

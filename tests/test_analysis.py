@@ -22,6 +22,7 @@ from repro.analysis.ast_lint import lint_source, lint_tree
 from repro.analysis.jaxpr_lint import lint_plan
 from repro.analysis.report import AnalysisReport, Finding
 from repro.analysis.selftest import PLANTS, check_suppression
+from test_distributed import child_env
 
 ROOT = Path(__file__).resolve().parents[1]
 SRC = str(ROOT / "src")
@@ -186,11 +187,11 @@ def run_sub(body: str):
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import warnings; warnings.filterwarnings("ignore")
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
     """) + textwrap.dedent(body)
     res = subprocess.run([sys.executable, "-c", prog], capture_output=True,
                          text=True,
-                         env={"PYTHONPATH": f"{SRC}:{TESTS}",
-                              "PATH": "/usr/bin:/bin", "HOME": "/root"},
+                         env=child_env(SRC, TESTS),
                          timeout=600)
     assert res.returncode == 0, f"subprocess failed:\n{res.stderr[-3000:]}"
     return res.stdout
@@ -209,8 +210,8 @@ def test_collective_bytes_match_workload_report_8dev():
         spec = reduced_graph(CORA, 64, 16)
         g = make_synthetic_graph(spec); x = make_features(spec)
         cfg = dataclasses.replace(PAPER_MODELS["gcn"], hidden_dims=(8,))
-        meshes = {"1d": jax.make_mesh((8,), ("data",)),
-                  "2d": jax.make_mesh((4, 2), ("node", "feat"))}
+        meshes = {"1d": make_mesh((8,), ("data",)),
+                  "2d": make_mesh((4, 2), ("node", "feat"))}
         for kind, mesh in meshes.items():
             for dtype in ("f32", "bf16"):
                 for overlap in ("none", "pipelined"):

@@ -23,6 +23,7 @@ from repro.core.plan import build_plan, plan_cache_stats
 from repro.graph.dedup import (build_dedup_layout, dedup_cost,
                                dedup_layout_for_graph, pad_dedup_arrays)
 from repro.graph.structure import graph_from_coo
+from repro.kernels.ref import gcn_forward_ref
 from repro.models.gcn import PAPER_MODELS
 
 
@@ -224,7 +225,7 @@ def test_dedup_is_a_cache_axis_and_described():
 
 def test_dynamic_compiled_dedup_roundtrip():
     """One dedup bucket plan serves same-shape blocks with runtime dedup
-    arrays -- bitwise against each block's own naive plan."""
+    arrays -- each block's result matches the float32 oracle."""
     cfg = dataclasses.replace(PAPER_MODELS["gcn"], hidden_dims=(16,))
     v = 128
     g_a, g_b = _hub_graph(v, 8, seed=1), _hub_graph(v, 8, seed=2)
@@ -239,11 +240,11 @@ def test_dynamic_compiled_dedup_roundtrip():
         ded = pad_dedup_arrays(lay, plan.dedup_layout.num_pairs,
                                plan.dedup_layout.num_edges2, sink=v - 1)
         out = fn(params, x, gb, dedup=ded)
-        ref = build_plan(gb, cfg, 8, 7, dedup="none").run_model(params, x)
+        ref = gcn_forward_ref(gb.src, gb.dst, v, cfg, params, x)
         # pad no-ops dump into the sink row (v-1): in bucketed use that is
         # a dedicated pad slot, but this synthetic graph makes it a real
-        # vertex, so exclude it -- every other row must be bitwise
-        assert_allclose_dtype(out[:-1], ref[:-1], bitwise=True)
+        # vertex, so exclude it -- every other row must match
+        assert_allclose_dtype(out[:-1], ref[:-1])
     assert fn.num_traces == 1              # both blocks, one trace
     with pytest.raises(ValueError):        # missing runtime arrays
         fn(params, x, g_b)

@@ -1,6 +1,7 @@
 """Multi-device tests: run in SUBPROCESSES with 8 fake CPU devices so the
 main pytest process keeps its single real device (per the dry-run rule)."""
 
+import os
 import subprocess
 import sys
 import textwrap
@@ -12,18 +13,27 @@ SRC = str(Path(__file__).resolve().parents[1] / "src")
 TESTS = str(Path(__file__).resolve().parent)  # tolerance.py for subprocesses
 
 
+def child_env(*pythonpath: str) -> dict:
+    """Environment of a virtual-device child: CPU only, so a child started
+    after the parent has touched JAX can never try to load the TPU."""
+    return {"PYTHONPATH": os.pathsep.join(pythonpath),
+            "PATH": os.environ.get("PATH", ""),
+            "HOME": os.environ.get("HOME", ""),
+            "JAX_PLATFORMS": "cpu"}
+
+
 def run_sub(body: str):
     prog = textwrap.dedent("""
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import warnings; warnings.filterwarnings("ignore")
         import jax, jax.numpy as jnp, numpy as np
-        from tolerance import assert_allclose_dtype
+        from repro.launch.mesh import make_mesh
+        from tolerance import assert_allclose_dtype, assert_matches_reference
     """) + textwrap.dedent(body)
     res = subprocess.run([sys.executable, "-c", prog], capture_output=True,
                          text=True,
-                         env={"PYTHONPATH": f"{SRC}:{TESTS}",
-                              "PATH": "/usr/bin:/bin", "HOME": "/root"},
+                         env=child_env(SRC, TESTS),
                          timeout=600)
     assert res.returncode == 0, f"subprocess failed:\n{res.stderr[-3000:]}"
     return res.stdout
@@ -38,7 +48,7 @@ def test_distributed_aggregation_strategies():
         from repro.core.distributed import (aggregate_allgather,
             aggregate_ring, pad_features)
         from repro.core.phases import aggregate
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
         spec = reduced_graph(CORA, 300, 32)
         g = make_synthetic_graph(spec); x = make_features(spec)
         pg = partition_1d(g, 8, edge_balanced=False)
@@ -71,7 +81,7 @@ def test_distributed_phase_ordering_halo_reduction():
         w = jnp.asarray(np.random.default_rng(0).standard_normal(
             (64, 16)) * 0.2, jnp.float32)
         b = jnp.zeros(16)
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
         ref = phase_ordered_layer(g, x, [(w, b)], order="combine_first",
                                   agg_op="mean", activation="none")
         with mesh:
@@ -102,7 +112,7 @@ def test_distributed_plan_matches_local():
         spec = reduced_graph(CORA, 300, 32)
         g = make_synthetic_graph(spec); x = make_features(spec)
         cfg = dataclasses.replace(PAPER_MODELS["gcn"], hidden_dims=(16,))
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
         local = build_plan(g, cfg, spec.feature_len, spec.num_classes)
         dist = build_plan(g, cfg, spec.feature_len, spec.num_classes,
                           mesh=mesh, num_shards=8, strategy="ring")
@@ -147,7 +157,7 @@ def test_distributed_2d_plan_matches_local():
         # smoke run -- keep this sweep inside run_sub's 600 s budget
         combos = [((4, 2), "ring"), ((4, 2), "allgather")]
         for shape, strat in combos:
-            mesh = jax.make_mesh(shape, ("node", "feat"))
+            mesh = make_mesh(shape, ("node", "feat"))
             for order in ("combine_first", "aggregate_first"):
                 plan = build_plan(g, cfg, spec.feature_len,
                                   spec.num_classes, mesh=mesh,
@@ -160,7 +170,7 @@ def test_distributed_2d_plan_matches_local():
                                       err_msg=f"{shape}/{strat}/{order}")
         # bare-layer entry: padded layout in, padded layout out
         p2 = partition_2d(g, 4, 2)
-        mesh = jax.make_mesh((4, 2), ("node", "feat"))
+        mesh = make_mesh((4, 2), ("node", "feat"))
         w = jnp.asarray(np.random.default_rng(0).standard_normal(
             (32, 16)) * 0.2, jnp.float32)
         b = jnp.zeros(16)
@@ -186,7 +196,7 @@ def test_compressed_allreduce_matches_mean():
         from jax.sharding import Mesh
         from repro.optim.compression import (make_compressed_allreduce,
             init_residuals)
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
         rng = np.random.default_rng(0)
         g = {"w": jnp.asarray(rng.standard_normal((8, 32)), jnp.float32)}
         res = init_residuals(g)
@@ -210,7 +220,7 @@ def test_ctx_parallel_attention_sharded():
     out = run_sub("""
         from repro.launch.sharding import sharding_rules, DEFAULT_RULES
         from repro.nn.attention import flash_attention_xla, direct_attention
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         rng = np.random.default_rng(0)
         q = jnp.asarray(rng.standard_normal((2, 14, 512, 32)), jnp.float32)
         k = jnp.asarray(rng.standard_normal((2, 2, 512, 32)), jnp.float32)
@@ -259,7 +269,7 @@ def test_sharded_lm_train_step_matches_single_device():
         batch = {"tokens": toks, "labels": toks}
         step = make_train_step(cfg, opt)
         s_ref, m_ref = jax.jit(step)(state, batch)
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         with mesh, sharding_rules(mesh, rules_for(cfg, mesh)):
             st_sh = jax.tree.map(lambda s: NamedSharding(mesh, s),
                                  state_pspecs(jax.eval_shape(

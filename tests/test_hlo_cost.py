@@ -65,19 +65,18 @@ def test_dynamic_slice_bytes_not_overcounted():
 
 
 def test_collective_bytes_from_sharded_program():
-    import os
     import subprocess
     import sys
     import textwrap
-    from pathlib import Path
-    src = str(Path(__file__).resolve().parents[1] / "src")
+    from test_distributed import SRC, child_env
     prog = textwrap.dedent("""
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import jax, jax.numpy as jnp
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.core.hlo_cost import analyze_hlo
-        mesh = jax.make_mesh((8,), ("d",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((8,), ("d",))
         def f(x):
             return jax.lax.with_sharding_constraint(
                 x.sum(0, keepdims=True), NamedSharding(mesh, P()))
@@ -90,8 +89,7 @@ def test_collective_bytes_from_sharded_program():
         print("COLL", hc.collective_bytes)
     """)
     res = subprocess.run([sys.executable, "-c", prog], capture_output=True,
-                         text=True, env={"PYTHONPATH": src, "HOME": "/root",
-                                          "PATH": "/usr/bin:/bin"},
+                         text=True, env=child_env(SRC),
                          timeout=600)  # 8 fake-device startup is slow on CI
     assert res.returncode == 0, res.stderr[-2000:]
     assert "COLL" in res.stdout

@@ -11,9 +11,9 @@ from tolerance import assert_allclose_dtype
 
 from repro.kernels import ops
 from repro.kernels.flash_attention import flash_attention as flash_pallas
-from repro.kernels.fused_agg_combine import fused_agg_combine_blocked
+from repro.kernels import fused_agg_combine as fused_mod
+from repro.kernels import seg_agg as seg_mod
 from repro.kernels.ref import fused_agg_combine_ref, mha_ref, seg_agg_ref
-from repro.kernels.seg_agg import seg_agg_blocked
 
 RNG = np.random.default_rng(42)
 
@@ -23,6 +23,20 @@ def _blocked_inputs(nblocks, emax, f, tile_m, dtype, density=0.8):
     seg = jnp.asarray(RNG.integers(0, tile_m, (nblocks, emax)), jnp.int32)
     mask = jnp.asarray(RNG.random((nblocks, emax)) < density, jnp.float32)
     return rows, seg, mask
+
+
+def seg_agg_blocked(rows, seg, mask, *, tile_m, tile_e):
+    """The kernel on (nblocks, emax) BlockedGraph arrays, laid out the way
+    every production caller lays them out (``ops.kernel_edges``)."""
+    seg3, mask3 = ops.kernel_edges(seg, mask, tile_e)
+    return seg_mod.seg_agg_blocked(rows, seg3, mask3, tile_m=tile_m,
+                                   tile_e=tile_e)
+
+
+def fused_agg_combine_blocked(rows, seg, mask, w, *, tile_m, tile_e):
+    seg3, mask3 = ops.kernel_edges(seg, mask, tile_e)
+    return fused_mod.fused_agg_combine_blocked(rows, seg3, mask3, w,
+                                               tile_m=tile_m, tile_e=tile_e)
 
 
 # ---------------------------------------------------------------- seg_agg

@@ -14,6 +14,7 @@ from repro.core.distributed import (OVERLAP_SAVING_THRESHOLD, choose_overlap,
 from repro.core.plan import build_plan
 from repro.graph.datasets import make_features, make_synthetic_graph
 from repro.graph.partition import partition_1d
+from repro.launch.mesh import make_mesh
 from repro.models.gcn import PAPER_MODELS
 from repro.profile.machine import TPU_V5E, TPU_V5P
 
@@ -95,7 +96,7 @@ def test_build_plan_overlap_validation(pg249):
     with pytest.raises(ValueError, match="overlap"):
         build_plan(g, cfg, spec.feature_len, spec.num_classes,
                    overlap="sometimes")
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     with pytest.raises(ValueError, match="requires strategy='ring'"):
         build_plan(g, cfg, spec.feature_len, spec.num_classes, mesh=mesh,
                    strategy="allgather", overlap="pipelined")
@@ -108,7 +109,7 @@ def test_build_plan_overlap_validation(pg249):
 def test_overlap_in_describe_and_cache_key(pg249):
     spec, g, _ = pg249
     cfg = PAPER_MODELS["gcn"]
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     kw = dict(mesh=mesh, num_shards=1, strategy="ring")
     p_none = build_plan(g, cfg, spec.feature_len, spec.num_classes,
                         overlap="none", **kw)
@@ -154,8 +155,8 @@ def test_overlapped_halo_bitwise_with_ragged_padding():
         local = build_plan(g, cfg, spec.feature_len, spec.num_classes)
         params = local.init(jax.random.PRNGKey(0))
         ref = np.asarray(local.run_model(params, x))
-        meshes = {"1d": jax.make_mesh((8,), ("data",)),
-                  "2d": jax.make_mesh((4, 2), ("node", "feat"))}
+        meshes = {"1d": make_mesh((8,), ("data",)),
+                  "2d": make_mesh((4, 2), ("node", "feat"))}
         for kind, mesh in meshes.items():
             outs = {}
             for ov in ("none", "pipelined"):
@@ -173,7 +174,10 @@ def test_overlapped_halo_bitwise_with_ragged_padding():
                     fn(params, x)
                     assert fn.num_traces == 1, (kind, ov)
                 eager = np.asarray(rep.output)
-                assert np.array_equal(comp, eager), (kind, ov)
+                for got in (comp, eager):
+                    assert_matches_reference(got, g, cfg, params, x,
+                                             scale=10,
+                                             err_msg=f"{kind}/{ov}")
                 outs[ov] = eager
                 exp = sum(r.exposed_collective_time for r in rep.records)
                 hid = sum(r.overlapped_collective_time

@@ -79,6 +79,16 @@ def test_machine_for_backend_mapping():
     assert default_machine("pallas-gpu") is A100
 
 
+def test_tpu_machine_keyed_by_device_kind():
+    """On a real TPU the preset comes from the chip's device_kind; a kind
+    with no preset is an error, never a silent v5e."""
+    from repro.profile.machine import TPU_V5P, tpu_machine
+    assert tpu_machine("TPU v5 lite") is TPU_V5E
+    assert tpu_machine("TPU v5") is TPU_V5P
+    with pytest.raises(ValueError, match="no Machine preset"):
+        tpu_machine("TPU v99")
+
+
 def test_deprecated_characterize_shims_removed():
     """The PR 4 'one release' constant shims are gone: Machine presets are
     the only copy of the hardware numbers."""
