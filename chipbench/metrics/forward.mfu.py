@@ -1,0 +1,13 @@
+"""Model FLOPs of the forwards completed in the traced window, over the
+window's length and the chip's bf16 peak, in percent: the whole step's
+share of the peak, host gaps included."""
+
+from chipbench import work
+
+
+def read(r):
+    s = r.summary
+    if s is None or s.window_s <= 0 or r.window_count <= 0:
+        return None
+    flops = work.forward_flops(r.config) * r.window_count
+    return 100.0 * flops / s.window_s / r.peak()["flops_per_s"]
