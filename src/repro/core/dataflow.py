@@ -49,6 +49,8 @@ class BlockedGraph(NamedTuple):
            slots point at edge 0 and are masked) -- lets traced per-edge
            data (edge weights) be regrouped into this layout with one
            gather, no host round-trip (kernels/ops.seg_agg_planned).
+    num_edges: real (unmasked) slots; ``nblocks·emax / num_edges`` is the
+           layout's padding waste (``kernels.ops.layout_counts``).
     """
 
     src: jnp.ndarray
@@ -57,6 +59,7 @@ class BlockedGraph(NamedTuple):
     tile_m: int
     num_vertices: int
     eidx: Optional[jnp.ndarray] = None
+    num_edges: int = 0
 
     @property
     def nblocks(self) -> int:
@@ -115,7 +118,7 @@ def block_graph_arrays(src: np.ndarray, dst: np.ndarray, num_vertices: int,
     bm[blk, offs] = 1.0
     be[blk, offs] = np.arange(len(src), dtype=np.int32)
     return BlockedGraph(jnp.asarray(bs), jnp.asarray(bd), jnp.asarray(bm),
-                        tile_m, v, jnp.asarray(be))
+                        tile_m, v, jnp.asarray(be), len(src))
 
 
 def suggest_tile_m(in_len: int, out_len: int, avg_deg: float,

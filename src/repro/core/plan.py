@@ -65,6 +65,7 @@ the distributed example) all dispatch through plans; none of them takes raw
 
 from __future__ import annotations
 
+import re
 from dataclasses import dataclass, replace
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -81,6 +82,7 @@ from repro.core.dataflow import (BlockedGraph, block_graph, fused_gcn_layer,
 from repro.core.scheduler import (AGGREGATE_FIRST, COMBINE_FIRST,
                                   choose_ordering, ordering_cost)
 from repro.graph.structure import Graph
+from repro.profile.spans import gauge, span
 
 # ---------------------------------------------------------------------------
 # Plan data model
@@ -481,7 +483,10 @@ class GraphExecutionPlan:
         qerr = 0.0
         if probe is not None and self.dtype != "f32":
             qerr = _quant_err(x, _reduce_in(x, self.dtype))
-        return _phase(probe, "distributed", thunk, lp=lp,
+        def halo():
+            with jax.named_scope("halo"):
+                return thunk()
+        return _phase(probe, "distributed", halo, lp=lp,
                       feature_len=agg_len, overlap=self.overlap,
                       quant_error=qerr)
 
@@ -521,14 +526,17 @@ class GraphExecutionPlan:
         "int8-agg" -- never "auto"),
         and ``compiled`` the trace-purity capability (``plan.compile()``
         works iff True -- always, for plans built by the public entry
-        points).  N.B. one-off Pallas aggregation on an UN-planned graph
+        points).  ``agg_edges`` / ``agg_gather_rows`` /
+        ``agg_kernel_slots`` / ``agg_gather_bytes`` count one forward's
+        Pallas aggregation layout (``agg_counts``; 0 on XLA layers).
+        N.B. one-off Pallas aggregation on an UN-planned graph
         (``kernels.ops.seg_agg`` without a layout) still pays host-side
         regrouping per call and cannot trace -- route repeated work
         through a plan.
         """
         out = []
         compiled_ok = self.compile_supported
-        for lp in self.layers:
+        for lp, counts in zip(self.layers, self.agg_counts()):
             oc = ordering_cost(self.g, lp.din, lp.dout, lp.order)
             out.append({
                 "layer": lp.index, "kind": lp.kind,
@@ -542,7 +550,40 @@ class GraphExecutionPlan:
                 "reorder": self.reorder, "compiled": compiled_ok,
                 "dedup": self.dedup,
                 "agg_bytes": oc.agg_bytes, "agg_flops": oc.agg_flops,
+                **{f"agg_{k}": v for k, v in counts.items()},
             })
+        return out
+
+    def agg_counts(self) -> List[Dict[str, int]]:
+        """Per layer, what one forward's Pallas aggregation moves
+        (``kernels.ops.layout_counts``: ``edges``, ``gather_rows``,
+        ``kernel_slots``, ``gather_bytes``) over the layout the layer
+        dispatches: the fused tile's blocking, the dedup level-2 blocking,
+        or the unfused ``agg_layout``.  All 0 on XLA and distributed
+        layers, which build no blocked layout."""
+        from repro.kernels.ops import layout_counts
+        dedup = self.dedup_layout
+        if dedup is not None and (dedup.num_pairs == 0
+                                  or dedup.blocked is None):
+            dedup = None
+        itemsize = 2 if self.dtype == "bf16" else 4
+        out = []
+        for lp in self.layers:
+            fused = lp.fused and lp.blocked is not None and \
+                _fused_agg_op(lp) is not None
+            bg = dedup.blocked if dedup is not None else (
+                lp.blocked if fused else lp.agg_layout)
+            if not is_pallas(lp.backend) or bg is None or self.distributed:
+                out.append(dict.fromkeys(
+                    ("edges", "gather_rows", "kernel_slots",
+                     "gather_bytes"), 0))
+                continue
+            width = lp.din if fused or lp.order == AGGREGATE_FIRST \
+                else lp.dout
+            # the dedup path gathers from an f32 [x ; partials] concat
+            out.append(layout_counts(
+                bg, width, 4 if dedup is not None else itemsize, lp.backend,
+                f_out=lp.dims[1] if fused else 0))
         return out
 
     def layer_costs(self, layer: int = 0) -> Dict:
@@ -579,6 +620,10 @@ class CompiledPlan:
 
         def fwd(params, x):
             self._num_traces += 1   # runs at TRACE time only
+            # the layout this executable reads, as gauges agg.<count>.l<i>
+            for i, counts in enumerate(plan.agg_counts()):
+                for k, v in counts.items():
+                    gauge(f"agg.{k}.l{i}", v)
             if layer is None:
                 return plan.run_model(params, x)
             return plan.run_layer(params, x, layer=layer)
@@ -616,6 +661,14 @@ class CompiledPlan:
         if self.dynamic:
             raise ValueError("lower() covers static compiled plans")
         return self._fn.lower(params, x)
+
+    def op_scopes(self, params, x) -> Dict[str, str]:
+        """``{HLO op name: scope path}`` of the executable a call with
+        ``(params, x)`` runs, such as ``fusion.1 -> l0.aggregate/gather``,
+        ``pad.6 -> l0.aggregate/pad`` or ``dot.3 -> l1.combine``: what a
+        profiler trace's device op time is put down to.  Compiles the
+        forward once more; nothing on the call path uses it."""
+        return hlo_op_scopes(self.lower(params, x).compile().as_text())
 
     @staticmethod
     def _signature(params, *arrays):
@@ -679,16 +732,41 @@ class CompiledPlan:
                                  "with plan.compile(dynamic=True) to pass "
                                  "a runtime graph")
             args = (x,)
-        sig = self._signature(params, *args)
-        before = self._num_traces
-        out = self._fn(params, *args)
-        if self._num_traces > before and sig in self._seen:
-            raise RuntimeError(
-                "plan.compile() retraced for an input signature it already "
-                "compiled -- something is busting the jit cache (weak "
-                "types? fresh arrays with different dtypes?)")
-        self._seen.add(sig)
-        return out
+        with span("plan.call"):
+            with span("plan.guard"):
+                sig = self._signature(params, *args)
+                seen = sig in self._seen
+            before = self._num_traces
+            out = self._fn(params, *args)
+            if self._num_traces > before and seen:
+                raise RuntimeError(
+                    "plan.compile() retraced for an input signature it "
+                    "already compiled -- something is busting the jit "
+                    "cache (weak types? fresh arrays with different "
+                    "dtypes?)")
+            if not seen:
+                self._seen.add(sig)
+            return out
+
+
+_OP_META = re.compile(
+    r'^\s*(?:ROOT\s+)?%?([\w.\-]+) = .*?metadata=\{[^}]*?op_name="([^"]*)"')
+
+
+def hlo_op_scopes(hlo_text: str) -> Dict[str, str]:
+    """``{op name: scope path}`` from a compiled module's text: each
+    instruction's ``metadata={op_name=...}`` with the transformation
+    frames (``jit(fwd)``, ``jvp(...)``) and the primitive's own name
+    dropped.  Ops outside every named scope are left out."""
+    out = {}
+    for line in hlo_text.splitlines():
+        m = _OP_META.match(line)
+        if m is None:
+            continue
+        path = [p for p in m.group(2).split("/")[:-1] if "(" not in p]
+        if path:
+            out[m.group(1)] = "/".join(path)
+    return out
 
 
 # ---------------------------------------------------------------------------
@@ -718,17 +796,21 @@ def _can_fuse(lp: LayerPlan, weights, edge_weight) -> bool:
 
 
 def _phase(probe, name: str, thunk, *, lp: LayerPlan, **meta):
-    """Run one phase, optionally observed by an instrumentation probe.
+    """Run one phase under the named scope ``l<layer>.<phase>``,
+    optionally observed by an instrumentation probe.
 
-    ``probe`` is the characterization hook (``repro.profile.instrument``):
-    None in production (zero overhead -- the thunk runs directly); when set,
-    ``probe.run`` times the phase and records its analytic cost.  Keeping
-    the hook HERE means reports always describe the dispatch path that
-    actually ran, not a parallel re-implementation.
+    The scope names the phase's ops in the traced program's metadata
+    (``CompiledPlan.op_scopes``); it acts at trace time only.  ``probe``
+    is the characterization hook (``repro.profile.instrument``): None in
+    production (the thunk runs directly); when set, ``probe.run`` times
+    the phase and records its analytic cost.  Keeping the hook HERE means
+    reports always describe the dispatch path that actually ran, not a
+    parallel re-implementation.
     """
-    if probe is None:
-        return thunk()
-    return probe.run(name, thunk, lp=lp, **meta)
+    with jax.named_scope(f"l{lp.index}.{name}"):
+        if probe is None:
+            return thunk()
+        return probe.run(name, thunk, lp=lp, **meta)
 
 
 def _round(h: jnp.ndarray, dtype: str) -> jnp.ndarray:

@@ -158,9 +158,32 @@ def kernel_edges(seg_local, mask, tile_e: int, *same_padding):
         return jnp.pad(a, ((0, 0), (0, emax_p - emax))
                        + ((0, 0),) * (a.ndim - 2))
 
-    return (pad(seg_local).reshape(nblocks, 1, emax_p),
-            pad(mask).reshape(nblocks, 1, emax_p),
-            *(pad(a) for a in same_padding))
+    with jax.named_scope("pad"):
+        return (pad(seg_local).reshape(nblocks, 1, emax_p),
+                pad(mask).reshape(nblocks, 1, emax_p),
+                *(pad(a) for a in same_padding))
+
+
+def layout_counts(bg, f_in: int, itemsize: int, backend: str,
+                  f_out: int = 0) -> dict:
+    """What one aggregation over the blocked layout ``bg`` moves, by the
+    same tiling ``seg_agg_planned`` (``f_out=0``) and ``fused_agg_combine``
+    (a ``(f_in, f_out)`` weight) apply:
+
+    * ``edges``: real edges (``bg.num_edges``);
+    * ``gather_rows``: rows the pre-gather writes: ``nblocks·emax`` for
+      ``seg_agg``, which gathers before the edge-axis pad, and
+      ``nblocks·emax_p`` for the fused kernel, which pads the ids first;
+    * ``kernel_slots``: edge slots the kernel reads, ``nblocks·emax_p``;
+    * ``gather_bytes``: ``gather_rows · f_in · itemsize``.
+    """
+    backend = resolve_backend(backend)
+    tile_e = _tile_e(backend, bg.tile_m, f_in, f_out, itemsize,
+                     FUSED_TILE_E_MAX if f_out else SEG_TILE_E_MAX)
+    slots = bg.nblocks * _round_up(bg.emax, tile_e)
+    rows = slots if f_out else bg.nblocks * bg.emax
+    return {"edges": int(bg.num_edges), "gather_rows": rows,
+            "kernel_slots": slots, "gather_bytes": rows * f_in * itemsize}
 
 
 # ---------------------------------------------------------------------------
@@ -256,8 +279,9 @@ def seg_agg_planned(bg, x: jnp.ndarray, edge_weight=None, *,
     """
     backend = resolve_backend(backend)
     nblocks, emax = bg.src.shape
-    rows = jnp.take(x, bg.src.reshape(-1), axis=0).reshape(
-        nblocks, emax, x.shape[-1])
+    with jax.named_scope("gather"):
+        rows = jnp.take(x, bg.src.reshape(-1), axis=0).reshape(
+            nblocks, emax, x.shape[-1])
     if edge_weight is not None:
         if bg.eidx is None:
             raise ValueError("BlockedGraph built without eidx cannot "
@@ -292,7 +316,9 @@ def fused_agg_combine(src, dst_local, mask, x, w, *, tile_m: int,
     tile_e = _tile_e(backend, tile_m, f_in, f_out,
                      jnp.dtype(x.dtype).itemsize, FUSED_TILE_E_MAX)
     seg3, mask3, src = kernel_edges(dst_local, mask, tile_e, src)
-    rows = jnp.take(x, src.reshape(-1), axis=0).reshape(nblocks, -1, f_in)
+    with jax.named_scope("gather"):
+        rows = jnp.take(x, src.reshape(-1), axis=0).reshape(nblocks, -1,
+                                                            f_in)
     if backend == PALLAS_GPU:
         return fused_agg_combine_gpu_blocked(
             rows, seg3, mask3, w, tile_m=tile_m, tile_e=tile_e,

@@ -2,7 +2,7 @@
 
 Everything the paper calls *characterization* -- per-phase time/FLOP/byte
 breakdowns (Tables 3-5), bound classification, roofline terms, benchmark
-sweeps -- hangs off three surfaces:
+sweeps -- hangs off four surfaces:
 
   * ``Machine`` (machine.py): hardware presets (``TPU_V5E`` | ``TPU_V5P``
     | ``A100`` | ``H100`` | the paper's ``V100``); every cost model takes
@@ -16,6 +16,10 @@ sweeps -- hangs off three surfaces:
   * ``BenchSpec`` / ``run_specs`` (bench.py): declarative benchmark specs
     (graph x model x machine x sweep axis) executed by one shared harness
     that owns warmup, timing, CSV artifacts, and dry-run validation.
+  * ``span`` / ``count`` / ``gauge`` (spans.py): the host spans and
+    counters the planned forward and the serving engine record at their
+    layer boundaries, read back with ``repro.profile.spans.spans()`` and
+    ``counters()``.
 
 One call end to end::
 
@@ -40,6 +44,9 @@ __all__ = [
     "WorkloadReportError", "validate_report_dict",
     "BenchSpec", "BenchContext", "run_specs", "timeit", "write_csv",
     "bench_graph", "latency_percentiles",
+    # lazy (spans.py; its reader ``spans()`` is reached through the
+    # submodule, ``repro.profile.spans.spans``):
+    "Span", "span", "record", "count", "gauge", "counters", "reset",
 ]
 
 _LAZY = {
@@ -55,6 +62,8 @@ _LAZY = {
     "write_csv": "repro.profile.bench",
     "bench_graph": "repro.profile.bench",
     "latency_percentiles": "repro.profile.bench",
+    **{n: "repro.profile.spans" for n in ("Span", "span", "record", "count",
+                                          "gauge", "counters", "reset")},
 }
 
 

@@ -27,15 +27,19 @@ Subclass contract:
 
 ``stats()`` reports the core's view -- steps, served, active, queued,
 latency percentiles (p50/p95/p99 ms), throughput -- and engines extend it
-with their own counters.
+with their own counters.  Every time the core stamps is a
+``time.perf_counter()`` reading, the clock of ``repro.profile.spans``:
+each request's wait from ``submit`` to admission is recorded as a
+``serve.queue`` span with its ``rid``.
 """
 
 from __future__ import annotations
 
 import time
-from typing import Any, Dict, List
+from typing import Any, Dict, List, Optional
 
 from repro.profile.bench import latency_percentiles
+from repro.profile.spans import record
 
 
 class SlotServeCore:
@@ -61,7 +65,7 @@ class SlotServeCore:
 
     def submit(self, req) -> None:
         """Enqueue one request (stamps ``enqueue_t``); FIFO admission."""
-        req.enqueue_t = time.time()
+        req.enqueue_t = time.perf_counter()
         if self._t_first_enqueue is None:
             self._t_first_enqueue = req.enqueue_t
         self._queue.append(req)
@@ -127,6 +131,8 @@ class SlotServeCore:
         while free and self._queue:
             slot = free[0]
             req = self._queue.pop(0)
+            record("serve.queue", req.enqueue_t, time.perf_counter(),
+                   rid=getattr(req, "rid", None))
             self._slot_assignments += 1
             if self._admit_into_slot(slot, req):
                 self._record_finish(req)
@@ -136,16 +142,18 @@ class SlotServeCore:
             self._active[slot] = req
         return done_at_admit
 
-    def _complete(self, slot: int):
+    def _complete(self, slot: int, t: Optional[float] = None):
         """Finish the request in ``slot`` and free the slot (engines call
-        this from ``_step`` for every request that finished)."""
+        this from ``_step`` for every request that finished).  ``t`` is the
+        finish reading (the end of the request's dispatch span); None
+        reads the clock now."""
         req = self._active.pop(slot)
-        self._record_finish(req)
+        self._record_finish(req, t)
         return req
 
-    def _record_finish(self, req) -> None:
+    def _record_finish(self, req, t: Optional[float] = None) -> None:
         req.done = True
-        req.finish_t = time.time()
+        req.finish_t = time.perf_counter() if t is None else t
         self._t_last_finish = req.finish_t
         self._latencies_s.append(req.finish_t - req.enqueue_t)
         self._served += 1

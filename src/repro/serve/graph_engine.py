@@ -27,6 +27,14 @@ traffic the same way ``ServeEngine`` instantiates it for LM decode:
     throughput report through the ``WorkloadReport`` machinery
     (``workload_report()``).
 
+Each request's host work is recorded as spans with its ``rid``
+(``repro.profile.spans``): ``serve.queue``; ``serve.admit`` with its
+children ``serve.sample`` and ``serve.union``; ``serve.dispatch`` with its
+children ``serve.pad``, ``serve.transfer``, ``serve.execute`` and
+``serve.readback``.  Counters ``serve.pad_rows`` (bucket rows beyond the
+real frontier) and ``serve.h2d_bytes`` (bytes moved to the device) add up
+over requests.
+
 Requests too large for every bucket are *bucket misses*: served through a
 per-request eager plan (correct but slow) and counted -- the smoke gate
 hard-fails on any miss.  Per-request plans are what the plan-cache
@@ -50,12 +58,14 @@ from __future__ import annotations
 import dataclasses
 from typing import Any, Dict, List, NamedTuple, Optional, Sequence, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.core.plan import build_plan, clear_plan_cache, plan_cache_stats
 from repro.graph.sampling import SampledBlock, two_hop_batch
 from repro.graph.structure import Graph, graph_from_coo
+from repro.profile.spans import count, span
 from repro.serve.core import SlotServeCore
 
 
@@ -282,20 +292,26 @@ class GraphServeEngine(SlotServeCore):
 
     # ----------------------------------------------------------- preparation
 
-    def prepare(self, seeds: np.ndarray) -> PreparedBlock:
+    def prepare(self, seeds: np.ndarray, rid=None) -> PreparedBlock:
         """Host-side admission work for one request: sample the 2-hop
-        frontier (fresh draws from the engine's long-lived RNG), merge
-        into the union block, select the bucket."""
+        frontier (fresh draws from the engine's long-lived RNG;
+        ``serve.sample``), merge into the union block and select the
+        bucket (``serve.union``)."""
         seeds = np.asarray(seeds, np.int32)
-        hop2, hop1 = two_hop_batch(self.g, seeds, self.fanouts, rng=self.rng)
-        frontier, ug, seed_pos = union_two_hop(hop2, hop1, seeds)
-        bucket = self.select_bucket(len(seeds), len(frontier), ug.num_edges)
+        with span("serve.sample", rid=rid):
+            hop2, hop1 = two_hop_batch(self.g, seeds, self.fanouts,
+                                       rng=self.rng)
+        with span("serve.union", rid=rid):
+            frontier, ug, seed_pos = union_two_hop(hop2, hop1, seeds)
+            bucket = self.select_bucket(len(seeds), len(frontier),
+                                        ug.num_edges)
         return PreparedBlock(frontier=frontier, graph=ug, seed_pos=seed_pos,
                              bucket=bucket)
 
     def _pad_into(self, prep: PreparedBlock, bucket: Bucket
-                  ) -> Tuple[jnp.ndarray, Graph]:
-        """Pad the union block into the bucket's static shapes.
+                  ) -> Tuple[np.ndarray, ...]:
+        """Pad the union block into the bucket's static shapes, on the
+        host: ``(x, src, dst, in_deg)``.
 
         Exactness contract: pad feature rows are zero, pad edges are
         sink self-loops on the LAST row (preserving the dst-sort), pad
@@ -314,22 +330,33 @@ class GraphServeEngine(SlotServeCore):
         in_deg[:n] = np.asarray(prep.graph.in_deg, np.int32)
         x = np.zeros((bucket.num_inputs, self.in_dim), np.float32)
         x[:n] = self.features[prep.frontier]
-        g = Graph(src=jnp.asarray(src), dst=jnp.asarray(dst),
-                  in_deg=jnp.asarray(in_deg), out_deg=jnp.asarray(in_deg),
-                  num_vertices=bucket.num_inputs)
-        return jnp.asarray(x), g
+        count("serve.pad_rows", bucket.num_inputs - n)
+        return x, src, dst, in_deg
 
     # ------------------------------------------------------------- execution
 
-    def run_prepared(self, prep: PreparedBlock) -> np.ndarray:
+    def run_prepared(self, prep: PreparedBlock, rid=None) -> np.ndarray:
         """Serve one prepared block through its bucket's compiled callable
-        (the production path); falls back to ``run_eager`` on a miss."""
+        (the production path); falls back to ``run_eager`` on a miss.
+
+        Spans, each with ``rid``: ``serve.pad`` (host pad),
+        ``serve.transfer`` (host to device, waited for), ``serve.execute``
+        (the bucket call, waited for) and ``serve.readback``."""
         if prep.bucket is None:
             return self.run_eager(prep)
         plan, fn = self._bucket_plan(prep.bucket)
-        x, g = self._pad_into(prep, prep.bucket)
-        out = fn(self.params, x, g)
-        return np.asarray(out)[prep.seed_pos]
+        with span("serve.pad", rid=rid):
+            host = self._pad_into(prep, prep.bucket)
+        with span("serve.transfer", rid=rid):
+            x, src, dst, in_deg = jax.block_until_ready(
+                [jnp.asarray(a) for a in host])
+        count("serve.h2d_bytes", sum(a.nbytes for a in host))
+        g = Graph(src=src, dst=dst, in_deg=in_deg, out_deg=in_deg,
+                  num_vertices=prep.bucket.num_inputs)
+        with span("serve.execute", rid=rid):
+            out = fn(self.params, x, g).block_until_ready()
+        with span("serve.readback", rid=rid):
+            return np.asarray(out)[prep.seed_pos]
 
     def run_eager(self, prep: PreparedBlock) -> np.ndarray:
         """Unpadded eager reference for a prepared block.
@@ -355,7 +382,8 @@ class GraphServeEngine(SlotServeCore):
     # ------------------------------------------------------------ core hooks
 
     def _admit_into_slot(self, slot: int, req: GraphRequest) -> bool:
-        req.prep = self.prepare(req.seeds)
+        with span("serve.admit", rid=req.rid):
+            req.prep = self.prepare(req.seeds, rid=req.rid)
         req.bucket = req.prep.bucket
         req.frontier_size = len(req.prep.frontier)
         req.edge_count = req.prep.graph.num_edges
@@ -369,10 +397,11 @@ class GraphServeEngine(SlotServeCore):
         finished = []
         for slot in sorted(self._active):
             req = self._active[slot]
-            req.logits = self.run_prepared(req.prep)
+            with span("serve.dispatch", rid=req.rid) as sp:
+                req.logits = self.run_prepared(req.prep, rid=req.rid)
             if req.bucket is not None:
                 self._bucket_hits[req.bucket] += 1
-            finished.append(self._complete(slot))
+            finished.append(self._complete(slot, t=sp.t1))
         self._steps += 1
         self._maybe_sweep_plan_cache()
         return finished

@@ -116,6 +116,37 @@ def test_citeseer_fused_tile_compiles_for_v5e(one_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+def test_gather_and_pad_scopes_survive_tpu_fusion(one_chip, monkeypatch):
+    """The GIN forward at Pubmed's width, compiled for the chip: the
+    pre-gather and the edge-axis pad stay ops of their own in the
+    executable, each named for its layer's scope, so device op time in a
+    trace can be put down to them (``CompiledPlan.op_scopes``)."""
+    from repro.core.plan import hlo_op_scopes
+    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")  # lower for Mosaic
+    rng = np.random.default_rng(0)
+    v, e, f = 2000, 8000, 500
+    g = graph_from_coo(rng.integers(0, v, e), rng.integers(0, v, e), v)
+    plan = build_plan(g, PAPER_MODELS["gin"], f, 3, backend="pallas-tpu")
+    params = jax.tree_util.tree_map(
+        lambda a: _shape(a.shape, a.dtype, one_chip),
+        jax.eval_shape(plan.init, jax.random.PRNGKey(0)))
+    text = plan.compile().lower(
+        params, _shape((v, f), jnp.float32, one_chip)).compile().as_text()
+    entry = text[text.index("\nENTRY"):]
+    scopes = hlo_op_scopes(entry)
+    for layer in ("l0", "l1"):
+        ops_in = {path: [op for op, p in scopes.items() if p == path]
+                  for path in (f"{layer}.aggregate/gather",
+                               f"{layer}.aggregate/pad",
+                               f"{layer}.aggregate/seg_agg")}
+        assert any(op.startswith("fusion")
+                   for op in ops_in[f"{layer}.aggregate/gather"]), ops_in
+        assert any(op.startswith("pad")
+                   for op in ops_in[f"{layer}.aggregate/pad"]), ops_in
+        assert any(op.startswith("seg_agg")
+                   for op in ops_in[f"{layer}.aggregate/seg_agg"]), ops_in
+
+
 def test_planner_refuses_fused_tile_that_overflows_vmem():
     """At F=3703 a square layer's pinned W alone overflows VMEM: the plan
     refuses fused=True at build time instead of emitting a tile Mosaic
