@@ -49,8 +49,11 @@ class BlockedGraph(NamedTuple):
            slots point at edge 0 and are masked) -- lets traced per-edge
            data (edge weights) be regrouped into this layout with one
            gather, no host round-trip (kernels/ops.seg_agg_planned).
-    num_edges: real (unmasked) slots; ``nblocks·emax / num_edges`` is the
-           layout's padding waste (``kernels.ops.layout_counts``).
+    num_edges: real (unmasked) slots.  The kernels pad ``emax`` further
+           to a ``tile_e`` multiple ``emax_p`` (pad slots carry mask 0)
+           and the pre-gather writes every slot, so the
+           padding waste a forward pays is ``nblocks·emax_p / num_edges``
+           (``kernels.ops.layout_counts``).
     """
 
     src: jnp.ndarray

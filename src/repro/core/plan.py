@@ -665,8 +665,8 @@ class CompiledPlan:
     def op_scopes(self, params, x) -> Dict[str, str]:
         """``{HLO op name: scope path}`` of the executable a call with
         ``(params, x)`` runs, such as ``fusion.1 -> l0.aggregate/gather``,
-        ``pad.6 -> l0.aggregate/pad`` or ``dot.3 -> l1.combine``: what a
-        profiler trace's device op time is put down to.  Compiles the
+        ``seg_agg.2 -> l0.aggregate/seg_agg`` or ``dot.3 -> l1.combine``:
+        what a profiler trace's device op time is put down to.  Compiles the
         forward once more; nothing on the call path uses it."""
         return hlo_op_scopes(self.lower(params, x).compile().as_text())
 

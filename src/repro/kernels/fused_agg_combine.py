@@ -72,7 +72,8 @@ def fused_agg_combine_blocked(rows: jnp.ndarray, seg_local: jnp.ndarray,
                               ) -> jnp.ndarray:
     """out[block b] = (sum_seg rows[b]) @ w, fused in VMEM.
 
-    rows: (nblocks, emax, F_in) destination-block-grouped gathered rows.
+    rows: (nblocks, emax + tail, F_in) destination-block-grouped gathered
+    rows; the grid stops at ``emax`` (``kernels.ops.gather_tail``).
     seg_local/mask: (nblocks, 1, emax) (the ``kernels.ops`` edge layout).
     w: (F_in, F_out).
     interpret: None = auto-detect (core.backend.default_interpret).
@@ -83,12 +84,14 @@ def fused_agg_combine_blocked(rows: jnp.ndarray, seg_local: jnp.ndarray,
     Returns (nblocks * tile_m, F_out) in w.dtype.
     """
     interpret = resolve_interpret(interpret)
-    nblocks, emax, f_in = rows.shape
+    nblocks, _, f_in = rows.shape
+    emax = seg_local.shape[-1]
     f_out = w.shape[1]
     assert w.shape[0] == f_in, (w.shape, f_in)
     assert seg_local.shape == mask.shape == (nblocks, 1, emax), \
         (seg_local.shape, mask.shape, rows.shape)
-    assert emax % tile_e == 0, (emax, tile_e)
+    assert emax % tile_e == 0 and rows.shape[1] >= emax, \
+        (emax, tile_e, rows.shape)
 
     out = pl.pallas_call(
         functools.partial(_fused_kernel, tile_m=tile_m, tile_e=tile_e,

@@ -138,7 +138,30 @@ def _tile_e(backend: str, tile_m: int, f_in: int, f_out: int,
     return tile_e
 
 
-def kernel_edges(seg_local, mask, tile_e: int, *same_padding):
+#: XLA's TPU gather keeps half as many rows in flight (its emitter's
+#: setting drops from 256 to 128) when the index count leaves a remainder
+#: of 0 or more than 896 modulo 1024: Reddit's pre-gather of 1821 × 7168
+#: slots ran 2.7x slower so (TPU v5e, jax 0.9).
+_GATHER_WINDOW, _GATHER_SLOW_ABOVE = 1024, 896
+
+
+def gather_tail(backend: str, nblocks: int, emax_p: int) -> int:
+    """Slots per block the TPU pre-gather writes past ``emax_p`` so that
+    its index count ``nblocks·(emax_p + tail)`` leaves a remainder in
+    ``[1, 896]`` modulo 1024.  The kernels' grid stops at ``emax_p``, so
+    no step reads them.  A multiple of 8, which keeps the rows' block
+    view a bitcast; 0 off the TPU tier or where no such tail exists."""
+    if backend != PALLAS_TPU:
+        return 0
+    for tail in range(0, _GATHER_WINDOW, 8):
+        if 0 < nblocks * (emax_p + tail) % _GATHER_WINDOW \
+                <= _GATHER_SLOW_ABOVE:
+            return tail
+    return 0
+
+
+def kernel_edges(seg_local, mask, tile_e: int, *same_padding,
+                 backend=None):
     """The edge layout every aggregation kernel reads.
 
     Pads the per-block edge axis of the ``(nblocks, emax)`` BlockedGraph
@@ -146,22 +169,26 @@ def kernel_edges(seg_local, mask, tile_e: int, *same_padding):
     ids and mask to ``(nblocks, 1, emax_p)``.  A ``(1, 1, tile_e)`` block
     then ends in (full dim, lane multiple), which Mosaic accepts; a
     ``(1, tile_e)`` block over ``(nblocks, emax)`` is refused.
-    ``same_padding`` arrays (source ids, pre-gathered rows) get the same
-    edge-axis padding and keep their rank.
+    ``same_padding`` arrays get the same edge-axis padding and keep their
+    rank: on the planned paths the source ids (and edge indices) that the
+    rows are then gathered by (``_gather_slots``), with
+    ``gather_tail(backend, ...)`` more slots per block; rows the caller
+    grouped itself (``seg_agg_pregrouped``, no backend).
     """
     nblocks, emax = seg_local.shape
     emax_p = _round_up(emax, tile_e)
+    tail = gather_tail(backend, nblocks, emax_p)
 
-    def pad(a):
-        if emax_p == emax:
+    def pad(a, to):
+        if to == emax:
             return a
-        return jnp.pad(a, ((0, 0), (0, emax_p - emax))
+        return jnp.pad(a, ((0, 0), (0, to - emax))
                        + ((0, 0),) * (a.ndim - 2))
 
     with jax.named_scope("pad"):
-        return (pad(seg_local).reshape(nblocks, 1, emax_p),
-                pad(mask).reshape(nblocks, 1, emax_p),
-                *(pad(a) for a in same_padding))
+        return (pad(seg_local, emax_p).reshape(nblocks, 1, emax_p),
+                pad(mask, emax_p).reshape(nblocks, 1, emax_p),
+                *(pad(a, emax_p + tail) for a in same_padding))
 
 
 def layout_counts(bg, f_in: int, itemsize: int, backend: str,
@@ -171,19 +198,38 @@ def layout_counts(bg, f_in: int, itemsize: int, backend: str,
     (a ``(f_in, f_out)`` weight) apply:
 
     * ``edges``: real edges (``bg.num_edges``);
-    * ``gather_rows``: rows the pre-gather writes: ``nblocks·emax`` for
-      ``seg_agg``, which gathers before the edge-axis pad, and
-      ``nblocks·emax_p`` for the fused kernel, which pads the ids first;
+    * ``gather_rows``: rows the pre-gather writes, straight into the
+      kernel's slot layout: ``nblocks·(emax_p + gather_tail)``;
     * ``kernel_slots``: edge slots the kernel reads, ``nblocks·emax_p``;
     * ``gather_bytes``: ``gather_rows · f_in · itemsize``.
     """
     backend = resolve_backend(backend)
     tile_e = _tile_e(backend, bg.tile_m, f_in, f_out, itemsize,
                      FUSED_TILE_E_MAX if f_out else SEG_TILE_E_MAX)
-    slots = bg.nblocks * _round_up(bg.emax, tile_e)
-    rows = slots if f_out else bg.nblocks * bg.emax
+    emax_p = _round_up(bg.emax, tile_e)
+    slots = bg.nblocks * emax_p
+    rows = bg.nblocks * (emax_p + gather_tail(backend, bg.nblocks, emax_p))
     return {"edges": int(bg.num_edges), "gather_rows": rows,
             "kernel_slots": slots, "gather_bytes": rows * f_in * itemsize}
+
+
+def _gather_slots(x, src, mask):
+    """Gather ``x`` rows by padded ``(nblocks, emax_p + tail)`` source ids
+    into the kernel's slot layout ``(nblocks, emax_p + tail, F)``: one
+    write of each row, and no pad of the rows afterwards.  Slots that hold
+    no edge (``mask`` 0, and the tail) read rows 0, 1, 2, ... in turn, not
+    all row 0: reads of one row queue behind each other (12.7 of Reddit's
+    65.4 ms a layer on a v5e), and mask 0 adds a finite row as an exact 0.
+    """
+    nblocks, slots = src.shape
+    with jax.named_scope("gather"):
+        edge = jnp.pad(mask.reshape(nblocks, -1) > 0,
+                       ((0, 0), (0, slots - mask.shape[-1])))
+        spread = np.arange(src.size, dtype=np.int32).reshape(
+            src.shape) % x.shape[0]
+        ids = jnp.where(edge, src, spread)
+        return jnp.take(x, ids.reshape(-1), axis=0).reshape(
+            nblocks, slots, x.shape[-1])
 
 
 # ---------------------------------------------------------------------------
@@ -192,13 +238,20 @@ def layout_counts(bg, f_in: int, itemsize: int, backend: str,
 
 
 def _seg_agg_call(backend: str, rows, seg_local, mask, tile_m: int):
-    """Lay out the edge operands and run the tier's blocked kernel (TPU
-    sequential-grid vs GPU row-owned).  ``backend`` must already be
-    resolved, so entry and interpret mode can never disagree."""
-    f = rows.shape[-1]
-    tile_e = _tile_e(backend, tile_m, f, 0, jnp.dtype(rows.dtype).itemsize,
-                     SEG_TILE_E_MAX)
+    """Pad pre-grouped ``(nblocks, emax, F)`` rows and their edge arrays to
+    the kernel's edge layout, then run it (``_seg_agg_kernel``)."""
+    tile_e = _tile_e(backend, tile_m, rows.shape[-1], 0,
+                     jnp.dtype(rows.dtype).itemsize, SEG_TILE_E_MAX)
     seg3, mask3, rows = kernel_edges(seg_local, mask, tile_e, rows)
+    return _seg_agg_kernel(backend, rows, seg3, mask3, tile_m, tile_e)
+
+
+def _seg_agg_kernel(backend: str, rows, seg3, mask3, tile_m: int,
+                    tile_e: int):
+    """Run the tier's blocked kernel (TPU sequential-grid vs GPU
+    row-owned) on operands already in the ``kernel_edges`` layout.
+    ``backend`` must already be resolved, so entry and interpret mode can
+    never disagree."""
     if backend == PALLAS_GPU:
         return seg_agg_gpu_blocked(rows, seg3, mask3, tile_m=tile_m,
                                    tile_e=tile_e,
@@ -276,20 +329,27 @@ def seg_agg_planned(bg, x: jnp.ndarray, edge_weight=None, *,
     blocked layout whose ``src`` ids reach into the partial rows, so the
     kernel folds the SHORTENED level-2 edge list unchanged; only the
     first-dim bound differs, never the kernel body.
+
+    The ids are padded to the kernel's edge layout first (``kernel_edges``:
+    pad slots take mask 0 and edge 0), and the rows are gathered straight
+    into it (``_gather_slots``), as ``fused_agg_combine`` does: each row is
+    written once, at ``(nblocks, emax_p + gather_tail, F)``.
     """
     backend = resolve_backend(backend)
-    nblocks, emax = bg.src.shape
-    with jax.named_scope("gather"):
-        rows = jnp.take(x, bg.src.reshape(-1), axis=0).reshape(
-            nblocks, emax, x.shape[-1])
+    if edge_weight is not None and bg.eidx is None:
+        raise ValueError("BlockedGraph built without eidx cannot "
+                         "regroup edge weights; rebuild via block_graph")
+    tile_e = _tile_e(backend, bg.tile_m, x.shape[-1], 0,
+                     jnp.dtype(x.dtype).itemsize, SEG_TILE_E_MAX)
+    ids = (bg.src,) if edge_weight is None else (bg.src, bg.eidx)
+    seg3, mask3, src, *eidx = kernel_edges(bg.dstl, bg.mask, tile_e, *ids,
+                                           backend=backend)
+    rows = _gather_slots(x, src, mask3)
     if edge_weight is not None:
-        if bg.eidx is None:
-            raise ValueError("BlockedGraph built without eidx cannot "
-                             "regroup edge weights; rebuild via block_graph")
-        w_blk = jnp.take(edge_weight, bg.eidx.reshape(-1),
-                         axis=0).reshape(nblocks, emax)
+        w_blk = jnp.take(edge_weight, eidx[0].reshape(-1),
+                         axis=0).reshape(src.shape)
         rows = rows * w_blk[..., None].astype(rows.dtype)
-    out = _seg_agg_call(backend, rows, bg.dstl, bg.mask, bg.tile_m)
+    out = _seg_agg_kernel(backend, rows, seg3, mask3, bg.tile_m, tile_e)
     return out[:bg.num_vertices]
 
 
@@ -311,14 +371,12 @@ def fused_agg_combine(src, dst_local, mask, x, w, *, tile_m: int,
     per platform.
     """
     backend = resolve_backend(backend)
-    nblocks = src.shape[0]
     f_in, f_out = w.shape
     tile_e = _tile_e(backend, tile_m, f_in, f_out,
                      jnp.dtype(x.dtype).itemsize, FUSED_TILE_E_MAX)
-    seg3, mask3, src = kernel_edges(dst_local, mask, tile_e, src)
-    with jax.named_scope("gather"):
-        rows = jnp.take(x, src.reshape(-1), axis=0).reshape(nblocks, -1,
-                                                            f_in)
+    seg3, mask3, src = kernel_edges(dst_local, mask, tile_e, src,
+                                    backend=backend)
+    rows = _gather_slots(x, src, mask3)
     if backend == PALLAS_GPU:
         return fused_agg_combine_gpu_blocked(
             rows, seg3, mask3, w, tile_m=tile_m, tile_e=tile_e,

@@ -98,8 +98,10 @@ def seg_agg_blocked(rows: jnp.ndarray, seg_local: jnp.ndarray,
     """Blocked segmented sum.
 
     Args:
-      rows:      (nblocks, emax, F) pre-gathered edge rows, grouped by
-                 destination block (see core.dataflow.block_graph).
+      rows:      (nblocks, emax + tail, F) pre-gathered edge rows, grouped
+                 by destination block (see core.dataflow.block_graph); the
+                 grid stops at ``emax``, so no step reads the tail slots
+                 (``kernels.ops.gather_tail``).
       seg_local: (nblocks, 1, emax) int32 destination row id LOCAL to the
                  block (the ``kernels.ops`` edge layout).
       mask:      (nblocks, 1, emax) 1/0 edge validity.
@@ -118,10 +120,12 @@ def seg_agg_blocked(rows: jnp.ndarray, seg_local: jnp.ndarray,
     Returns (nblocks * tile_m, F) in ``rows.dtype``.
     """
     interpret = resolve_interpret(interpret)
-    nblocks, emax, f = rows.shape
+    nblocks, _, f = rows.shape
+    emax = seg_local.shape[-1]
     assert seg_local.shape == mask.shape == (nblocks, 1, emax), \
         (seg_local.shape, mask.shape, rows.shape)
-    assert emax % tile_e == 0, (emax, tile_e)
+    assert emax % tile_e == 0 and rows.shape[1] >= emax, \
+        (emax, tile_e, rows.shape)
 
     out = pl.pallas_call(
         functools.partial(_seg_agg_kernel, tile_m=tile_m, tile_e=tile_e,

@@ -122,6 +122,153 @@ def test_fused_equals_unfused_composition():
     assert_allclose_dtype(fused, unfused, scale=10)
 
 
+# ------------------------------------- planned seg_agg: the slot layout
+def _sorted_edges(v, e, seed):
+    rng = np.random.default_rng(seed)
+    dst = np.sort(rng.integers(0, v, e))
+    return rng.integers(0, v, e), dst
+
+
+def _planned_case(source, f, seed=0):
+    """A blocked layout whose ``emax`` is no ``tile_e`` multiple, and the
+    matrix it gathers from: vertex features, or the dedup level-2 source
+    ``[x ; partials]`` with ids that reach into the partial rows.  The
+    ``tail`` graph pads 3 blocks to 1024 slots, so the TPU pre-gather adds
+    a tail (``ops.gather_tail``)."""
+    from repro.core.dataflow import block_graph_arrays
+    from repro.graph.dedup import attach_blocked, build_dedup_layout
+    v, e, tile_m = (192, 1800, 64) if source == "tail" else (300, 1500, 64)
+    src, dst = _sorted_edges(v, e, seed)
+    x = jnp.asarray(np.random.default_rng(seed + 1).standard_normal((v, f)),
+                    jnp.float32)
+    if source == "dedup":
+        # every destination's two leading sources drawn from four hubs,
+        # so many destinations share a pair
+        hubs = np.random.default_rng(seed + 2).integers(0, 4, (v, 2))
+        src = np.concatenate([hubs, src.reshape(v, -1)], 1).reshape(-1)
+        dst = np.repeat(np.arange(v), src.size // v)
+        lay = attach_blocked(build_dedup_layout(src, dst, v), tile_m)
+        assert lay.num_pairs > 0
+        partials = jnp.take(x, lay.pair_left, axis=0) + \
+            jnp.take(x, lay.pair_right, axis=0)
+        return lay.blocked, jnp.concatenate([x, partials], axis=0)
+    return block_graph_arrays(src, dst, v, tile_m), x
+
+
+def _old_order(bg, x, edge_weight):
+    """The parent order: gather to ``emax``, then pad the gathered rows
+    (``seg_agg_pregrouped`` pads pre-grouped rows)."""
+    rows = jnp.take(x, bg.src.reshape(-1), axis=0).reshape(
+        bg.nblocks, bg.emax, x.shape[-1])
+    if edge_weight is not None:
+        w_blk = jnp.take(edge_weight, bg.eidx.reshape(-1), axis=0)
+        rows = rows * w_blk.reshape(bg.nblocks, bg.emax, 1)
+    out = ops.seg_agg_pregrouped(rows, bg.dstl, bg.mask, bg.tile_m,
+                                 backend="pallas-tpu")
+    return out[:bg.num_vertices]
+
+
+def _slot_shape(bg, f):
+    """``(emax_p, tail)`` of ``seg_agg_planned`` on ``bg`` at width f."""
+    counts = ops.layout_counts(bg, f, 4, "pallas-tpu")
+    emax_p = counts["kernel_slots"] // bg.nblocks
+    assert emax_p > bg.emax, "emax must not be a tile_e multiple here"
+    return emax_p, counts["gather_rows"] // bg.nblocks - emax_p
+
+
+def _eqns(jaxpr):
+    """Every equation of ``jaxpr`` and of the jaxprs nested in it."""
+    from jax.extend.core import ClosedJaxpr, Jaxpr
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for p in eqn.params.values():
+            for sub in p if isinstance(p, (list, tuple)) else (p,):
+                if isinstance(sub, ClosedJaxpr):
+                    yield from _eqns(sub.jaxpr)
+                elif isinstance(sub, Jaxpr):
+                    yield from _eqns(sub)
+
+
+@pytest.mark.parametrize("source,weighted", [
+    ("graph", False), ("graph", True), ("dedup", False), ("tail", True)])
+@pytest.mark.parametrize("f", [41, 128, 500])
+def test_seg_agg_planned_equals_the_old_order(source, weighted, f):
+    """Gathering into the padded slots is bitwise the gather-then-pad
+    order: pad slots carry mask 0, so they add an exact 0, and the tail
+    slots are never read."""
+    bg, x = _planned_case(source, f)
+    _, tail = _slot_shape(bg, f)
+    assert (tail > 0) == (source == "tail")
+    w = jnp.asarray(np.random.default_rng(3).random(bg.num_edges),
+                    jnp.float32) if weighted else None
+    new = ops.seg_agg_planned(bg, x, w, backend="pallas-tpu")
+    np.testing.assert_array_equal(np.asarray(new),
+                                  np.asarray(_old_order(bg, x, w)))
+
+
+@pytest.mark.parametrize("source", ["graph", "tail"])
+@pytest.mark.parametrize("weighted", [False, True])
+@pytest.mark.parametrize("f", [41, 128, 500])
+def test_seg_agg_planned_gathers_into_the_slot_layout(f, weighted, source):
+    """One gather writes ``nblocks·(emax_p + tail)`` rows; no f32 row pad
+    follows, only the 2-D pads of ids and mask."""
+    bg, x = _planned_case(source, f)
+    emax_p, tail = _slot_shape(bg, f)
+    w = jnp.ones(bg.num_edges, jnp.float32) if weighted else None
+    jaxpr = jax.make_jaxpr(lambda x, w: ops.seg_agg_planned(
+        bg, x, w, backend="pallas-tpu"))(x, w).jaxpr
+    eqns = list(_eqns(jaxpr))
+    gathers = [q.outvars[0].aval.shape for q in eqns
+               if q.primitive.name == "gather"
+               and q.outvars[0].aval.shape[-1:] == (f,)]
+    assert gathers == [(bg.nblocks * (emax_p + tail), f)]
+    pads = [q.outvars[0].aval.shape for q in eqns
+            if q.primitive.name == "pad"]
+    assert pads and all(p in {(bg.nblocks, emax_p),
+                              (bg.nblocks, emax_p + tail)}
+                        for p in pads), pads
+
+
+@pytest.mark.parametrize("nblocks,emax_p,tail", [
+    (1821, 7168, 8),     # Reddit's layout: 0 modulo 1024 without a tail
+    (155, 512, 0),       # Pubmed's: 512 modulo 1024 already
+    (3, 1024, 8), (1, 2048, 8),
+    (128, 1024, 0),      # every tail leaves 0 modulo 1024: none helps
+])
+def test_gather_tail_moves_the_index_count_off_the_slow_remainders(
+        nblocks, emax_p, tail):
+    assert ops.gather_tail("pallas-tpu", nblocks, emax_p) == tail
+    assert ops.gather_tail("pallas-gpu", nblocks, emax_p) == 0
+    if tail:
+        assert 0 < nblocks * (emax_p + tail) % 1024 <= 896
+
+
+@pytest.mark.parametrize("model", ["gcn", "gin"])
+def test_planned_forward_pads_no_gathered_rows(model):
+    """The lowered forward of a ``pallas-tpu`` plan pads ids and masks
+    only: no f32 pad of rank 3 (the gathered rows) is left.  Its layout
+    needs no gather tail: the interpreter pads a kernel operand whose
+    slots are no block multiple (the compiled TPU forward with a tail is
+    in tests/test_tpu_compile.py)."""
+    import re
+    from repro.core.plan import build_plan
+    from repro.graph.structure import graph_from_coo
+    from repro.models.gcn import PAPER_MODELS
+    src, dst = _sorted_edges(300, 900, 4)
+    g = graph_from_coo(src, dst, 300)
+    plan = build_plan(g, PAPER_MODELS[model], 24, 5, backend="pallas-tpu",
+                      fused=False)
+    params = plan.init(jax.random.PRNGKey(0))
+    x = jnp.ones((g.num_vertices, 24), jnp.float32)
+    hlo = plan.compile().lower(params, x).as_text()
+    pads = re.findall(r"stablehlo\.pad .*-> tensor<([0-9x]+)xf32>", hlo)
+    assert pads, "the mask pad is gone: the test reads the wrong text"
+    assert all(p.count("x") == 1 for p in pads), pads
+    for lp, d in zip(plan.layers, plan.describe()):
+        assert d["agg_gather_rows"] == d["agg_kernel_slots"] \
+            > lp.agg_layout.nblocks * lp.agg_layout.emax
+
+
 # --------------------------------------------------------- flash attention
 CASES = [
     # b, hq, hkv, sq, sk, d, causal, window, cap

@@ -13,6 +13,7 @@ from repro.config import CORA, reduced_graph
 from repro.core.plan import build_plan
 from repro.graph.datasets import make_features, make_synthetic_graph
 from repro.graph.structure import graph_from_coo
+from repro.kernels.ops import gather_tail
 from repro.kernels.ref import gcn_forward_ref
 from repro.models.gcn import PAPER_MODELS
 from repro.profile import spans as reg
@@ -152,12 +153,18 @@ def test_one_call_span_with_one_guard_child_per_call(gin_plan):
 
 
 def test_op_scopes_name_gather_pad_and_combine(gin_plan):
+    """Gather, kernel and combine are ops of their scopes.  The ``pad``
+    scope pads only the plan's ids and mask, which are constants of the
+    executable, so all it leaves are constants: the rows are gathered
+    straight into the padded slots and no pad of them is left to run."""
     plan, params, x = gin_plan
     scopes = plan.compile().op_scopes(params, x)
     paths = set(scopes.values())
-    for want in ("l0.aggregate/gather", "l0.aggregate/pad", "l0.combine",
-                 "l1.aggregate/gather", "l1.aggregate/pad", "l1.combine"):
+    for want in ("l0.aggregate/gather", "l0.aggregate/seg_agg", "l0.combine",
+                 "l1.aggregate/gather", "l1.aggregate/seg_agg", "l1.combine"):
         assert want in paths, sorted(paths)
+    padded = [op for op, p in scopes.items() if p.endswith("/pad")]
+    assert all(op.startswith("constant") for op in padded), padded
     assert all("(" not in p for p in paths)
 
 
@@ -187,12 +194,13 @@ def test_layout_counts_match_the_traced_kernel_operands(fused):
     hlo = plan.compile().lower(params, x).as_text()
     for lp, d in zip(plan.layers, plan.describe()):
         assert d["agg_edges"] == g.num_edges
-        assert d["agg_gather_rows"] <= d["agg_kernel_slots"]
+        bg = lp.blocked if fused else lp.agg_layout
+        emax_p = d["agg_kernel_slots"] // bg.nblocks
+        assert d["agg_gather_rows"] == bg.nblocks * (
+            emax_p + gather_tail(lp.backend, bg.nblocks, emax_p))
         width = lp.din if fused or lp.order == "aggregate_first" \
             else lp.dout
         assert d["agg_gather_bytes"] == d["agg_gather_rows"] * width * 4
-        bg = lp.blocked if fused else lp.agg_layout
-        emax_p = d["agg_kernel_slots"] // bg.nblocks
         assert f"tensor<{bg.nblocks}x1x{emax_p}xf32>" in hlo   # mask
         assert f"tensor<{d['agg_gather_rows']}x{width}xf32>" in hlo
     counts = reg.counters()

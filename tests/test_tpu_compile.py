@@ -13,6 +13,7 @@ the worker that runs this file loads the TPU compiler.
 """
 
 import dataclasses
+import re
 
 import jax
 import jax.numpy as jnp
@@ -118,9 +119,12 @@ def test_citeseer_fused_tile_compiles_for_v5e(one_chip):
 
 def test_gather_and_pad_scopes_survive_tpu_fusion(one_chip, monkeypatch):
     """The GIN forward at Pubmed's width, compiled for the chip: the
-    pre-gather and the edge-axis pad stay ops of their own in the
-    executable, each named for its layer's scope, so device op time in a
-    trace can be put down to them (``CompiledPlan.op_scopes``)."""
+    pre-gather and the kernel stay ops of their own in the executable,
+    each named for its layer's scope, so device op time in a trace can be
+    put down to them (``CompiledPlan.op_scopes``).  The gather writes the
+    kernel's padded slots itself; the edge-axis pad touches only the
+    plan's constant ids and mask, so the ``pad`` scope holds constants
+    alone, and no f32 pad of the gathered rows is left to run."""
     from repro.core.plan import hlo_op_scopes
     monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")  # lower for Mosaic
     rng = np.random.default_rng(0)
@@ -134,17 +138,61 @@ def test_gather_and_pad_scopes_survive_tpu_fusion(one_chip, monkeypatch):
         params, _shape((v, f), jnp.float32, one_chip)).compile().as_text()
     entry = text[text.index("\nENTRY"):]
     scopes = hlo_op_scopes(entry)
-    for layer in ("l0", "l1"):
+    shapes = dict(re.findall(r"%([\w.-]+) = (\w+\[[\d,]*\])", entry))
+    for lp, d in zip(plan.layers, plan.describe()):
+        layer, width = f"l{lp.index}", lp.din
         ops_in = {path: [op for op, p in scopes.items() if p == path]
                   for path in (f"{layer}.aggregate/gather",
                                f"{layer}.aggregate/pad",
                                f"{layer}.aggregate/seg_agg")}
-        assert any(op.startswith("fusion")
-                   for op in ops_in[f"{layer}.aggregate/gather"]), ops_in
-        assert any(op.startswith("pad")
+        gathers = [shapes[op] for op in ops_in[f"{layer}.aggregate/gather"]
+                   if op.startswith("fusion")]
+        assert gathers == [f"f32[{d['agg_gather_rows']},{width}]"], ops_in
+        assert all(op.startswith("constant")
                    for op in ops_in[f"{layer}.aggregate/pad"]), ops_in
         assert any(op.startswith("seg_agg")
                    for op in ops_in[f"{layer}.aggregate/seg_agg"]), ops_in
+    assert not [s for op, s in shapes.items()
+                if op.startswith("pad") and s.startswith("f32")], shapes
+
+
+def test_gather_tail_keeps_the_tpu_gather_wide(one_chip, monkeypatch):
+    """A Reddit-size source (232,965 × 128 f32) and 3 blocks padded to
+    1024 slots: 3072 ids, 0 modulo 1024, where XLA's TPU gather keeps 128
+    rows in flight and not 256 (2.7x slower at Reddit's size on a v5e).
+    ``seg_agg_planned`` gathers 8 tail slots a block (3096 ids), the
+    compiled gather keeps 256, and its rows reach the kernel with no pad
+    or copy between."""
+    from repro.core.dataflow import block_graph_arrays
+    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")  # lower for Mosaic
+    rng = np.random.default_rng(0)
+    v, dests, e = 232965, 192, 1800
+    bg = block_graph_arrays(rng.integers(0, v, e),
+                            np.sort(rng.integers(0, dests, e)), dests, 64)
+    emax_p = ops.layout_counts(bg, 128, 4, "pallas-tpu")["kernel_slots"] \
+        // bg.nblocks
+    assert (bg.nblocks, emax_p) == (3, 1024)
+    assert ops.gather_tail("pallas-tpu", bg.nblocks, emax_p) == 8
+    x = _shape((v, 128), jnp.float32, one_chip)
+
+    def entry(fn):
+        text = jax.jit(fn).lower(x).compile().as_text()
+        return text[text.index("\nENTRY"):]
+
+    def in_flight(text):
+        return re.findall(r'"integer_config":\{"integer":"(\d+)"', text)
+
+    planned = entry(lambda x: ops.seg_agg_planned(bg, x,
+                                                  backend="pallas-tpu"))
+    assert in_flight(planned) == ["256"], planned
+    assert re.search(r"= f32\[3096,128\]\S* fusion\(", planned), planned
+    assert not re.search(r"= f32\[[\d,]+\]\S* (pad|copy)\(", planned), \
+        planned
+    assert "seg_agg" in planned
+    src, mask = (jnp.pad(a, ((0, 0), (0, emax_p - bg.emax)))
+                 for a in (bg.src, bg.mask))
+    assert in_flight(entry(lambda x: ops._gather_slots(x, src, mask))) \
+        == ["128"]
 
 
 def test_planner_refuses_fused_tile_that_overflows_vmem():
