@@ -1,4 +1,5 @@
-"""Distributed GCN execution: vertex-partitioned aggregation via shard_map.
+"""Distributed GCN-family execution: vertex-partitioned aggregation via
+shard_map.
 
 The paper profiles a single GPU; this module is the cluster-scale story its
 Table 4 implies (DESIGN.md §8.5): with a 1-D destination partition the
@@ -12,8 +13,16 @@ Two interchangeable aggregation strategies (both exact):
     then purely local gather+segment-reduce.  Simple; wire bytes V*F.
   * ``ring``       -- collective_permute steps around the data-axis ring; at
     each step every device reduces the contributions of the block it
-    currently holds.  Same total wire bytes as all-gather but only
-    O(V/P * F) resident.
+    currently holds, reading only the bucket of its edges whose sources
+    that block owns (``PartitionedGraph.bucket_*``).  Same total wire
+    bytes as all-gather but only O(V/P * F) resident.
+
+A distributed layer is two steps.  ``halo_aggregate`` exchanges the halo
+and returns each vertex's neighbour sum plus its own row (over in-degree
++ 1 for the mean) in the partition layout; the layer's combine then runs
+on the rows each device owns: one matmul for GCN/SAGE
+(``distributed_gcn_layer``, either phase order), GIN's MLP with its
+interior ReLU (``phases.combine``, called by ``core.plan``) after the sum.
 
 The ring strategy additionally has two SCHEDULES, selected by the
 ``overlap=`` plan decision (``build_plan(overlap=...)``, priced by
@@ -32,7 +41,8 @@ The ring strategy additionally has two SCHEDULES, selected by the
     equal -- eager and under ``plan.compile()``.
 
 Both strategies run under shard_map on the ``data`` axis; per-shard edge
-lists come from graph.partition (edge-balanced, padded static shapes).
+lists and the ring's owner buckets come from graph.partition (uniform
+blocks, padded static shapes).
 :func:`overlap_model` / :func:`choose_overlap` price the schedules against
 a ``Machine`` (per-hop link bytes vs. per-hop partial-combine work), and
 ``plan.instrument()`` reports the resulting exposed vs. overlapped
@@ -95,29 +105,31 @@ def _allgather_local(x_loc, srcl, dstl, mskl, block, nsh, axis):
     return _local_agg(x_full, srcl, dstl, mskl, block)
 
 
-def _hop_partial(buf, k, p, srcl, dstl, mskl, block, nsh):
+def _hop_partial(buf, k, p, bsrc, bdst, bmsk, block, nsh):
     """Partial combine of hop k's resident slab: the contributions of the
-    block currently held (``(p - k) mod P`` -- ring sends i -> i+1), masked
-    so neither padding rows nor edges owned by other blocks enter the
-    accumulator.  Shared by BOTH ring schedules so their per-hop math -- and
-    therefore their accumulation order -- is structurally identical
-    (bitwise-equal outputs are part of the overlap contract)."""
+    block currently held (``(p - k) mod P`` -- ring sends i -> i+1), read
+    from that owner's bucket of the device's edges alone
+    (``PartitionedGraph.bucket_*``: sources local to the held block,
+    destinations ascending, padding masked and out of range).  Shared by
+    BOTH ring schedules so their per-hop math -- and therefore their
+    accumulation order -- is structurally identical (bitwise-equal outputs
+    are part of the overlap contract)."""
     owner = jnp.mod(p - k, nsh)                   # whose block we hold
-    sel = (srcl // block) == owner
-    local_src = srcl - owner * block
-    rows = jnp.take(buf, jnp.clip(local_src, 0, block - 1), axis=0)
-    rows = rows * (mskl * sel)[:, None]
+    src, dst, msk = (jax.lax.dynamic_index_in_dim(a, owner, keepdims=False)
+                     for a in (bsrc, bdst, bmsk))
+    rows = jnp.take(buf, src, axis=0) * msk[:, None]
     # the barrier pins each partial's rounding: the pipelined schedule's
     # last partial sits outside the scan, where XLA would otherwise fuse
     # it with the accumulate differently (a 1-ulp drift between schedules)
     return jax.lax.optimization_barrier(
-        jax.ops.segment_sum(rows, dstl, num_segments=block))
+        jax.ops.segment_sum(rows, dst, num_segments=block,
+                            indices_are_sorted=True))
 
 
-def _ring_local(x_loc, srcl, dstl, mskl, block, nsh, axis):
+def _ring_local(x_loc, bsrc, bdst, bmsk, block, nsh, axis):
     """Per-device ring halo body, single-buffered (``overlap="none"``):
     nsh hops of collective_permute over ``axis``, each hop reducing the
-    currently-held block's contributions and THEN passing it onward -- the
+    currently-held block's bucket and THEN passing the block onward -- the
     send waits behind the hop's partial combine, so the wire time is fully
     exposed.  Shared by the 1-D path (axis = the single data axis) and the
     2-D path (axis = the node axis of the mesh; feature columns ride
@@ -128,7 +140,7 @@ def _ring_local(x_loc, srcl, dstl, mskl, block, nsh, axis):
 
     def hop(carry, k):
         buf, acc = carry
-        acc = acc + _hop_partial(buf, k, p, srcl, dstl, mskl, block, nsh)
+        acc = acc + _hop_partial(buf, k, p, bsrc, bdst, bmsk, block, nsh)
         buf = jax.lax.ppermute(buf, axis, perm)   # pass block onward
         return (buf, acc), None
 
@@ -137,12 +149,12 @@ def _ring_local(x_loc, srcl, dstl, mskl, block, nsh, axis):
     # while the ppermute wire keeps carrying the reduced x_loc slab.
     # f32 slabs: promote_types(f32, f32) == f32 -- unchanged.
     acc0 = jnp.zeros((block, x_loc.shape[-1]),
-                     jnp.promote_types(x_loc.dtype, mskl.dtype))
+                     jnp.promote_types(x_loc.dtype, bmsk.dtype))
     (_, acc), _ = jax.lax.scan(hop, (x_loc, acc0), jnp.arange(nsh))
     return acc
 
 
-def _ring_local_pipelined(x_loc, srcl, dstl, mskl, block, nsh, axis):
+def _ring_local_pipelined(x_loc, bsrc, bdst, bmsk, block, nsh, axis):
     """Per-device ring halo body, double-buffered (``overlap="pipelined"``).
 
     Each hop issues the ``ppermute`` FIRST -- hop k+1's slab is in flight
@@ -164,16 +176,16 @@ def _ring_local_pipelined(x_loc, srcl, dstl, mskl, block, nsh, axis):
     def hop(carry, k):
         buf, acc = carry
         nxt = jax.lax.ppermute(buf, axis, perm)   # in flight during reduce
-        acc = acc + _hop_partial(buf, k, p, srcl, dstl, mskl, block, nsh)
+        acc = acc + _hop_partial(buf, k, p, bsrc, bdst, bmsk, block, nsh)
         return (nxt, acc), None
 
     # same promoted accumulator as _ring_local (f32 partials over a reduced
     # bf16 wire slab); identical type for f32 slabs
     acc0 = jnp.zeros((block, x_loc.shape[-1]),
-                     jnp.promote_types(x_loc.dtype, mskl.dtype))
+                     jnp.promote_types(x_loc.dtype, bmsk.dtype))
     (buf, acc), _ = jax.lax.scan(hop, (x_loc, acc0), jnp.arange(nsh - 1))
     # last hop: the slab is already resident -- reduce it, send nothing
-    return acc + _hop_partial(buf, nsh - 1, p, srcl, dstl, mskl, block, nsh)
+    return acc + _hop_partial(buf, nsh - 1, p, bsrc, bdst, bmsk, block, nsh)
 
 
 _STRATEGIES = {"ring": _ring_local, "allgather": _allgather_local}
@@ -227,26 +239,61 @@ def aggregate_ring(pg: PartitionedGraph, x: jnp.ndarray, mesh: Mesh,
                    axis: str = "data", *,
                    overlap: str = "none") -> jnp.ndarray:
     """Ring halo exchange: collective_permutes with a partial reduce per
-    hop.  ``overlap`` picks the schedule: ``"none"`` = single-buffered
-    (``_ring_local``), ``"pipelined"`` = double-buffered with each send in
-    flight under the resident slab's reduce (``_ring_local_pipelined``);
-    both are bit-for-bit equal."""
+    hop over the held block's owner bucket.  ``overlap`` picks the
+    schedule: ``"none"`` = single-buffered (``_ring_local``),
+    ``"pipelined"`` = double-buffered with each send in flight under the
+    resident slab's reduce (``_ring_local_pipelined``); both are
+    bit-for-bit equal."""
     _require_uniform(pg)
     block = pg.block_size
     nsh = pg.num_shards
     local = _halo_body("ring", overlap)
 
-    def fn(x_local, src, dst_local, mask):
-        out = local(x_local[0], src[0], dst_local[0], mask[0],
-                    block, nsh, axis)
+    def fn(x_local, src, dst, mask):
+        out = local(x_local[0], src[0], dst[0], mask[0], block, nsh, axis)
         return out[None]
 
     return jax.shard_map(
-        fn, mesh=mesh,
-        in_specs=(P(axis, None), P(axis, None), P(axis, None), P(axis, None)),
+        fn, mesh=mesh, in_specs=(P(axis, None),) + (P(axis),) * 3,
         out_specs=P(axis, None), check_vma=False,
-    )(x.reshape(nsh, -1, x.shape[-1]), pg.src, pg.dst_local,
-      pg.mask).reshape(x.shape[0], x.shape[-1])
+    )(x.reshape(nsh, -1, x.shape[-1]),
+      *_halo_edges(pg, "ring")).reshape(x.shape[0], x.shape[-1])
+
+
+def _halo_edges(pg: PartitionedGraph, strategy: str):
+    """The ``(P, ...)`` edge arrays a strategy's halo body reads: the
+    owner buckets for the ring, the flat per-shard lists for the
+    all-gather."""
+    if strategy == "ring":
+        return pg.bucket_src, pg.bucket_dst, pg.bucket_mask
+    return pg.src, pg.dst_local, pg.mask
+
+
+def halo_aggregate(pg: PartitionedGraph, x: jnp.ndarray, mesh: Mesh, *,
+                   op: str = "sum", in_deg=None, strategy: str = "ring",
+                   axis: str = "data", overlap: str = "none") -> jnp.ndarray:
+    """The aggregation half of a distributed layer, in the partition
+    layout: each vertex's in-neighbour sum plus its own row (``op="sum"``),
+    or that over ``in_deg + 1`` (``op="mean"``, a multiply by the
+    reciprocal).  ``x`` is ``(P*block, F)`` sharded over ``axis``; the
+    result is too, so a layer's combine runs on the rows each device
+    owns."""
+    _halo_body(strategy, overlap)     # validate the (strategy, overlap) pair
+    agg = functools.partial(aggregate_ring, overlap=overlap) \
+        if strategy == "ring" else aggregate_allgather
+    out = agg(pg, x, mesh, axis) + x
+    if op == "sum":
+        return out
+    if op != "mean":
+        raise ValueError(f"distributed aggregation is sum or mean, not "
+                         f"{op!r}")
+    deg = jnp.maximum(
+        in_deg.astype(jnp.promote_types(x.dtype, jnp.float32)) + 1.0,
+        1.0)[:, None]
+    deg = pad_features(deg, pg.block_size, pg.num_shards)
+    # reciprocal-multiply normalization (not broadcast division) so the
+    # jitted plan.compile() path stays bit-for-bit equal to eager dispatch
+    return out * (1.0 / jnp.where(deg == 0, 1.0, deg))
 
 
 def halo_bytes(pg: PartitionedGraph, feature_len: int,
@@ -298,10 +345,10 @@ def overlap_model(pg: PartitionedGraph, feature_len: int, machine, *,
         single interconnect link -- ``t_wire_hop = Machine.hop_time(bytes)``
         (per-hop link bandwidth + link latency, NOT the aggregate
         ``interconnect_total``: a ring saturates one link per direction);
-      * per hop, the resident slab's partial combine walks the device's
-        whole local edge list (the owner mask zeroes foreign and padding
-        rows), so per-hop compute is the full aggregation roofline divided
-        by the shard count.
+      * per hop, the resident slab's partial combine is priced as the
+        full aggregation roofline divided by the shard count: a device's
+        whole edge list, where a hop now reads one owner bucket of it
+        (about a P-th), so the model errs toward hiding the wire.
 
     Single-buffered (``overlap="none"``) exposes every hop's wire time;
     the pipelined schedule hides ``min(t_wire, t_comp)`` per hop under the
@@ -443,26 +490,17 @@ def distributed_gcn_layer(pg: PartitionedGraph, x, w, bias, in_deg,
         order = choose_ordering(
             _local_graph_view(pg), int(w.shape[0]), int(w.shape[1]),
             agg_op="mean", n_mlp_layers=1)
-    _halo_body(strategy, overlap)     # validate the (strategy, overlap) pair
-    agg = functools.partial(aggregate_ring, overlap=overlap) \
-        if strategy == "ring" else aggregate_allgather
     if dtype == "bf16":
         x = x.astype(jnp.bfloat16)
         w = w.astype(jnp.bfloat16)
         bias = bias.astype(jnp.bfloat16)
-    deg = jnp.maximum(
-        in_deg.astype(jnp.promote_types(x.dtype, jnp.float32)) + 1.0,
-        1.0)[:, None]
-    deg = pad_features(deg, pg.block_size, pg.num_shards)
-    # reciprocal-multiply normalization (not broadcast division) so the
-    # jitted plan.compile() path stays bit-for-bit equal to eager dispatch
-    rdeg = 1.0 / jnp.where(deg == 0, 1.0, deg)
+    mean = functools.partial(halo_aggregate, pg, mesh=mesh, op="mean",
+                             in_deg=in_deg, strategy=strategy, axis=axis,
+                             overlap=overlap)
     if order == "combine_first":
-        h = _reduce_wire(_mm(x, w), dtype)   # the wire carries the reduced h
-        out = (agg(pg, h, mesh, axis) + h) * rdeg
+        out = mean(_reduce_wire(_mm(x, w), dtype))  # the wire carries h
     else:
-        xw = _reduce_wire(x, dtype)          # the wire carries the reduced x
-        out = _mm((agg(pg, xw, mesh, axis) + xw) * rdeg, w)
+        out = _mm(mean(_reduce_wire(x, dtype)), w)  # the wire carries x
     out = out + bias
     return out.astype(jnp.bfloat16) if dtype == "bf16" else out
 
@@ -586,11 +624,11 @@ def distributed_gcn_layer_2d(p2: Partition2D, x, w, bias, in_deg,
 
     out = jax.shard_map(
         fn, mesh=mesh,
-        in_specs=(P(node_ax, None, feat_ax, None), P(node_ax, None),
-                  P(node_ax, None), P(node_ax, None), P(node_ax, None, None),
+        in_specs=(P(node_ax, None, feat_ax, None), P(node_ax), P(node_ax),
+                  P(node_ax), P(node_ax, None, None),
                   P(None, None), P(None)),
         out_specs=P(node_ax, None, feat_ax, None), check_vma=False,
-    )(x.reshape(nsh, block, q_sh, fb_in), pg.src, pg.dst_local, pg.mask,
+    )(x.reshape(nsh, block, q_sh, fb_in), *_halo_edges(pg, strategy),
       rdeg.reshape(nsh, block, 1), wp, bp)
     out = out.reshape(nsh * block, q_sh * fb_out)
     return out.astype(jnp.bfloat16) if dtype == "bf16" else out
@@ -683,3 +721,36 @@ def schedule_wire_bytes(partition, feature_len: int, *,
     out["total_bytes"] = (out["ppermute_bytes"] + out["all_gather_bytes"]
                           + out["reduce_scatter_bytes"] + out["psum_bytes"])
     return out
+
+
+def halo_counts(partition, feature_len: int, *, strategy: str = "ring",
+                overlap: str = "none", dtype: str = "f32") -> dict:
+    """What one distributed layer's aggregation moves in one forward, in
+    the keys of ``kernels.ops.layout_counts`` summed over the mesh --
+    ``edges`` (real edges, once per feature shard), ``gather_rows`` and
+    ``kernel_slots`` (the edge slots the halo bodies gather: every ring
+    hop reads one owner bucket of ``Eb`` slots, the all-gather body the
+    device's flat list), ``gather_bytes`` (those rows at the exchanged
+    width and wire itemsize) -- plus ``wire_bytes``, what ONE device sends
+    over the halo collectives (``schedule_wire_bytes``: ``(P-1)`` slabs
+    pipelined, ``P`` single-buffered)."""
+    from repro.graph.partition import Partition2D
+    two_d = isinstance(partition, Partition2D)
+    pg = partition.nodes if two_d else partition
+    q = partition.feat_shards if two_d else 1
+    flen = partition.feature_block(feature_len) if two_d else feature_len
+    devices = pg.num_shards * q
+    if strategy == "ring":
+        slots = pg.num_shards * int(pg.bucket_src.shape[-1])
+    else:
+        slots = int(pg.src.shape[-1])
+    rows = devices * slots
+    # only the halo's terms are read: the 2-D reduce-scatter, which
+    # needs the layer's output width, belongs to the combine
+    wire = schedule_wire_bytes(partition, feature_len, strategy=strategy,
+                               overlap=overlap, dtype=dtype,
+                               combine_out_len=feature_len)
+    return {"edges": int(np.asarray(pg.mask).sum()) * q,
+            "gather_rows": rows, "kernel_slots": rows,
+            "gather_bytes": rows * flen * wire_dtype_bytes(dtype),
+            "wire_bytes": wire["ppermute_bytes"] + wire["all_gather_bytes"]}

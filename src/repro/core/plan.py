@@ -25,11 +25,13 @@ ONCE per (graph, model, device) and then replayed on every forward/backward:
     the ``partition_1d`` vertex partition and routes layers through the
     ring / all-gather halo aggregation, with ordering still chosen by the
     same cost model (combine-first shrinks the *collective* term by the
-    same in/out ratio).  With a 2-D mesh (two named axes, e.g.
+    same in/out ratio); GIN sums over the halo and runs its MLP on each
+    device's own rows.  With a 2-D mesh (two named axes, e.g.
     ``jax.make_mesh((4, 2), ("node", "feat"))``), the plan builds the
     ``partition_2d`` node x feature partition instead and routes layers
     through ``distributed_gcn_layer_2d`` -- per-device halo bytes shrink a
-    further Q-fold (the multi-host tier; see docs/planner.md).
+    further Q-fold (the multi-host tier, single-matmul layers only; see
+    docs/planner.md).
 
   * **Locality reordering (paper F4, §5.1 guideline 1).**  Built with
     ``reorder="degree"`` (or ``"auto"``, priced by ``choose_reorder``
@@ -458,22 +460,14 @@ class GraphExecutionPlan:
 
     def _run_distributed(self, lp: LayerPlan, x, weights, bias_post, *,
                          probe=None):
+        """One layer on the mesh: the halo aggregation, then the combine
+        on the rows each device owns.  Single-matmul layers (GCN/SAGE)
+        run both in ``distributed_gcn_layer`` (either phase order); GIN
+        sums first and applies its MLP with the interior ReLU as the local
+        path does (``phases.combine``, scope ``l<i>.combine``)."""
         from repro.core.distributed import (distributed_gcn_layer,
-                                            distributed_gcn_layer_2d)
-        (w, b_inline), = weights  # build_plan guarantees single-matmul layers
-        bias = bias_post if bias_post is not None else b_inline
-        if bias is None:
-            bias = jnp.zeros((w.shape[1],), x.dtype)
-        if self.partition_kind == "2d":
-            thunk = lambda: distributed_gcn_layer_2d(  # noqa: E731
-                self.partition, x, w, bias, self.g.in_deg, self.mesh,
-                order=lp.order, strategy=self.strategy, axes=self.axes,
-                overlap=self.overlap, dtype=self.dtype)
-        else:
-            thunk = lambda: distributed_gcn_layer(  # noqa: E731
-                self.partition, x, w, bias, self.g.in_deg, self.mesh,
-                order=lp.order, strategy=self.strategy, axis=self.axis,
-                overlap=self.overlap, dtype=self.dtype)
+                                            distributed_gcn_layer_2d,
+                                            halo_aggregate)
         # halo feature length: what the exchange moves under this ordering;
         # overlap rides along so the probe prices the schedule that
         # actually dispatched (exposed vs. overlapped collective time);
@@ -483,12 +477,45 @@ class GraphExecutionPlan:
         qerr = 0.0
         if probe is not None and self.dtype != "f32":
             qerr = _quant_err(x, _reduce_in(x, self.dtype))
+        if lp.n_mlp > 1:
+            if self.dtype == "bf16":
+                weights = [(w.astype(jnp.bfloat16),
+                            None if b is None else b.astype(jnp.bfloat16))
+                           for (w, b) in weights]
+            xw = _reduce_in(x, self.dtype)     # the wire carries the reduced x
+            thunk = lambda: halo_aggregate(  # noqa: E731
+                self.partition, xw, self.mesh, op=lp.agg_op,
+                in_deg=self.g.in_deg, strategy=self.strategy,
+                axis=self.axis, overlap=self.overlap)
+        else:
+            (w, b_inline), = weights
+            bias = bias_post if bias_post is not None else b_inline
+            if bias is None:
+                bias = jnp.zeros((w.shape[1],), x.dtype)
+            if self.partition_kind == "2d":
+                thunk = lambda: distributed_gcn_layer_2d(  # noqa: E731
+                    self.partition, x, w, bias, self.g.in_deg, self.mesh,
+                    order=lp.order, strategy=self.strategy, axes=self.axes,
+                    overlap=self.overlap, dtype=self.dtype)
+            else:
+                thunk = lambda: distributed_gcn_layer(  # noqa: E731
+                    self.partition, x, w, bias, self.g.in_deg, self.mesh,
+                    order=lp.order, strategy=self.strategy, axis=self.axis,
+                    overlap=self.overlap, dtype=self.dtype)
+
         def halo():
             with jax.named_scope("halo"):
                 return thunk()
-        return _phase(probe, "distributed", halo, lp=lp,
-                      feature_len=agg_len, overlap=self.overlap,
-                      quant_error=qerr)
+        h = _phase(probe, "distributed", halo, lp=lp,
+                   feature_len=agg_len, overlap=self.overlap,
+                   quant_error=qerr, combine=lp.n_mlp == 1)
+        if lp.n_mlp == 1:
+            return h
+        h = _round(h, self.dtype)
+        h = _round(_phase(probe, "combine",
+                          lambda: phases.combine(h, weights),
+                          lp=lp, dims=lp.dims), self.dtype)
+        return h if bias_post is None else h + bias_post
 
     def instrument(self, machine=None, warmup: int = 0):
         """Wrap this plan for characterization (``repro.profile``).
@@ -528,7 +555,10 @@ class GraphExecutionPlan:
         works iff True -- always, for plans built by the public entry
         points).  ``agg_edges`` / ``agg_gather_rows`` /
         ``agg_kernel_slots`` / ``agg_gather_bytes`` count one forward's
-        Pallas aggregation layout (``agg_counts``; 0 on XLA layers).
+        aggregation layout (``agg_counts``: the Pallas blocking, or the
+        halo's edge slots on a mesh; 0 on local XLA layers), and
+        ``halo_wire_bytes`` what one device sends over the halo
+        (``halo_wire_bytes``; 0 on local plans).
         N.B. one-off Pallas aggregation on an UN-planned graph
         (``kernels.ops.seg_agg`` without a layout) still pays host-side
         regrouping per call and cannot trace -- route repeated work
@@ -536,7 +566,8 @@ class GraphExecutionPlan:
         """
         out = []
         compiled_ok = self.compile_supported
-        for lp, counts in zip(self.layers, self.agg_counts()):
+        for lp, counts, wire in zip(self.layers, self.agg_counts(),
+                                    self.halo_wire_bytes()):
             oc = ordering_cost(self.g, lp.din, lp.dout, lp.order)
             out.append({
                 "layer": lp.index, "kind": lp.kind,
@@ -551,17 +582,23 @@ class GraphExecutionPlan:
                 "dedup": self.dedup,
                 "agg_bytes": oc.agg_bytes, "agg_flops": oc.agg_flops,
                 **{f"agg_{k}": v for k, v in counts.items()},
+                "halo_wire_bytes": wire,
             })
         return out
 
     def agg_counts(self) -> List[Dict[str, int]]:
-        """Per layer, what one forward's Pallas aggregation moves
-        (``kernels.ops.layout_counts``: ``edges``, ``gather_rows``,
-        ``kernel_slots``, ``gather_bytes``) over the layout the layer
-        dispatches: the fused tile's blocking, the dedup level-2 blocking,
-        or the unfused ``agg_layout``.  All 0 on XLA and distributed
-        layers, which build no blocked layout."""
+        """Per layer, what one forward's aggregation moves (``edges``,
+        ``gather_rows``, ``kernel_slots``, ``gather_bytes``): over the
+        Pallas layout the layer dispatches (``kernels.ops.layout_counts``:
+        the fused tile's blocking, the dedup level-2 blocking, or the
+        unfused ``agg_layout``), or, on distributed layers, over the halo
+        bodies' edge slots summed over the mesh
+        (``core.distributed.halo_counts``).  All 0 on local XLA layers,
+        which build no blocked layout."""
         from repro.kernels.ops import layout_counts
+        keys = ("edges", "gather_rows", "kernel_slots", "gather_bytes")
+        if self.distributed:
+            return [{k: c[k] for k in keys} for c in self._halo_counts()]
         dedup = self.dedup_layout
         if dedup is not None and (dedup.num_pairs == 0
                                   or dedup.blocked is None):
@@ -573,10 +610,8 @@ class GraphExecutionPlan:
                 _fused_agg_op(lp) is not None
             bg = dedup.blocked if dedup is not None else (
                 lp.blocked if fused else lp.agg_layout)
-            if not is_pallas(lp.backend) or bg is None or self.distributed:
-                out.append(dict.fromkeys(
-                    ("edges", "gather_rows", "kernel_slots",
-                     "gather_bytes"), 0))
+            if not is_pallas(lp.backend) or bg is None:
+                out.append(dict.fromkeys(keys, 0))
                 continue
             width = lp.din if fused or lp.order == AGGREGATE_FIRST \
                 else lp.dout
@@ -585,6 +620,22 @@ class GraphExecutionPlan:
                 bg, width, 4 if dedup is not None else itemsize, lp.backend,
                 f_out=lp.dims[1] if fused else 0))
         return out
+
+    def halo_wire_bytes(self) -> List[int]:
+        """Per layer, the bytes one device sends over the halo collectives
+        in one forward (``core.distributed.halo_counts``); 0 on local
+        plans."""
+        if not self.distributed:
+            return [0] * self.num_layers
+        return [c["wire_bytes"] for c in self._halo_counts()]
+
+    def _halo_counts(self) -> List[Dict[str, int]]:
+        from repro.core.distributed import halo_counts
+        return [halo_counts(self.partition,
+                            lp.din if lp.order == AGGREGATE_FIRST
+                            else lp.dout, strategy=self.strategy,
+                            overlap=self.overlap, dtype=self.dtype)
+                for lp in self.layers]
 
     def layer_costs(self, layer: int = 0) -> Dict:
         """Analytic per-phase costs of one planned layer (Table 3/4)."""
@@ -620,10 +671,14 @@ class CompiledPlan:
 
         def fwd(params, x):
             self._num_traces += 1   # runs at TRACE time only
-            # the layout this executable reads, as gauges agg.<count>.l<i>
+            # the layout this executable reads, as gauges agg.<count>.l<i>,
+            # and on a mesh the halo's bytes, halo.wire_bytes.l<i>
             for i, counts in enumerate(plan.agg_counts()):
                 for k, v in counts.items():
                     gauge(f"agg.{k}.l{i}", v)
+            if plan.distributed:
+                for i, v in enumerate(plan.halo_wire_bytes()):
+                    gauge(f"halo.wire_bytes.l{i}", v)
             if layer is None:
                 return plan.run_model(params, x)
             return plan.run_layer(params, x, layer=layer)
@@ -1393,12 +1448,12 @@ def build_plan(g: Graph, cfg, in_dim: int, num_classes: int, *,
 
         axes = ("node", "feat")
         if mesh is not None:
-            if cfg.conv == "gin":
-                raise ValueError(
-                    "distributed plans support single-matmul convs "
-                    "(gcn/sage); GIN's interior nonlinearity needs the "
-                    "local path")
             axis_names = tuple(getattr(mesh, "axis_names", ()))
+            if len(axis_names) == 2 and cfg.conv == "gin":
+                raise ValueError(
+                    "2-D (node x feature) plans split each matmul over the "
+                    "feature axis; GIN's interior ReLU needs whole rows -- "
+                    "use a 1-D mesh")
             if len(axis_names) == 2:                       # 2-D: node x feat
                 from repro.graph.partition import partition_2d
                 axes = axis_names

@@ -22,7 +22,7 @@ into (P, ...) arrays consumable by shard_map.
 
 from __future__ import annotations
 
-from typing import NamedTuple
+from typing import NamedTuple, Optional
 
 import jax.numpy as jnp
 import numpy as np
@@ -39,6 +39,19 @@ class PartitionedGraph(NamedTuple):
     vtx_start:  (P,)      int32 first global vertex id of each shard block.
     block_size: python int      vertices per shard (padded).
     num_vertices: python int    real global vertex count.
+
+    The uniform partition (``edge_balanced=False``) also groups each
+    shard's edges by the block that owns their source -- the block a ring
+    hop holds -- so a hop reads only its own bucket (None otherwise):
+
+    bucket_src:  (P, P, Eb) int32 source id LOCAL to its owner block
+                 ``[shard, owner]``; padding slots read rows 0, 1, 2, ...
+    bucket_dst:  (P, P, Eb) int32 local destination, ascending within a
+                 bucket; padding slots hold ``block_size`` (out of range:
+                 ``segment_sum`` drops them) after the real edges.
+    bucket_mask: (P, P, Eb) f32   1.0 for real edges, 0.0 padding.
+
+    ``Eb`` is the largest bucket: a shard holds ``P * Eb`` slots.
     """
 
     src: jnp.ndarray
@@ -47,6 +60,9 @@ class PartitionedGraph(NamedTuple):
     vtx_start: jnp.ndarray
     block_size: int
     num_vertices: int
+    bucket_src: Optional[jnp.ndarray] = None
+    bucket_dst: Optional[jnp.ndarray] = None
+    bucket_mask: Optional[jnp.ndarray] = None
 
     @property
     def num_shards(self) -> int:
@@ -101,9 +117,40 @@ def partition_1d(g: Graph, num_shards: int, edge_balanced: bool = True
         pd[p, :e] = per_dst[p]
         pm[p, :e] = 1.0
     starts = np.array([bounds[p] for p in range(num_shards)], np.int32)
+    buckets = {} if edge_balanced else dict(zip(
+        ("bucket_src", "bucket_dst", "bucket_mask"),
+        (jnp.asarray(a) for a in _owner_buckets(per_src, per_dst, block))))
     return PartitionedGraph(
         src=jnp.asarray(ps), dst_local=jnp.asarray(pd), mask=jnp.asarray(pm),
-        vtx_start=jnp.asarray(starts), block_size=block, num_vertices=v)
+        vtx_start=jnp.asarray(starts), block_size=block, num_vertices=v,
+        **buckets)
+
+
+def _owner_buckets(per_src, per_dst, block: int):
+    """Each shard's edges grouped by the block that owns their source, as
+    ``(P, P, Eb)`` arrays (``PartitionedGraph.bucket_*``).  A shard's
+    edges arrive sorted by destination, and a stable sort by owner keeps
+    that order inside each bucket."""
+    nsh = len(per_src)
+    groups = []
+    for s, d in zip(per_src, per_dst):
+        owner = s // block
+        order = np.argsort(owner, kind="stable")
+        cuts = np.searchsorted(owner[order], np.arange(nsh + 1))
+        groups.append([(s[order[a:b]] - o * block, d[order[a:b]])
+                       for o, (a, b) in enumerate(zip(cuts, cuts[1:]))])
+    eb = max(1, max(len(s) for row in groups for s, _ in row))
+    bs = np.zeros((nsh, nsh, eb), np.int32)
+    bd = np.full((nsh, nsh, eb), block, np.int32)
+    bm = np.zeros((nsh, nsh, eb), np.float32)
+    for p, row in enumerate(groups):
+        for o, (s, d) in enumerate(row):
+            n = len(s)
+            bs[p, o, :n], bd[p, o, :n], bm[p, o, :n] = s, d, 1.0
+            # padding reads distinct rows: on a TPU, many reads of one row
+            # serialise in the gather
+            bs[p, o, n:] = np.arange(eb - n) % block
+    return bs, bd, bm
 
 
 def edge_balance(pg: PartitionedGraph) -> float:

@@ -239,13 +239,16 @@ class _Probe:
             byt = max(agg["bytes"] + comb["bytes"] - saved, 1)
             return agg["flops"] + comb["flops"], byt, 0.0, din, 0.0, 0.0
         if name == "distributed":
-            # whole layer behind shard_map; collective term from the halo
-            # model at the feature length the exchange actually moves, and
-            # the exposed/overlapped wall-time split from the overlap model
-            # priced for the halo schedule the dispatch passed along.
+            # the layer behind shard_map (its combine too, unless the
+            # dispatch records that as a phase of its own: GIN's MLP);
+            # collective term from the halo model at the feature length
+            # the exchange actually moves, and the exposed/overlapped
+            # wall-time split from the overlap model priced for the halo
+            # schedule the dispatch passed along.
             flen = meta["feature_len"]
             agg = aggregate_cost(g, flen, include_self=lp.include_self)
-            comb = combine_cost(v, lp.dims)
+            comb = combine_cost(v, lp.dims if meta.get("combine", True)
+                                else ())
             coll = self._halo_bytes(flen)
             exp_s, ovl_s = self._overlap_times(
                 flen, meta.get("overlap",

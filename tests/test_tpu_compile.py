@@ -195,6 +195,56 @@ def test_gather_tail_keeps_the_tpu_gather_wide(one_chip, monkeypatch):
         == ["128"]
 
 
+def test_gin_ring_forward_fits_four_v5e_chips(topo):
+    """The ``gin-reddit.ring4`` forward compiled for four described v5e
+    chips at the cell's shapes: Reddit's 232,965 rows at F=602, GIN's
+    602-128-128 and 128-128-41 MLPs, 4 blocks of 58,242 rows, owner
+    buckets of 956,740 slots (the hottest owner's edges into one block of
+    the cell's graph).  The buckets are arguments here, so no graph is
+    built: every ring hop gathers one bucket's rows, not a shard's ~2.9M
+    edges, each device's program fits its 16 GB, and the only features
+    that cross chips are the ring's slabs."""
+    import copy
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from repro.launch.mesh import make_mesh
+    v, f, classes, eb = 232_965, 602, 41, 956_740
+    mesh = make_mesh((4,), ("data",), devices=topo.devices)
+    rng = np.random.default_rng(0)
+    g = graph_from_coo(rng.integers(0, v, 64), rng.integers(0, v, 64), v)
+    plan = build_plan(g, PAPER_MODELS["gin"], f, classes, mesh=mesh,
+                      strategy="ring", overlap="pipelined")
+    pg = plan.partition
+    assert pg.block_size == 58_242
+
+    def forward(params, x, bsrc, bdst, bmask):
+        cell = copy.copy(plan)
+        cell.partition = pg._replace(bucket_src=bsrc, bucket_dst=bdst,
+                                     bucket_mask=bmask)
+        return cell.run_model(params, x)
+
+    def on(shape, dtype, *spec):
+        return _shape(shape, dtype, NamedSharding(mesh, P(*spec)))
+
+    params = jax.tree_util.tree_map(
+        lambda a: on(a.shape, a.dtype),
+        jax.eval_shape(plan.init, jax.random.PRNGKey(0)))
+    buckets = [on((4, 4, eb), dt, "data")
+               for dt in (jnp.int32, jnp.int32, jnp.float32)]
+    compiled = jax.jit(forward).lower(
+        params, on((4 * pg.block_size, f), jnp.float32, "data"),
+        *buckets).compile()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.output_size_in_bytes + \
+        mem.temp_size_in_bytes + mem.generated_code_size_in_bytes < 16e9
+    text = compiled.as_text()
+    gathers = re.findall(r"= f32\[(\d+),(\d+)\]\S* gather\(", text)
+    assert {(int(n), int(w)) for n, w in gathers} == {(eb, f), (eb, 128)}
+    assert "collective-permute-start" in text
+    # the logits (41 wide) are gathered for the caller; no feature slab is
+    gathered = re.findall(r"= f32\[([\d,]+)\]\S* all-gather\(", text)
+    assert all(s.endswith(f",{classes}") for s in gathered), gathered
+
+
 def test_planner_refuses_fused_tile_that_overflows_vmem():
     """At F=3703 a square layer's pinned W alone overflows VMEM: the plan
     refuses fused=True at build time instead of emitting a tile Mosaic
