@@ -63,14 +63,18 @@ local).
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.graph.partition import Partition2D, PartitionedGraph
+from repro.core.backend import PALLAS_TPU
+from repro.graph.partition import (Partition2D, PartitionedGraph,
+                                   block_buckets)
+from repro.kernels.ops import (SEG_TILE_E_MAX, _gather_slots,
+                               _seg_agg_kernel, _tile_e, kernel_edges)
 
 
 def pad_features(x: jnp.ndarray, block: int, num_shards: int) -> jnp.ndarray:
@@ -98,35 +102,94 @@ def _local_agg(x_full, src, dst_local, mask, block):
     return jax.ops.segment_sum(rows, dst_local, num_segments=block)
 
 
-def _allgather_local(x_loc, srcl, dstl, mskl, block, nsh, axis):
+def _allgather_local(x_loc, srcl, dstl, mskl, block, nsh, axis,
+                     kernel=None):
     """Per-device all-gather halo body (inside shard_map, over ``axis``)."""
-    del nsh
+    del nsh, kernel
     x_full = jax.lax.all_gather(x_loc, axis, tiled=True)
     return _local_agg(x_full, srcl, dstl, mskl, block)
 
 
-def _hop_partial(buf, k, p, bsrc, bdst, bmsk, block, nsh):
+class HopLayout(NamedTuple):
+    """The ring hops' owner buckets in the ``seg_agg`` kernel's layout
+    (``kernels.ops.kernel_edges``), built once at plan build by
+    :func:`hop_layouts`: bucket ``[shard, owner]`` blocked by destination
+    block of ``tile_m`` rows (``graph.partition.block_buckets``).
+
+    src:  (P, P, nb, emax_p + tail) int32 source ids local to the owner.
+    seg:  (P, P, nb, 1, emax_p) int32 destination row local to its block.
+    mask: (P, P, nb, 1, emax_p) f32 1.0 for real edges, 0.0 padding.
+    """
+
+    src: jnp.ndarray
+    seg: jnp.ndarray
+    mask: jnp.ndarray
+    backend: str
+    tile_m: int
+    tile_e: int
+
+    @property
+    def kernel(self):
+        """The static half a hop dispatches on."""
+        return self.backend, self.tile_m, self.tile_e
+
+
+def hop_layouts(pg: PartitionedGraph, widths, backend: str = PALLAS_TPU):
+    """One :class:`HopLayout` per exchanged row width in ``widths`` (the
+    hop gathers f32 rows: a reduced wire slab is promoted first).  The
+    host blocking is done once, and layers whose widths give the same
+    ``tile_e`` share one padded layout."""
+    tile_m = max(8, min(128, -(-pg.block_size // 8) * 8))
+    src, seg, mask = block_buckets(pg, tile_m)
+    padded = {}
+    out = []
+    for width in widths:
+        tile_e = _tile_e(backend, tile_m, int(width), 0, 4, SEG_TILE_E_MAX)
+        if tile_e not in padded:
+            seg3, mask3, src_p = kernel_edges(seg, mask, tile_e, src,
+                                              backend=backend)
+            padded[tile_e] = HopLayout(src_p, seg3, mask3, backend, tile_m,
+                                       tile_e)
+        out.append(padded[tile_e])
+    return out
+
+
+def _hop_partial(buf, k, p, bsrc, bseg, bmsk, block, nsh, kernel=None):
     """Partial combine of hop k's resident slab: the contributions of the
     block currently held (``(p - k) mod P`` -- ring sends i -> i+1), read
-    from that owner's bucket of the device's edges alone
-    (``PartitionedGraph.bucket_*``: sources local to the held block,
-    destinations ascending, padding masked and out of range).  Shared by
-    BOTH ring schedules so their per-hop math -- and therefore their
+    from that owner's bucket of the device's edges alone.  Shared by BOTH
+    ring schedules so their per-hop math -- and therefore their
     accumulation order -- is structurally identical (bitwise-equal outputs
-    are part of the overlap contract)."""
+    are part of the overlap contract).
+
+    ``kernel`` None: the flat bucket (``PartitionedGraph.bucket_*``:
+    destinations ascending, padding masked and out of range), gathered
+    and summed by XLA's sorted ``segment_sum``.  Otherwise the bucket in
+    the ``seg_agg`` layout (:class:`HopLayout`, ``kernel`` its static
+    half): the rows are gathered into the kernel's slots
+    (``ops._gather_slots``) and reduced by the tier's kernel, then the
+    ``nb * tile_m`` output rows are cut to the block.  Both promote a
+    reduced (bf16) slab to the mask's f32 before the sum."""
     owner = jnp.mod(p - k, nsh)                   # whose block we hold
-    src, dst, msk = (jax.lax.dynamic_index_in_dim(a, owner, keepdims=False)
-                     for a in (bsrc, bdst, bmsk))
-    rows = jnp.take(buf, src, axis=0) * msk[:, None]
+    src, seg, msk = (jax.lax.dynamic_index_in_dim(a, owner, keepdims=False)
+                     for a in (bsrc, bseg, bmsk))
+    if kernel is None:
+        rows = jnp.take(buf, src, axis=0) * msk[:, None]
+        part = jax.ops.segment_sum(rows, seg, num_segments=block,
+                                   indices_are_sorted=True)
+    else:
+        backend, tile_m, tile_e = kernel
+        rows = _gather_slots(
+            buf.astype(jnp.promote_types(buf.dtype, msk.dtype)), src, msk)
+        part = _seg_agg_kernel(backend, rows, seg, msk, tile_m,
+                               tile_e)[:block]
     # the barrier pins each partial's rounding: the pipelined schedule's
     # last partial sits outside the scan, where XLA would otherwise fuse
     # it with the accumulate differently (a 1-ulp drift between schedules)
-    return jax.lax.optimization_barrier(
-        jax.ops.segment_sum(rows, dst, num_segments=block,
-                            indices_are_sorted=True))
+    return jax.lax.optimization_barrier(part)
 
 
-def _ring_local(x_loc, bsrc, bdst, bmsk, block, nsh, axis):
+def _ring_local(x_loc, bsrc, bseg, bmsk, block, nsh, axis, kernel=None):
     """Per-device ring halo body, single-buffered (``overlap="none"``):
     nsh hops of collective_permute over ``axis``, each hop reducing the
     currently-held block's bucket and THEN passing the block onward -- the
@@ -140,7 +203,8 @@ def _ring_local(x_loc, bsrc, bdst, bmsk, block, nsh, axis):
 
     def hop(carry, k):
         buf, acc = carry
-        acc = acc + _hop_partial(buf, k, p, bsrc, bdst, bmsk, block, nsh)
+        acc = acc + _hop_partial(buf, k, p, bsrc, bseg, bmsk, block, nsh,
+                                 kernel)
         buf = jax.lax.ppermute(buf, axis, perm)   # pass block onward
         return (buf, acc), None
 
@@ -154,7 +218,8 @@ def _ring_local(x_loc, bsrc, bdst, bmsk, block, nsh, axis):
     return acc
 
 
-def _ring_local_pipelined(x_loc, bsrc, bdst, bmsk, block, nsh, axis):
+def _ring_local_pipelined(x_loc, bsrc, bseg, bmsk, block, nsh, axis,
+                          kernel=None):
     """Per-device ring halo body, double-buffered (``overlap="pipelined"``).
 
     Each hop issues the ``ppermute`` FIRST -- hop k+1's slab is in flight
@@ -176,7 +241,8 @@ def _ring_local_pipelined(x_loc, bsrc, bdst, bmsk, block, nsh, axis):
     def hop(carry, k):
         buf, acc = carry
         nxt = jax.lax.ppermute(buf, axis, perm)   # in flight during reduce
-        acc = acc + _hop_partial(buf, k, p, bsrc, bdst, bmsk, block, nsh)
+        acc = acc + _hop_partial(buf, k, p, bsrc, bseg, bmsk, block, nsh,
+                                 kernel)
         return (nxt, acc), None
 
     # same promoted accumulator as _ring_local (f32 partials over a reduced
@@ -185,7 +251,8 @@ def _ring_local_pipelined(x_loc, bsrc, bdst, bmsk, block, nsh, axis):
                      jnp.promote_types(x_loc.dtype, bmsk.dtype))
     (buf, acc), _ = jax.lax.scan(hop, (x_loc, acc0), jnp.arange(nsh - 1))
     # last hop: the slab is already resident -- reduce it, send nothing
-    return acc + _hop_partial(buf, nsh - 1, p, bsrc, bdst, bmsk, block, nsh)
+    return acc + _hop_partial(buf, nsh - 1, p, bsrc, bseg, bmsk, block, nsh,
+                              kernel)
 
 
 _STRATEGIES = {"ring": _ring_local, "allgather": _allgather_local}
@@ -195,9 +262,11 @@ _STRATEGIES = {"ring": _ring_local, "allgather": _allgather_local}
 OVERLAP_MODES = ("none", "pipelined")
 
 
-def _halo_body(strategy: str, overlap: str):
+def _halo_body(strategy: str, overlap: str,
+               hops: Optional[HopLayout] = None):
     """Resolve (strategy, overlap) to the per-device halo body, validating
-    the combination: pipelining needs the ring's per-hop structure."""
+    the combination: pipelining and hop layouts need the ring's per-hop
+    structure."""
     if strategy not in _STRATEGIES:
         raise ValueError(f"unknown strategy {strategy!r}; "
                          f"expected one of {sorted(_STRATEGIES)}")
@@ -205,6 +274,9 @@ def _halo_body(strategy: str, overlap: str):
         raise ValueError(
             f"unknown overlap {overlap!r}; expected 'none' | 'pipelined' "
             "('auto' is resolved at plan build -- see choose_overlap)")
+    if hops is not None and strategy != "ring":
+        raise ValueError("hop layouts serve the ring's per-hop reduce; "
+                         "the all-gather halo has none")
     if overlap == "pipelined":
         if strategy != "ring":
             raise ValueError(
@@ -236,34 +308,41 @@ def aggregate_allgather(pg: PartitionedGraph, x: jnp.ndarray, mesh: Mesh,
 
 
 def aggregate_ring(pg: PartitionedGraph, x: jnp.ndarray, mesh: Mesh,
-                   axis: str = "data", *,
-                   overlap: str = "none") -> jnp.ndarray:
+                   axis: str = "data", *, overlap: str = "none",
+                   hops: Optional[HopLayout] = None) -> jnp.ndarray:
     """Ring halo exchange: collective_permutes with a partial reduce per
     hop over the held block's owner bucket.  ``overlap`` picks the
     schedule: ``"none"`` = single-buffered (``_ring_local``),
     ``"pipelined"`` = double-buffered with each send in flight under the
     resident slab's reduce (``_ring_local_pipelined``); both are
-    bit-for-bit equal."""
+    bit-for-bit equal.  ``hops`` (:func:`hop_layouts`) runs each hop's
+    reduce through the ``seg_agg`` kernel; None keeps XLA's
+    ``segment_sum``."""
     _require_uniform(pg)
     block = pg.block_size
     nsh = pg.num_shards
     local = _halo_body("ring", overlap)
+    kernel = None if hops is None else hops.kernel
 
-    def fn(x_local, src, dst, mask):
-        out = local(x_local[0], src[0], dst[0], mask[0], block, nsh, axis)
+    def fn(x_local, src, seg, mask):
+        out = local(x_local[0], src[0], seg[0], mask[0], block, nsh, axis,
+                    kernel)
         return out[None]
 
     return jax.shard_map(
         fn, mesh=mesh, in_specs=(P(axis, None),) + (P(axis),) * 3,
         out_specs=P(axis, None), check_vma=False,
     )(x.reshape(nsh, -1, x.shape[-1]),
-      *_halo_edges(pg, "ring")).reshape(x.shape[0], x.shape[-1])
+      *_halo_edges(pg, "ring", hops)).reshape(x.shape[0], x.shape[-1])
 
 
-def _halo_edges(pg: PartitionedGraph, strategy: str):
+def _halo_edges(pg: PartitionedGraph, strategy: str,
+                hops: Optional[HopLayout] = None):
     """The ``(P, ...)`` edge arrays a strategy's halo body reads: the
-    owner buckets for the ring, the flat per-shard lists for the
-    all-gather."""
+    owner buckets for the ring (blocked for ``seg_agg`` when ``hops`` is
+    given), the flat per-shard lists for the all-gather."""
+    if hops is not None:
+        return hops.src, hops.seg, hops.mask
     if strategy == "ring":
         return pg.bucket_src, pg.bucket_dst, pg.bucket_mask
     return pg.src, pg.dst_local, pg.mask
@@ -271,17 +350,20 @@ def _halo_edges(pg: PartitionedGraph, strategy: str):
 
 def halo_aggregate(pg: PartitionedGraph, x: jnp.ndarray, mesh: Mesh, *,
                    op: str = "sum", in_deg=None, strategy: str = "ring",
-                   axis: str = "data", overlap: str = "none") -> jnp.ndarray:
+                   axis: str = "data", overlap: str = "none",
+                   hops: Optional[HopLayout] = None) -> jnp.ndarray:
     """The aggregation half of a distributed layer, in the partition
     layout: each vertex's in-neighbour sum plus its own row (``op="sum"``),
     or that over ``in_deg + 1`` (``op="mean"``, a multiply by the
     reciprocal).  ``x`` is ``(P*block, F)`` sharded over ``axis``; the
     result is too, so a layer's combine runs on the rows each device
-    owns."""
-    _halo_body(strategy, overlap)     # validate the (strategy, overlap) pair
-    agg = functools.partial(aggregate_ring, overlap=overlap) \
-        if strategy == "ring" else aggregate_allgather
-    out = agg(pg, x, mesh, axis) + x
+    owns.  ``hops``: as in :func:`aggregate_ring` (ring only)."""
+    _halo_body(strategy, overlap, hops)   # validate the combination
+    if strategy == "ring":
+        out = aggregate_ring(pg, x, mesh, axis, overlap=overlap, hops=hops)
+    else:
+        out = aggregate_allgather(pg, x, mesh, axis)
+    out = out + x
     if op == "sum":
         return out
     if op != "mean":
@@ -458,7 +540,8 @@ def _reduce_wire(h: jnp.ndarray, dtype: str) -> jnp.ndarray:
 def distributed_gcn_layer(pg: PartitionedGraph, x, w, bias, in_deg,
                           mesh: Mesh, *, order: Optional[str] = None,
                           strategy: str = "ring", axis: str = "data",
-                          overlap: str = "none", dtype: str = "f32"):
+                          overlap: str = "none", dtype: str = "f32",
+                          hops: Optional[HopLayout] = None):
     """One distributed GCN layer with explicit phase ordering (Table 4).
 
     combine_first: project locally (embarrassingly parallel GEMM), then
@@ -480,6 +563,9 @@ def distributed_gcn_layer(pg: PartitionedGraph, x, w, bias, in_deg,
     exchanged aggregation operand (per-row scales, f32 accumulate) and
     keeps the GEMM in f32.
 
+    ``hops`` (:func:`hop_layouts`, at the exchanged width) runs the ring
+    hops through the ``seg_agg`` kernel; None keeps ``segment_sum``.
+
     This is the shard_map primitive; model-level code reaches it through a
     ``GraphExecutionPlan`` built with ``mesh=``/``num_shards=`` (core/plan.py)
     rather than calling it with hand-threaded flags.
@@ -496,7 +582,7 @@ def distributed_gcn_layer(pg: PartitionedGraph, x, w, bias, in_deg,
         bias = bias.astype(jnp.bfloat16)
     mean = functools.partial(halo_aggregate, pg, mesh=mesh, op="mean",
                              in_deg=in_deg, strategy=strategy, axis=axis,
-                             overlap=overlap)
+                             overlap=overlap, hops=hops)
     if order == "combine_first":
         out = mean(_reduce_wire(_mm(x, w), dtype))  # the wire carries h
     else:
@@ -522,7 +608,8 @@ def distributed_gcn_layer_2d(p2: Partition2D, x, w, bias, in_deg,
                              mesh: Mesh, *, order: Optional[str] = None,
                              strategy: str = "ring",
                              axes=("node", "feat"),
-                             overlap: str = "none", dtype: str = "f32"):
+                             overlap: str = "none", dtype: str = "f32",
+                             hops: Optional[HopLayout] = None):
     """One GCN layer on a 2-D (node x feature) device mesh (exact).
 
     Device (p, q) owns node block p's rows restricted to feature block q.
@@ -550,7 +637,9 @@ def distributed_gcn_layer_2d(p2: Partition2D, x, w, bias, in_deg,
     path; bf16 halves the node-axis halo slab the ring actually moves
     (the feature-axis reduce-scatter keeps f32 partials -- its cross-
     device sum IS the accumulator); int8-agg fake-quantizes only the
-    exchanged aggregation operand.  Model-level
+    exchanged aggregation operand.  ``hops`` is the node-axis ring's
+    :class:`HopLayout` at the ``F/Q`` column width, as in
+    :func:`distributed_gcn_layer`.  Model-level
     code reaches this through a ``GraphExecutionPlan`` built with a 2-D
     ``mesh=`` (core/plan.py).
     """
@@ -566,7 +655,8 @@ def distributed_gcn_layer_2d(p2: Partition2D, x, w, bias, in_deg,
         from repro.core.scheduler import choose_ordering
         order = choose_ordering(_local_graph_view(pg), f_in, f_out,
                                 agg_op="mean", n_mlp_layers=1)
-    local = _halo_body(strategy, overlap)
+    local = _halo_body(strategy, overlap, hops)
+    kernel = None if hops is None else hops.kernel
 
     if dtype == "bf16":
         x = x.astype(jnp.bfloat16)
@@ -614,10 +704,11 @@ def distributed_gcn_layer_2d(p2: Partition2D, x, w, bias, in_deg,
         if order == "combine_first":
             # the node-axis halo wire carries the reduced combine output
             hq = _reduce_wire(combine(x_loc), dtype)     # (block, fb_out)
-            out = (local(hq, srcl, dl, ml, block, nsh, node_ax) + hq) * rdg
+            out = (local(hq, srcl, dl, ml, block, nsh, node_ax, kernel)
+                   + hq) * rdg
         else:
             xw = _reduce_wire(x_loc, dtype)
-            agg = local(xw, srcl, dl, ml, block, nsh, node_ax)
+            agg = local(xw, srcl, dl, ml, block, nsh, node_ax, kernel)
             out = combine((agg + xw) * rdg)
         out = out + jax.lax.dynamic_slice(bp_, (qi * fb_out,), (fb_out,))
         return out.reshape(1, block, 1, fb_out)
@@ -628,7 +719,7 @@ def distributed_gcn_layer_2d(p2: Partition2D, x, w, bias, in_deg,
                   P(node_ax), P(node_ax, None, None),
                   P(None, None), P(None)),
         out_specs=P(node_ax, None, feat_ax, None), check_vma=False,
-    )(x.reshape(nsh, block, q_sh, fb_in), *_halo_edges(pg, strategy),
+    )(x.reshape(nsh, block, q_sh, fb_in), *_halo_edges(pg, strategy, hops),
       rdeg.reshape(nsh, block, 1), wp, bp)
     out = out.reshape(nsh * block, q_sh * fb_out)
     return out.astype(jnp.bfloat16) if dtype == "bf16" else out
@@ -724,33 +815,39 @@ def schedule_wire_bytes(partition, feature_len: int, *,
 
 
 def halo_counts(partition, feature_len: int, *, strategy: str = "ring",
-                overlap: str = "none", dtype: str = "f32") -> dict:
+                overlap: str = "none", dtype: str = "f32",
+                hops: Optional[HopLayout] = None) -> dict:
     """What one distributed layer's aggregation moves in one forward, in
     the keys of ``kernels.ops.layout_counts`` summed over the mesh --
     ``edges`` (real edges, once per feature shard), ``gather_rows`` and
     ``kernel_slots`` (the edge slots the halo bodies gather: every ring
-    hop reads one owner bucket of ``Eb`` slots, the all-gather body the
-    device's flat list), ``gather_bytes`` (those rows at the exchanged
-    width and wire itemsize) -- plus ``wire_bytes``, what ONE device sends
-    over the halo collectives (``schedule_wire_bytes``: ``(P-1)`` slabs
-    pipelined, ``P`` single-buffered)."""
+    hop reads one owner bucket, the all-gather body the device's flat
+    list), ``gather_bytes`` (those rows at the exchanged width and the
+    itemsize gathered) -- plus ``wire_bytes``, what ONE device sends over
+    the halo collectives (``schedule_wire_bytes``: ``(P-1)`` slabs
+    pipelined, ``P`` single-buffered).  With ``hops`` a hop gathers
+    ``nb * (emax_p + tail)`` f32 rows of which the kernel reads
+    ``nb * emax_p`` slots (:class:`HopLayout`'s shapes); without, the
+    flat bucket's ``Eb`` at the wire itemsize."""
     from repro.graph.partition import Partition2D
     two_d = isinstance(partition, Partition2D)
     pg = partition.nodes if two_d else partition
     q = partition.feat_shards if two_d else 1
     flen = partition.feature_block(feature_len) if two_d else feature_len
-    devices = pg.num_shards * q
-    if strategy == "ring":
-        slots = pg.num_shards * int(pg.bucket_src.shape[-1])
+    itemsize = wire_dtype_bytes(dtype)
+    if hops is not None:
+        # every device runs P hops over its own row of the (P, P) buckets
+        rows, slots, itemsize = q * hops.src.size, q * hops.mask.size, 4
     else:
-        slots = int(pg.src.shape[-1])
-    rows = devices * slots
+        per = pg.bucket_src.shape[-1] * pg.num_shards \
+            if strategy == "ring" else pg.src.shape[-1]
+        rows = slots = pg.num_shards * q * int(per)
     # only the halo's terms are read: the 2-D reduce-scatter, which
     # needs the layer's output width, belongs to the combine
     wire = schedule_wire_bytes(partition, feature_len, strategy=strategy,
                                overlap=overlap, dtype=dtype,
                                combine_out_len=feature_len)
     return {"edges": int(np.asarray(pg.mask).sum()) * q,
-            "gather_rows": rows, "kernel_slots": rows,
-            "gather_bytes": rows * flen * wire_dtype_bytes(dtype),
+            "gather_rows": int(rows), "kernel_slots": int(slots),
+            "gather_bytes": int(rows) * flen * itemsize,
             "wire_bytes": wire["ppermute_bytes"] + wire["all_gather_bytes"]}

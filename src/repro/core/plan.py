@@ -133,7 +133,7 @@ class GraphExecutionPlan:
                  axes: Tuple[str, str] = ("node", "feat"), machine=None,
                  reorder: str = "none", perm=None, overlap: str = "none",
                  dtype: str = "f32", dedup: str = "none",
-                 dedup_layout=None):
+                 dedup_layout=None, hops=None):
         self.g = g                   # the EXECUTION graph (renumbered when
                                      # reorder="degree")
         self.layers: Tuple[LayerPlan, ...] = tuple(layers)
@@ -153,6 +153,10 @@ class GraphExecutionPlan:
                                      # redundancy elimination; never "auto",
                                      # and never "pairs" with zero matches)
         self.dedup_layout = dedup_layout  # graph.dedup.DedupLayout | None
+        # per layer, the ring hops' seg_agg layout (core.distributed
+        # .HopLayout), or None where a hop runs XLA's segment_sum
+        self.hops = tuple(hops) if hops is not None \
+            else (None,) * len(self.layers)
         # perm[old_id] = new_id (graph.reorder.degree_reorder contract);
         # inv[new_id] = old_id.  Device constants the traced ingress/egress
         # gathers close over -- never recomputed per call.
@@ -174,6 +178,14 @@ class GraphExecutionPlan:
     @property
     def distributed(self) -> bool:
         return self.partition is not None
+
+    @property
+    def halo_kernel(self) -> str:
+        """"seg_agg" | "segment_sum" -- what reduces a ring hop's (or the
+        all-gather's) edges on a distributed plan; "none" on a local one."""
+        if not self.distributed:
+            return "none"
+        return "segment_sum" if self.hops[0] is None else "seg_agg"
 
     @property
     def partition_kind(self) -> str:
@@ -474,6 +486,7 @@ class GraphExecutionPlan:
         # the quant error reported for reduced plans is the layer-ingress
         # operand's (the per-shard exchange operand is shard_map-internal)
         agg_len = lp.din if lp.order == AGGREGATE_FIRST else lp.dout
+        hops = self.hops[lp.index]
         qerr = 0.0
         if probe is not None and self.dtype != "f32":
             qerr = _quant_err(x, _reduce_in(x, self.dtype))
@@ -486,7 +499,7 @@ class GraphExecutionPlan:
             thunk = lambda: halo_aggregate(  # noqa: E731
                 self.partition, xw, self.mesh, op=lp.agg_op,
                 in_deg=self.g.in_deg, strategy=self.strategy,
-                axis=self.axis, overlap=self.overlap)
+                axis=self.axis, overlap=self.overlap, hops=hops)
         else:
             (w, b_inline), = weights
             bias = bias_post if bias_post is not None else b_inline
@@ -496,12 +509,12 @@ class GraphExecutionPlan:
                 thunk = lambda: distributed_gcn_layer_2d(  # noqa: E731
                     self.partition, x, w, bias, self.g.in_deg, self.mesh,
                     order=lp.order, strategy=self.strategy, axes=self.axes,
-                    overlap=self.overlap, dtype=self.dtype)
+                    overlap=self.overlap, dtype=self.dtype, hops=hops)
             else:
                 thunk = lambda: distributed_gcn_layer(  # noqa: E731
                     self.partition, x, w, bias, self.g.in_deg, self.mesh,
                     order=lp.order, strategy=self.strategy, axis=self.axis,
-                    overlap=self.overlap, dtype=self.dtype)
+                    overlap=self.overlap, dtype=self.dtype, hops=hops)
 
         def halo():
             with jax.named_scope("halo"):
@@ -558,7 +571,9 @@ class GraphExecutionPlan:
         aggregation layout (``agg_counts``: the Pallas blocking, or the
         halo's edge slots on a mesh; 0 on local XLA layers), and
         ``halo_wire_bytes`` what one device sends over the halo
-        (``halo_wire_bytes``; 0 on local plans).
+        (``halo_wire_bytes``; 0 on local plans), and ``halo_kernel``
+        what reduces the halo's edges (``"seg_agg"`` | ``"segment_sum"``;
+        ``"none"`` on local plans).
         N.B. one-off Pallas aggregation on an UN-planned graph
         (``kernels.ops.seg_agg`` without a layout) still pays host-side
         regrouping per call and cannot trace -- route repeated work
@@ -582,7 +597,7 @@ class GraphExecutionPlan:
                 "dedup": self.dedup,
                 "agg_bytes": oc.agg_bytes, "agg_flops": oc.agg_flops,
                 **{f"agg_{k}": v for k, v in counts.items()},
-                "halo_wire_bytes": wire,
+                "halo_wire_bytes": wire, "halo_kernel": self.halo_kernel,
             })
         return out
 
@@ -634,8 +649,9 @@ class GraphExecutionPlan:
         return [halo_counts(self.partition,
                             lp.din if lp.order == AGGREGATE_FIRST
                             else lp.dout, strategy=self.strategy,
-                            overlap=self.overlap, dtype=self.dtype)
-                for lp in self.layers]
+                            overlap=self.overlap, dtype=self.dtype,
+                            hops=hops)
+                for lp, hops in zip(self.layers, self.hops)]
 
     def layer_costs(self, layer: int = 0) -> Dict:
         """Analytic per-phase costs of one planned layer (Table 3/4)."""
@@ -1373,6 +1389,12 @@ def build_plan(g: Graph, cfg, in_dim: int, num_classes: int, *,
         counts come from the mesh shape, ``num_shards``/``axis`` are
         ignored.  ``strategy`` ("ring" | "allgather") picks the node-axis
         halo pattern in both distributed forms.
+      * On a mesh the layers run XLA ops, and ``backend`` picks what
+        reduces a ring hop, by the same tier decision as one chip
+        (``describe()``'s ``halo_kernel``): ``pallas-tpu`` (``"auto"`` on
+        a TPU) gathers into ``seg_agg`` over owner buckets blocked once
+        here (``core.distributed.hop_layouts``); ``xla`` (``"auto"`` on
+        the CPU) keeps XLA's ``segment_sum``.
 
     Worked example (local planning, CPU container)::
 
@@ -1556,30 +1578,39 @@ def build_plan(g: Graph, cfg, in_dim: int, num_classes: int, *,
         #    the schedule dispatch will actually run; local plans have no
         #    collective to schedule
         ov = overlap if partition is not None else "none"
-        if ov == "auto":
-            from repro.core.distributed import choose_overlap
+        hops = None
+        if partition is not None:
             from repro.graph.partition import Partition2D
-            from repro.profile.machine import machine_for_backend
             if isinstance(partition, Partition2D):
                 pg_nodes = partition.nodes
                 width = partition.feature_block
             else:
                 pg_nodes, width = partition, (lambda f: f)
-            # one schedule per plan, priced on what each layer's exchange
-            # actually moves (dout under combine-first, din otherwise;
-            # the F/Q column slice on a 2-D partition)
+            # what each layer's exchange actually moves (dout under
+            # combine-first, din otherwise; the F/Q column slice on a
+            # 2-D partition)
             lens = [width(lp.din if lp.order == AGGREGATE_FIRST
                           else lp.dout) for lp in layers]
-            ov = choose_overlap(pg_nodes, lens,
-                                machine or machine_for_backend(XLA),
-                                strategy=strategy)
+            if ov == "auto":
+                from repro.core.distributed import choose_overlap
+                from repro.profile.machine import machine_for_backend
+                # one schedule per plan, priced on every layer's exchange
+                ov = choose_overlap(pg_nodes, lens,
+                                    machine or machine_for_backend(XLA),
+                                    strategy=strategy)
+            # -- the ring hop's reduce: the same tier decision as the
+            #    one-chip aggregation (the layers' own backend stays XLA)
+            if strategy == "ring" and resolve_backend(backend) == PALLAS_TPU:
+                from repro.core.distributed import hop_layouts
+                hops = hop_layouts(pg_nodes, lens, PALLAS_TPU)
 
         return GraphExecutionPlan(
             g_exec, layers, interpret=_plan_interpret(interpret,
                                                       layers[0].backend),
             mesh=mesh, partition=partition, strategy=strategy, axis=axis,
             axes=axes, machine=machine, reorder=decision, perm=perm,
-            overlap=ov, dtype=dt, dedup=dd, dedup_layout=dlayout)
+            overlap=ov, dtype=dt, dedup=dd, dedup_layout=dlayout,
+            hops=hops)
 
     return _cached_plan(g, spec_key, builder)
 

@@ -153,6 +153,35 @@ def _owner_buckets(per_src, per_dst, block: int):
     return bs, bd, bm
 
 
+def block_buckets(pg: PartitionedGraph, tile_m: int):
+    """Each owner bucket ``[shard, owner]`` regrouped by destination block
+    of ``tile_m`` rows, as host arrays ``(P, P, nb, emax)`` with
+    ``nb = ceil(block_size / tile_m)`` and ``emax`` the largest (shard,
+    owner, block) edge count: source ids local to the owner, seg ids local
+    to the destination block (``[0, tile_m)``), and a 1/0 mask; pad slots
+    hold 0 in all three (the gather spreads their reads:
+    ``kernels.ops._gather_slots``).  A bucket's destinations ascend, so
+    its real edges are already in (block, position) order: one pass over
+    them, with no loop over blocks, places them all."""
+    nsh = pg.num_shards
+    nb = -(-pg.block_size // tile_m)
+    real = np.asarray(pg.bucket_mask) > 0
+    shard, owner, _ = np.nonzero(real)
+    dst = np.asarray(pg.bucket_dst)[real]
+    grp = (shard * nsh + owner) * nb + dst // tile_m
+    counts = np.bincount(grp, minlength=nsh * nsh * nb)
+    emax = max(1, int(counts.max(initial=0)))
+    pos = np.arange(len(grp)) - (np.cumsum(counts) - counts)[grp]
+    src = np.zeros((nsh * nsh * nb, emax), np.int32)
+    seg = np.zeros_like(src)
+    mask = np.zeros(src.shape, np.float32)
+    src[grp, pos] = np.asarray(pg.bucket_src)[real]
+    seg[grp, pos] = dst % tile_m
+    mask[grp, pos] = 1.0
+    shape = (nsh, nsh, nb, emax)
+    return src.reshape(shape), seg.reshape(shape), mask.reshape(shape)
+
+
 def edge_balance(pg: PartitionedGraph) -> float:
     """max/mean edge load across shards (1.0 = perfect)."""
     loads = np.asarray(pg.mask).sum(axis=1)

@@ -173,21 +173,25 @@ def kernel_edges(seg_local, mask, tile_e: int, *same_padding,
     rank: on the planned paths the source ids (and edge indices) that the
     rows are then gathered by (``_gather_slots``), with
     ``gather_tail(backend, ...)`` more slots per block; rows the caller
-    grouped itself (``seg_agg_pregrouped``, no backend).
+    grouped itself (``seg_agg_pregrouped``, no backend).  Leading axes
+    before ``nblocks`` are kept: the ring hops' ``(P, P, nb, emax)``
+    owner buckets (``graph.partition.block_buckets``) are padded once, one
+    ``(nb, emax_p + tail)`` gather a hop.
     """
-    nblocks, emax = seg_local.shape
+    *lead, nblocks, emax = seg_local.shape
     emax_p = _round_up(emax, tile_e)
     tail = gather_tail(backend, nblocks, emax_p)
 
     def pad(a, to):
         if to == emax:
             return a
-        return jnp.pad(a, ((0, 0), (0, to - emax))
-                       + ((0, 0),) * (a.ndim - 2))
+        width = [(0, 0)] * a.ndim
+        width[len(lead) + 1] = (0, to - emax)
+        return jnp.pad(a, width)
 
     with jax.named_scope("pad"):
-        return (pad(seg_local, emax_p).reshape(nblocks, 1, emax_p),
-                pad(mask, emax_p).reshape(nblocks, 1, emax_p),
+        return (pad(seg_local, emax_p).reshape(*lead, nblocks, 1, emax_p),
+                pad(mask, emax_p).reshape(*lead, nblocks, 1, emax_p),
                 *(pad(a, emax_p + tail) for a in same_padding))
 
 
@@ -228,8 +232,11 @@ def _gather_slots(x, src, mask):
         spread = np.arange(src.size, dtype=np.int32).reshape(
             src.shape) % x.shape[0]
         ids = jnp.where(edge, src, spread)
-        return jnp.take(x, ids.reshape(-1), axis=0).reshape(
-            nblocks, slots, x.shape[-1])
+        # the ids are in range; "clip" costs an index clamp, where the
+        # default fill mode selects over every gathered row (a second
+        # pass over the slab) unless the ids fold as constants
+        rows = jnp.take(x, ids.reshape(-1), axis=0, mode="clip")
+        return rows.reshape(nblocks, slots, x.shape[-1])
 
 
 # ---------------------------------------------------------------------------

@@ -135,14 +135,18 @@ def test_overlap_in_describe_and_cache_key(pg249):
 
 
 @pytest.mark.slow
-def test_overlapped_halo_bitwise_with_ragged_padding():
+@pytest.mark.parametrize("backend", ["xla", "pallas-tpu"])
+def test_overlapped_halo_bitwise_with_ragged_padding(backend):
     """Satellite regression: with V=249 on 8 shards every device block
     ends in padding rows; the pipelined schedule must produce the SAME
     BITS as the single-buffered one (pad rows never enter a hop's partial
     combine -- their mask zeroes them in _hop_partial), eager and
-    compiled, 1-D and 2-D, and the instrumented report must carry the
-    matching exposed/overlapped split."""
-    out = run_sub("""
+    compiled, 1-D and 2-D, on either hop kernel (``segment_sum``, or
+    ``seg_agg`` on the ``pallas-tpu`` tier), and the instrumented report
+    must carry the matching exposed/overlapped split."""
+    out = run_sub(f"""
+        backend = {backend!r}
+        """ + """
         import dataclasses
         from repro.config import CORA, reduced_graph
         from repro.graph.datasets import make_synthetic_graph, make_features
@@ -162,7 +166,8 @@ def test_overlapped_halo_bitwise_with_ragged_padding():
             for ov in ("none", "pipelined"):
                 plan = build_plan(g, cfg, spec.feature_len,
                                   spec.num_classes, mesh=mesh,
-                                  strategy="ring", overlap=ov)
+                                  strategy="ring", overlap=ov,
+                                  backend=backend)
                 assert plan.overlap == ov
                 with mesh:
                     rep = plan.instrument(machine=TPU_V5E).run_model(

@@ -195,31 +195,52 @@ def test_gather_tail_keeps_the_tpu_gather_wide(one_chip, monkeypatch):
         == ["128"]
 
 
-def test_gin_ring_forward_fits_four_v5e_chips(topo):
+#: the cell's hop shapes: owner buckets of 956,740 slots (the hottest
+#: owner's edges into one block of the cell's graph); blocked for seg_agg,
+#: 456 destination blocks of 128 rows, 2,560 slots each (the fullest block
+#: holds 2,298 edges) and a gather tail of 8
+_RING_HOPS = {"segment_sum": ((956_740,), (956_740,)),
+              "seg_agg": ((456, 2_568), (456, 1, 2_560))}
+
+
+@pytest.mark.parametrize("backend,kernel", [("auto", "segment_sum"),
+                                            ("pallas-tpu", "seg_agg")])
+def test_gin_ring_forward_fits_four_v5e_chips(topo, monkeypatch, backend,
+                                              kernel):
     """The ``gin-reddit.ring4`` forward compiled for four described v5e
     chips at the cell's shapes: Reddit's 232,965 rows at F=602, GIN's
-    602-128-128 and 128-128-41 MLPs, 4 blocks of 58,242 rows, owner
-    buckets of 956,740 slots (the hottest owner's edges into one block of
-    the cell's graph).  The buckets are arguments here, so no graph is
-    built: every ring hop gathers one bucket's rows, not a shard's ~2.9M
-    edges, each device's program fits its 16 GB, and the only features
-    that cross chips are the ring's slabs."""
+    602-128-128 and 128-128-41 MLPs, 4 blocks of 58,242 rows, and each
+    hop's edges in the layout its kernel reads (``_RING_HOPS``).  The hop
+    arrays are arguments here, so no graph is built: every ring hop
+    gathers one bucket's rows, not a shard's ~2.9M edges (on the TPU tier
+    straight into the ``seg_agg`` kernel's slots, with an index count
+    that keeps XLA's gather wide), each device's program fits its 16 GB,
+    and the only features that cross chips are the ring's slabs.  On the
+    CPU, ``"auto"`` plans XLA's ``segment_sum`` hop, as the cell's config
+    does off the chip."""
     import copy
     from jax.sharding import NamedSharding, PartitionSpec as P
     from repro.launch.mesh import make_mesh
-    v, f, classes, eb = 232_965, 602, 41, 956_740
+    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")  # lower for Mosaic
+    v, f, classes = 232_965, 602, 41
     mesh = make_mesh((4,), ("data",), devices=topo.devices)
     rng = np.random.default_rng(0)
     g = graph_from_coo(rng.integers(0, v, 64), rng.integers(0, v, 64), v)
     plan = build_plan(g, PAPER_MODELS["gin"], f, classes, mesh=mesh,
-                      strategy="ring", overlap="pipelined")
+                      strategy="ring", overlap="pipelined", backend=backend)
     pg = plan.partition
     assert pg.block_size == 58_242
+    assert {d["halo_kernel"] for d in plan.describe()} == {kernel}
+    src_shape, seg_shape = _RING_HOPS[kernel]
 
-    def forward(params, x, bsrc, bdst, bmask):
+    def forward(params, x, src, seg, mask):
         cell = copy.copy(plan)
-        cell.partition = pg._replace(bucket_src=bsrc, bucket_dst=bdst,
-                                     bucket_mask=bmask)
+        if kernel == "seg_agg":
+            cell.hops = tuple(h._replace(src=src, seg=seg, mask=mask)
+                              for h in plan.hops)
+        else:
+            cell.partition = pg._replace(bucket_src=src, bucket_dst=seg,
+                                         bucket_mask=mask)
         return cell.run_model(params, x)
 
     def on(shape, dtype, *spec):
@@ -228,17 +249,26 @@ def test_gin_ring_forward_fits_four_v5e_chips(topo):
     params = jax.tree_util.tree_map(
         lambda a: on(a.shape, a.dtype),
         jax.eval_shape(plan.init, jax.random.PRNGKey(0)))
-    buckets = [on((4, 4, eb), dt, "data")
-               for dt in (jnp.int32, jnp.int32, jnp.float32)]
+    hops = [on((4, 4) + shape, dt, "data")
+            for shape, dt in ((src_shape, jnp.int32), (seg_shape, jnp.int32),
+                              (seg_shape, jnp.float32))]
     compiled = jax.jit(forward).lower(
         params, on((4 * pg.block_size, f), jnp.float32, "data"),
-        *buckets).compile()
+        *hops).compile()
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.output_size_in_bytes + \
         mem.temp_size_in_bytes + mem.generated_code_size_in_bytes < 16e9
     text = compiled.as_text()
+    rows = int(np.prod(src_shape))
     gathers = re.findall(r"= f32\[(\d+),(\d+)\]\S* gather\(", text)
-    assert {(int(n), int(w)) for n, w in gathers} == {(eb, f), (eb, 128)}
+    assert {(int(n), int(w)) for n, w in gathers} == {(rows, f),
+                                                      (rows, 128)}
+    assert ("tpu_custom_call" in text) == (kernel == "seg_agg")
+    if kernel == "seg_agg":
+        assert 0 < rows % 1024 <= 896
+        # the gathered rows go to the kernel as they are: no fill-mode
+        # select over them (the hop's ids are not constants)
+        assert not re.search(rf"= f32\[{rows},\d+\]\S* select\(", text)
     assert "collective-permute-start" in text
     # the logits (41 wide) are gathered for the caller; no feature slab is
     gathered = re.findall(r"= f32\[([\d,]+)\]\S* all-gather\(", text)
