@@ -61,7 +61,12 @@ def _pallas_numerics(ctx, _):
     rows = jnp.asarray(rng.standard_normal((nb, emax, f)), jnp.float32)
     seg = jnp.asarray(np.sort(rng.integers(0, tm, (nb, emax))), jnp.int32)
     mask = jnp.ones((nb, emax), jnp.float32)
-    out = ops.seg_agg_pregrouped(rows, seg, mask, tile_m=tm)
+    # the rows are already grouped: slot j of block b gathers row b*emax+j
+    src = jnp.arange(nb * emax, dtype=jnp.int32).reshape(nb, emax)
+    tile_e = ops.kernel_tile_e(tm, f, 4)
+    seg3, mask3, src = ops.kernel_edges(seg, mask, tile_e, src)
+    out = ops.gather_seg_agg(rows.reshape(-1, f), src, seg3, mask3,
+                             tile_m=tm, tile_e=tile_e)
     gseg = (seg + jnp.arange(nb)[:, None] * tm).reshape(-1)
     ref = seg_agg_ref(rows.reshape(-1, f), gseg, mask.reshape(-1), nb * tm)
     ctx.emit("kernels/seg_agg_numerics", 0.0,

@@ -45,7 +45,7 @@ from pathlib import Path
 import jax
 import numpy as np
 
-from repro.core.backend import interpret_for, platform
+from repro.core.backend import default_interpret, platform
 from repro.core.plan import build_plan
 from repro.core.scheduler import AGGREGATE_FIRST, COMBINE_FIRST
 from repro.launch.mesh import make_mesh
@@ -53,7 +53,7 @@ from repro.models.gcn import make_paper_model
 from repro.profile.bench import BenchSpec, run_specs
 from repro.profile.machine import TPU_V5E
 
-BACKENDS = ("xla", "pallas-tpu", "pallas-gpu")
+BACKENDS = ("xla", "pallas-tpu")
 ORDERINGS = (None, COMBINE_FIRST, AGGREGATE_FIRST)  # None = cost model
 FUSION = (False, True)
 
@@ -351,7 +351,7 @@ def post_run(rows, dry: bool = False):
 
     plat = platform()
     compiled = [b for b in BACKENDS
-                if b == "xla" or not interpret_for(b)]
+                if b == "xla" or not default_interpret()]
     interp = [b for b in BACKENDS if b not in compiled]
     print(f"# backend coverage on platform={plat}: compiled natively: "
           f"{','.join(compiled)}; interpret-mode only (numerics validated, "

@@ -32,7 +32,7 @@ sys.path.insert(0, str(ROOT / "src"))
 # shard_map programs over a (8,) / (4, 2) mesh
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 
-LOCAL_BACKENDS = ("xla", "pallas-tpu", "pallas-gpu")
+LOCAL_BACKENDS = ("xla", "pallas-tpu")
 DTYPES = ("f32", "bf16", "int8-agg")
 OVERLAPS = ("none", "pipelined")
 

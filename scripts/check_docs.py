@@ -55,11 +55,11 @@ PUBLIC_SURFACE = {
         "PlannedSageTrainer.restore", "PlannedSageTrainer.predict",
         "train_minibatch_planned",
     ],
-    "repro.kernels.ops": ["seg_agg", "seg_agg_planned"],
-    "repro.core.backend": [
-        "resolve_backend", "interpret_for", "default_interpret",
-        "pallas_tier",
+    "repro.kernels.ops": [
+        "seg_agg_planned", "gather_seg_agg", "kernel_tile_e",
+        "kernel_edges", "layout_counts", "fused_agg_combine",
     ],
+    "repro.core.backend": ["resolve_backend", "default_interpret"],
     "repro.core.distributed": [
         "distributed_gcn_layer", "distributed_gcn_layer_2d",
         "pad_features_2d", "halo_bytes", "halo_bytes_2d",
@@ -119,13 +119,13 @@ CONTENT_REQUIREMENTS = {
         "exposed", "overlapped", "hop_time"],
     ("repro.core.plan", "plan_for_conv"): [">>>"],
     ("repro.core.plan", "plan_for_phases"): [">>>"],
-    ("repro.core.backend", "resolve_backend"): ["auto", "pallas-gpu",
+    ("repro.core.backend", "resolve_backend"): ["auto", "xla",
                                                 "pallas-tpu"],
     ("repro.core.plan", "GraphExecutionPlan.instrument"): [
         ">>>", "WorkloadReport", "machine"],
     ("repro.core.plan", "GraphExecutionPlan.compile"): [
         ">>>", "donate", "retrace", "layer", "dynamic"],
-    ("repro.kernels.ops", "seg_agg"): ["seg_agg_planned", "host"],
+    ("repro.kernels.ops", "gather_seg_agg"): ["kernel_edges", "seg_agg"],
     ("repro.analysis.jaxpr_lint", "lint_plan"): [
         "eager", "compiled", "donate", "dynamic", "never execute"],
     ("repro.analysis.ast_lint", "lint_source"): ["pragma", "allow"],
@@ -139,7 +139,7 @@ CONTENT_REQUIREMENTS = {
 REQUIRED_FILES = {
     ROOT / "README.md": ["Quickstart", "smoke.sh", "chip_smoke.py",
                          "JAX_PLATFORMS=cpu"],
-    ROOT / "docs" / "planner.md": ["decision table", "pallas-gpu",
+    ROOT / "docs" / "planner.md": ["decision table", "pallas-tpu",
                                    "partition_2d", "characterization.md",
                                    "plan.compile", "reorder",
                                    "degree_reorder",
@@ -177,7 +177,7 @@ REQUIRED_FILES = {
         "tracer-branch", "broadcast-div", "acc-dtype", "grid-arity",
         "allow(", "allow-file(", "--strict", "--selftest",
         "wire_collective_bytes", "schedule_wire_bytes",
-        "SEG_AGG_REMEDIATION", "tf.aliasing_output", "planner.md"],
+        "plan_for_phases", "tf.aliasing_output", "planner.md"],
 }
 
 MIN_DOC_LEN = 40  # a one-word docstring is not documentation

@@ -51,15 +51,13 @@ _DEVICE_PREFIXES = ("jnp.", "jax.lax.", "jax.nn.", "jax.ops.", "lax.",
                     "pl.", "pltpu.")
 
 
-def _remediation() -> str:
-    """The host-in-trace fix, verbatim from the runtime error users hit
-    (``repro.kernels.ops.SEG_AGG_REMEDIATION``) -- satellite contract:
-    lint finding and ValueError must agree on the remediation text."""
-    try:
-        from repro.kernels.ops import SEG_AGG_REMEDIATION
-        return SEG_AGG_REMEDIATION
-    except Exception:  # keep the linter usable without jax installed
-        return "dispatch the trace-pure seg_agg_planned instead"
+#: the ``host-in-trace`` fix: keep host work at plan build, and aggregate
+#: through the trace-pure planned entry points
+_HOST_IN_TRACE_FIX = (
+    "move the host work out of the traced function, to plan-build time; "
+    "aggregate through the trace-pure seg_agg_planned -- via a plan from "
+    "build_plan, plan_for_conv, or plan_for_phases (each owns a blocked "
+    "layout)")
 
 
 def _dotted(node: ast.AST) -> str:
@@ -149,7 +147,7 @@ class _FileLint:
             self.add("host-in-trace", "error", line,
                      f"host materialization {label} in a traced/compute "
                      "scope",
-                     f"in function {fn.name!r}; {_remediation()}")
+                     f"in function {fn.name!r}; {_HOST_IN_TRACE_FIX}")
         self._check_tracer_branch(fn, jnp_names)
 
     def _check_tracer_branch(self, fn: ast.FunctionDef,

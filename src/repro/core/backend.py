@@ -3,34 +3,27 @@
 One place answers three questions every execution path used to answer
 ad-hoc (and sometimes wrongly, e.g. a hardcoded ``interpret=True``):
 
-  * which platform are we on (``platform`` / ``on_tpu`` / ``on_gpu``)?
+  * which platform are we on (``platform`` / ``on_tpu``)?
   * should a Pallas kernel run compiled or interpreted
-    (``interpret_for``: compiled only where the kernel's tier matches the
-    real platform, interpreted everywhere else so the whole suite runs on
-    CPU containers; overridable via ``REPRO_PALLAS_INTERPRET``)?
+    (``default_interpret``: compiled on a TPU, interpreted everywhere else
+    so the whole suite runs on CPU containers; overridable via
+    ``REPRO_PALLAS_INTERPRET``)?
   * which aggregation backend should a plan use when asked for "auto"
     (``resolve_backend``)?
 
-Backends form three *tiers*, one per accelerator family the paper's
-guidelines differentiate (F3: specialized aggregation kernels beat the
-generic segmented reduction, but the winning kernel shape depends on the
-memory hierarchy):
+Backends form two *tiers* (paper F3: a specialized aggregation kernel
+beats the generic segmented reduction, and its shape follows the memory
+hierarchy):
 
   * ``"xla"``        -- ``jax.ops.segment_sum``; the portable baseline and
-    the CPU resolution of "auto".
+    the resolution of "auto" everywhere but a TPU.
   * ``"pallas-tpu"`` -- the one-hot-MXU ``seg_agg`` kernel
     (kernels/seg_agg.py): sequential edge-chunk grid dimension with a VMEM
     scratch accumulator; collisions are impossible by construction.
-  * ``"pallas-gpu"`` -- the row-blocked GPU kernel (kernels/gpu_agg.py):
-    one CTA owns one destination row block outright and loops over its
-    edge chunks in-register (GPU grid steps are independent thread blocks,
-    so the TPU trick of accumulating across a sequential grid axis would
-    need atomics -- exactly the serialization the paper measures).
 
-``"pallas"`` is accepted as a legacy alias and resolves to the current
-platform's native Pallas tier.  The execution planner (core/plan.py)
-consults this module once at plan-build time; kernels consult it only when
-a caller passes ``interpret=None``.
+The execution planner (core/plan.py) consults this module once at
+plan-build time; kernels consult it only when a caller passes
+``interpret=None``.
 """
 
 from __future__ import annotations
@@ -42,13 +35,8 @@ import jax
 
 XLA = "xla"
 PALLAS_TPU = "pallas-tpu"
-PALLAS_GPU = "pallas-gpu"
-PALLAS = "pallas"  # legacy alias: the current platform's native Pallas tier
 AUTO = "auto"
-BACKENDS = (XLA, PALLAS_TPU, PALLAS_GPU)
-
-#: platform a Pallas tier compiles natively on (anything else -> interpret)
-_NATIVE_PLATFORM = {PALLAS_TPU: "tpu", PALLAS_GPU: "gpu"}
+BACKENDS = (XLA, PALLAS_TPU)
 
 
 def platform() -> str:
@@ -60,65 +48,23 @@ def on_tpu() -> bool:
     return platform() == "tpu"
 
 
-def on_gpu() -> bool:
-    return platform() == "gpu"
+def default_interpret() -> bool:
+    """Pallas interpret mode: compiled on a TPU, interpreted elsewhere.
 
-
-def pallas_tier() -> str:
-    """The current platform's native Pallas tier (GPU -> pallas-gpu,
-    everything else -> pallas-tpu, which interprets fine off-TPU)."""
-    return PALLAS_GPU if on_gpu() else PALLAS_TPU
-
-
-def is_pallas(backend: str) -> bool:
-    """True for any Pallas tier (including the legacy "pallas" alias)."""
-    return backend in (PALLAS, PALLAS_TPU, PALLAS_GPU)
-
-
-def _interpret_env() -> Optional[bool]:
+    ``REPRO_PALLAS_INTERPRET=0``/``1`` overrides the detection (e.g. to
+    force interpret mode on a TPU while debugging a kernel, or to lower
+    the kernels for Mosaic when compiling for a described TPU).
+    """
     env = os.environ.get("REPRO_PALLAS_INTERPRET")
     if env is not None:
         return env != "0"
-    return None
-
-
-def default_interpret() -> bool:
-    """Pallas interpret mode default: compiled on TPU, interpreted elsewhere.
-
-    ``REPRO_PALLAS_INTERPRET=0``/``1`` overrides the detection (e.g. to force
-    interpret mode on a TPU while debugging a kernel).  Tier-aware callers
-    (the planner, kernels/ops.py) should prefer ``interpret_for(backend)``,
-    which also compiles the GPU tier on real GPUs.
-    """
-    env = _interpret_env()
-    if env is not None:
-        return env
     return not on_tpu()
 
 
-def interpret_for(backend: str) -> bool:
-    """Interpret-mode decision for one backend tier.
-
-    A Pallas kernel compiles only on the platform its tier targets
-    (pallas-tpu on TPU, pallas-gpu on GPU); everywhere else -- including a
-    GPU-tier kernel validated on a CPU container, or a TPU-tier kernel
-    forced onto a GPU box -- it runs in interpret mode so the numerics are
-    still exercised.  ``REPRO_PALLAS_INTERPRET`` overrides either way.
-    """
-    env = _interpret_env()
-    if env is not None:
-        return env
-    if backend == PALLAS:
-        backend = pallas_tier()
-    native = _NATIVE_PLATFORM.get(backend)
-    return platform() != native
-
-
-def resolve_interpret(interpret=None, backend: Optional[str] = None) -> bool:
+def resolve_interpret(interpret: Optional[bool] = None) -> bool:
+    """An explicit ``interpret`` as given, else ``default_interpret()``."""
     if interpret is not None:
         return bool(interpret)
-    if backend is not None:
-        return interpret_for(backend)
     return default_interpret()
 
 
@@ -126,27 +72,27 @@ def default_machine(requested: str = AUTO):
     """Machine preset matching a (possibly unresolved) backend tier.
 
     Resolves the tier first (``resolve_backend``), then maps it to the
-    preset the characterization subsystem models it with: ``pallas-gpu`` ->
-    A100, everything else -> TPU_V5E (``repro.profile.machine``).  Lets
-    plan-level code stay machine-implicit until a caller overrides it.
+    preset the characterization subsystem models it with
+    (``repro.profile.machine.machine_for_backend``).  Lets plan-level code
+    stay machine-implicit until a caller overrides it.
     """
     from repro.profile.machine import machine_for_backend
     return machine_for_backend(resolve_backend(requested))
 
 
 def resolve_backend(requested: str = AUTO) -> str:
-    """Map a requested backend to a concrete tier (never "auto"/"pallas").
+    """Map a requested backend to a concrete tier (never "auto").
 
     Resolution table (paper F3 restated per platform)::
 
-        requested      cpu          gpu          tpu
-        -----------    ----------   ----------   ----------
-        "auto"         xla          pallas-gpu   pallas-tpu
-        "pallas"       pallas-tpu*  pallas-gpu   pallas-tpu
-        "xla" / "pallas-tpu" / "pallas-gpu"   (returned as requested)
+        requested      tpu          anything else (cpu, gpu)
+        -----------    ----------   ------------------------
+        "auto"         pallas-tpu   xla
+        "xla" / "pallas-tpu"        (returned as requested)
 
-    ``*`` = runs in interpret mode there (``interpret_for``), so every tier
-    stays testable on a CPU-only container.
+    An explicit ``"pallas-tpu"`` off a TPU runs in interpret mode
+    (``default_interpret``), so the tier stays testable on a CPU-only
+    container.
 
     Example::
 
@@ -154,22 +100,13 @@ def resolve_backend(requested: str = AUTO) -> str:
         'xla'
         >>> resolve_backend()           # on a CPU container
         'xla'
-        >>> resolve_backend("pallas")   # on a CPU container: TPU tier,
-        'pallas-tpu'                    # auto-interpreted off-TPU
 
-    Raises ``ValueError`` for anything outside ``BACKENDS + (PALLAS, AUTO)``.
+    Raises ``ValueError`` for anything outside ``BACKENDS + (AUTO,)``.
     """
-    if requested == PALLAS:
-        return pallas_tier()
     if requested in BACKENDS:
         return requested
     if requested != AUTO:
         raise ValueError(
             f"unknown backend {requested!r}; expected one of "
-            f"{BACKENDS + (PALLAS, AUTO)}")
-    p = platform()
-    if p == "tpu":
-        return PALLAS_TPU
-    if p == "gpu":
-        return PALLAS_GPU
-    return XLA
+            f"{BACKENDS + (AUTO,)}")
+    return PALLAS_TPU if on_tpu() else XLA

@@ -15,9 +15,6 @@ combined while the next block's edges stream in.  Two backends:
   * ``pallas-tpu`` -- the fused gather->reduce->GEMM kernel
     (kernels/fused_agg_combine.py) where the block accumulator lives in VMEM
     and the weight tile is VMEM-resident across all blocks.
-  * ``pallas-gpu`` -- the row-blocked GPU variant (kernels/gpu_agg.py):
-    one thread block owns one destination block, edge chunks loop in-kernel
-    with a register accumulator (no cross-CTA atomics), coalesced slab loads.
 
 Granularity (``tile_m``) is the paper's "adaptive execution granularity":
 large tiles amortize the weight-tile reuse (compute efficiency), small tiles
@@ -33,7 +30,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.backend import is_pallas
+from repro.core.backend import PALLAS_TPU
 from repro.graph.structure import Graph
 from repro.profile.machine import Machine, machine_for_backend
 
@@ -134,37 +131,19 @@ def suggest_tile_m(in_len: int, out_len: int, avg_deg: float,
     + gathered rows stream (avg_deg*m*in, double-buffered factor 2).
 
     The budget and alignment come from one coherent ``machine``
-    (``repro.profile.Machine``; default: the tier's natural preset via
-    ``machine_for_backend`` -- A100 for ``pallas-gpu``, TPU_V5E otherwise),
-    the paper's F3 point that the winning kernel shape follows the memory
-    hierarchy.  The occupancy model is selected by ``machine.kind`` (NOT by
-    the backend string, so an explicit GPU machine is never priced with the
-    TPU formula or vice versa):
+    (``repro.profile.Machine``; default: the platform's preset via
+    ``machine_for_backend``), the paper's F3 point that the winning kernel
+    shape follows the memory hierarchy: fit one giant tile into half of
+    VMEM (``machine.tile_budget()``) -- a single sequential grid walks the
+    blocks, so bigger tiles only amortize the VMEM-pinned W further --
+    aligned to ``machine.row_align`` rows.
 
-      * ``kind="tpu"``: fit one giant tile into half of VMEM
-        (``machine.tile_budget()``) -- a single sequential grid walks the
-        blocks, so bigger tiles only amortize the VMEM-pinned W further.
-        Sublane alignment (``machine.row_align`` = 8).
-      * ``kind="gpu"``: fit the tile into a *fraction* of the SM's
-        shared-memory carveout (``machine.on_chip_bytes /
-        machine.target_ctas``), because latency hiding comes from multiple
-        resident CTAs per SM, not tile size; W is excluded from the
-        per-CTA budget (read once, served from L2).  Warp alignment
-        (``machine.row_align`` = 32 rows), capped low to keep the CTA
-        count >= SMs.
-
-    ``vmem_budget`` remains as a deprecated TPU-path override; prefer
-    passing a ``machine``.
+    ``vmem_budget`` remains as a deprecated override; prefer passing a
+    ``machine``.
     """
     if machine is None:
         machine = machine_for_backend(backend)
     per_row = (in_len + out_len + 2 * avg_deg * in_len) * dtype_bytes
-    if machine.kind == "gpu":
-        warp = machine.row_align
-        budget = machine.tile_budget()
-        m = max(warp, int(budget / max(per_row, 1)))
-        m = (m // warp) * warp
-        return int(max(warp, min(256, m)))
     align = machine.row_align
     budget = machine.tile_budget() if vmem_budget is None else vmem_budget
     w = in_len * out_len * dtype_bytes
@@ -185,10 +164,10 @@ def fused_gcn_layer(bg: BlockedGraph, x: jnp.ndarray, w: jnp.ndarray,
     x: (V, F_in) padded to block multiple internally.  w: (F_in, F_out).
     """
     from repro.core.phases import _mm
-    if is_pallas(backend):
+    if backend == PALLAS_TPU:
         from repro.kernels import ops as kops
         out = kops.fused_agg_combine(bg.src, bg.dstl, bg.mask, x, w,
-                                     tile_m=bg.tile_m, backend=backend)
+                                     tile_m=bg.tile_m)
     else:
         def body(carry, blk):
             src, dstl, mask = blk

@@ -70,11 +70,9 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.core.backend import PALLAS_TPU
 from repro.graph.partition import (Partition2D, PartitionedGraph,
                                    block_buckets)
-from repro.kernels.ops import (SEG_TILE_E_MAX, _gather_slots,
-                               _seg_agg_kernel, _tile_e, kernel_edges)
+from repro.kernels.ops import gather_seg_agg, kernel_edges, kernel_tile_e
 
 
 def pad_features(x: jnp.ndarray, block: int, num_shards: int) -> jnp.ndarray:
@@ -124,17 +122,16 @@ class HopLayout(NamedTuple):
     src: jnp.ndarray
     seg: jnp.ndarray
     mask: jnp.ndarray
-    backend: str
     tile_m: int
     tile_e: int
 
     @property
     def kernel(self):
         """The static half a hop dispatches on."""
-        return self.backend, self.tile_m, self.tile_e
+        return self.tile_m, self.tile_e
 
 
-def hop_layouts(pg: PartitionedGraph, widths, backend: str = PALLAS_TPU):
+def hop_layouts(pg: PartitionedGraph, widths):
     """One :class:`HopLayout` per exchanged row width in ``widths`` (the
     hop gathers f32 rows: a reduced wire slab is promoted first).  The
     host blocking is done once, and layers whose widths give the same
@@ -144,12 +141,10 @@ def hop_layouts(pg: PartitionedGraph, widths, backend: str = PALLAS_TPU):
     padded = {}
     out = []
     for width in widths:
-        tile_e = _tile_e(backend, tile_m, int(width), 0, 4, SEG_TILE_E_MAX)
+        tile_e = kernel_tile_e(tile_m, int(width), 4)
         if tile_e not in padded:
-            seg3, mask3, src_p = kernel_edges(seg, mask, tile_e, src,
-                                              backend=backend)
-            padded[tile_e] = HopLayout(src_p, seg3, mask3, backend, tile_m,
-                                       tile_e)
+            seg3, mask3, src_p = kernel_edges(seg, mask, tile_e, src)
+            padded[tile_e] = HopLayout(src_p, seg3, mask3, tile_m, tile_e)
         out.append(padded[tile_e])
     return out
 
@@ -166,8 +161,8 @@ def _hop_partial(buf, k, p, bsrc, bseg, bmsk, block, nsh, kernel=None):
     destinations ascending, padding masked and out of range), gathered
     and summed by XLA's sorted ``segment_sum``.  Otherwise the bucket in
     the ``seg_agg`` layout (:class:`HopLayout`, ``kernel`` its static
-    half): the rows are gathered into the kernel's slots
-    (``ops._gather_slots``) and reduced by the tier's kernel, then the
+    half): the rows are gathered into the kernel's slots and reduced by
+    ``seg_agg`` (``kernels.ops.gather_seg_agg``, as on one chip), then the
     ``nb * tile_m`` output rows are cut to the block.  Both promote a
     reduced (bf16) slab to the mask's f32 before the sum."""
     owner = jnp.mod(p - k, nsh)                   # whose block we hold
@@ -178,11 +173,10 @@ def _hop_partial(buf, k, p, bsrc, bseg, bmsk, block, nsh, kernel=None):
         part = jax.ops.segment_sum(rows, seg, num_segments=block,
                                    indices_are_sorted=True)
     else:
-        backend, tile_m, tile_e = kernel
-        rows = _gather_slots(
-            buf.astype(jnp.promote_types(buf.dtype, msk.dtype)), src, msk)
-        part = _seg_agg_kernel(backend, rows, seg, msk, tile_m,
-                               tile_e)[:block]
+        tile_m, tile_e = kernel
+        part = gather_seg_agg(
+            buf.astype(jnp.promote_types(buf.dtype, msk.dtype)), src, seg,
+            msk, tile_m=tile_m, tile_e=tile_e)[:block]
     # the barrier pins each partial's rounding: the pipelined schedule's
     # last partial sits outside the scan, where XLA would otherwise fuse
     # it with the accumulate differently (a 1-ulp drift between schedules)

@@ -26,7 +26,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro.core.backend import is_pallas, resolve_backend
+from repro.core.backend import PALLAS_TPU
 from repro.graph.structure import Graph
 
 AGGREGATORS = ("sum", "mean", "max")
@@ -85,16 +85,13 @@ def aggregate(g: Graph, x: jnp.ndarray, op: str = "mean",
       edge_weight: optional (E,) per-edge scalar (e.g. sym-norm GCN weights).
       edge_mask: optional (E,) 1/0 mask for padded edge lists.
       include_self: add the vertex's own row to the reduction.
-      backend: "xla" (segment_sum) or a Pallas tier ("pallas-tpu" |
-        "pallas-gpu"; legacy "pallas" = platform's native tier); None = xla.
-        Normally resolved by the execution planner (core/plan.py).
-      layout: plan-owned ``core.dataflow.BlockedGraph`` for the Pallas
-        tiers.  With a layout the Pallas dispatch is TRACE-PURE
-        (``kernels.ops.seg_agg_planned``: the O(E) regrouping was done once
-        at plan-build time); without one, one-off Pallas calls fall back to
-        the slow ad-hoc ``kernels.ops.seg_agg``, which regroups on the host
-        per call and cannot run under jit.  Plans always pass it
-        (``LayerPlan.agg_layout``).
+      backend: a resolved tier, "xla" (segment_sum) or "pallas-tpu";
+        None = xla.  Resolved by the execution planner (core/plan.py).
+      layout: plan-owned ``core.dataflow.BlockedGraph`` the "pallas-tpu"
+        tier aggregates over (``kernels.ops.seg_agg_planned``: the O(E)
+        regrouping was done once at plan-build time, so the dispatch is
+        trace-pure).  Plans always pass it (``LayerPlan.agg_layout``);
+        the Pallas tier refuses to run without one.
       dedup: plan-owned ``graph.dedup.DedupLayout`` two-level layout.
         When given (sum/mean, unweighted/unmasked only — the planner
         guarantees this), aggregation runs redundancy-eliminated: level 1
@@ -110,7 +107,7 @@ def aggregate(g: Graph, x: jnp.ndarray, op: str = "mean",
     if edge_mask is not None:
         w = edge_mask if w is None else w * edge_mask
 
-    use_pallas = backend is not None and is_pallas(backend)
+    use_pallas = backend == PALLAS_TPU
 
     if dedup is not None and dedup.num_pairs > 0 and op in ("sum", "mean") \
             and w is None:
@@ -123,8 +120,7 @@ def aggregate(g: Graph, x: jnp.ndarray, op: str = "mean",
         xp = jnp.concatenate([xf, partials], axis=0)
         if use_pallas and dedup.blocked is not None:
             from repro.kernels import ops as kops
-            summed = kops.seg_agg_planned(dedup.blocked, xp, None,
-                                          backend=resolve_backend(backend))
+            summed = kops.seg_agg_planned(dedup.blocked, xp, None)
         else:
             gathered2 = jnp.take(xp, dedup.src2, axis=0)
             summed = jax.ops.segment_sum(gathered2, dedup.dst2,
@@ -148,16 +144,15 @@ def aggregate(g: Graph, x: jnp.ndarray, op: str = "mean",
         return jnp.where(jnp.isfinite(out), out, 0.0)
 
     if use_pallas:
+        if layout is None:
+            raise ValueError(
+                "the pallas-tpu tier aggregates over a plan-owned blocked "
+                "layout: dispatch through a plan from build_plan, "
+                "plan_for_conv, or plan_for_phases, or call "
+                "kernels.ops.seg_agg_planned with a "
+                "core.dataflow.block_graph layout")
         from repro.kernels import ops as kops
-        if layout is not None:
-            summed = kops.seg_agg_planned(layout, x, w,
-                                          backend=resolve_backend(backend))
-        else:
-            gathered = jnp.take(x, g.src, axis=0)
-            if w is not None:
-                gathered = gathered * w[:, None].astype(gathered.dtype)
-            summed = kops.seg_agg(gathered, g.dst, v,
-                                  backend=resolve_backend(backend))
+        summed = kops.seg_agg_planned(layout, x, w)
     else:
         if w is not None:
             gathered = gathered * w[:, None].astype(gathered.dtype)

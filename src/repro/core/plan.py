@@ -10,11 +10,11 @@ ONCE per (graph, model, device) and then replayed on every forward/backward:
     (Reddit 602->128: 4.7x fewer aggregation bytes), and honors semantic
     pins (GIN's interior ReLU forces aggregate-first).
   * **Collision-free aggregation backend (paper F3).**  XLA
-    ``segment_sum`` vs a specialized Pallas kernel tier, chosen by
-    platform ("auto" = pallas-tpu on TPU, pallas-gpu on GPU, XLA on CPU --
-    ``backend.resolve_backend``); interpret mode is auto-detected per tier
-    (``backend.interpret_for``) instead of the old hardcoded
-    ``interpret=True``, so every tier validates on a CPU container.
+    ``segment_sum`` vs the specialized Pallas TPU kernel tier, chosen by
+    platform ("auto" = pallas-tpu on TPU, XLA everywhere else --
+    ``backend.resolve_backend``); interpret mode is auto-detected
+    (``backend.default_interpret``: compiled on a TPU, interpreted
+    elsewhere), so the Pallas tier validates on a CPU container.
   * **Inter-phase dataflow fusion (paper F5, §5.1-3).**  The fused
     aggregate->combine tile executor needs a ``BlockedGraph`` regrouping
     of the edge list and a VMEM-budgeted ``tile_m``; the plan builds both
@@ -76,9 +76,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import phases
-from repro.core.backend import (AUTO, PALLAS_GPU, PALLAS_TPU, XLA,
-                                interpret_for, is_pallas, resolve_backend,
-                                resolve_interpret)
+from repro.core.backend import (AUTO, PALLAS_TPU, XLA, default_interpret,
+                                resolve_backend, resolve_interpret)
 from repro.core.dataflow import (BlockedGraph, block_graph, fused_gcn_layer,
                                  suggest_tile_m)
 from repro.core.scheduler import (AGGREGATE_FIRST, COMBINE_FIRST,
@@ -101,8 +100,8 @@ class LayerPlan:
     agg_op: str               # "sum" | "mean" | "max"
     include_self: bool
     order: str                # COMBINE_FIRST | AGGREGATE_FIRST (resolved)
-    backend: str              # "xla" | "pallas-tpu" | "pallas-gpu"
-                              # (resolved, never "auto"/"pallas")
+    backend: str              # "xla" | "pallas-tpu" (resolved, never
+                              # "auto")
     fused: bool               # inter-phase dataflow fusion (F5)
     tile_m: int               # fused tile rows (0 when unfused)
     blocked: Optional[BlockedGraph]  # shared BlockedGraph (None when unfused)
@@ -202,7 +201,7 @@ class GraphExecutionPlan:
         ``plan.compile()`` traces with zero host transfers.  Plans built by
         the public entry points always qualify; False only for hand-built
         plans missing ``agg_layout``."""
-        return all(not is_pallas(lp.backend) or lp.agg_layout is not None
+        return all(lp.backend != PALLAS_TPU or lp.agg_layout is not None
                    for lp in self.layers)
 
     # -- parameter helpers --------------------------------------------------
@@ -359,7 +358,7 @@ class GraphExecutionPlan:
         if self.perm is not None:
             problems.append("reordered plans bake an edge-derived permute")
         for lp in self.layers:
-            if is_pallas(lp.backend) or lp.fused:
+            if lp.backend == PALLAS_TPU or lp.fused:
                 problems.append(
                     f"layer {lp.index} ({lp.backend}"
                     f"{', fused' if lp.fused else ''}) bakes a host-built "
@@ -574,10 +573,6 @@ class GraphExecutionPlan:
         (``halo_wire_bytes``; 0 on local plans), and ``halo_kernel``
         what reduces the halo's edges (``"seg_agg"`` | ``"segment_sum"``;
         ``"none"`` on local plans).
-        N.B. one-off Pallas aggregation on an UN-planned graph
-        (``kernels.ops.seg_agg`` without a layout) still pays host-side
-        regrouping per call and cannot trace -- route repeated work
-        through a plan.
         """
         out = []
         compiled_ok = self.compile_supported
@@ -625,14 +620,14 @@ class GraphExecutionPlan:
                 _fused_agg_op(lp) is not None
             bg = dedup.blocked if dedup is not None else (
                 lp.blocked if fused else lp.agg_layout)
-            if not is_pallas(lp.backend) or bg is None:
+            if lp.backend != PALLAS_TPU or bg is None:
                 out.append(dict.fromkeys(keys, 0))
                 continue
             width = lp.din if fused or lp.order == AGGREGATE_FIRST \
                 else lp.dout
             # the dedup path gathers from an f32 [x ; partials] concat
             out.append(layout_counts(
-                bg, width, 4 if dedup is not None else itemsize, lp.backend,
+                bg, width, 4 if dedup is not None else itemsize,
                 f_out=lp.dims[1] if fused else 0))
         return out
 
@@ -1209,14 +1204,14 @@ def _plan_layer(g: Graph, index: int, kind: str, dims: Tuple[int, ...], *,
     backend = resolve_backend(backend)
     fused = bool(fused) and agg_op in ("sum", "mean")
     tile_m, blocked = 0, None
-    align = 32 if backend == PALLAS_GPU else 8
+    align = 8
     if fused:
         avg_deg = g.num_edges / max(1, g.num_vertices)
         tile_m = suggest_tile_m(dims[0], dims[1], avg_deg,
                                 dtype_bytes=2 if dtype == "bf16" else 4,
                                 backend=backend, machine=machine)
         # a tile larger than the graph only pads; clamp to |V| rounded up,
-        # keeping the tier's alignment (warp rows on GPU, sublanes on TPU)
+        # keeping the sublane alignment
         tile_m = max(align, min(tile_m, -(-g.num_vertices // align) * align))
         if backend == PALLAS_TPU:
             # the kernel's own working set against the VMEM limit it is
@@ -1225,11 +1220,11 @@ def _plan_layer(g: Graph, index: int, kind: str, dims: Tuple[int, ...], *,
             # tile fits
             from repro.kernels.ops import fit_fused_tile_m, tpu_vmem_budget
             tile_m = fit_fused_tile_m(tile_m, dims[0], dims[1], 4,
-                                      budget=tpu_vmem_budget(backend),
+                                      budget=tpu_vmem_budget(),
                                       align=align)
         blocked = _blocked_for(g, tile_m)
     agg_layout = None
-    if backend in (PALLAS_TPU, PALLAS_GPU):
+    if backend == PALLAS_TPU:
         # plan-owned layout for the UNFUSED seg_agg path (also the fusion
         # fallback's), so dispatch never regroups on the host (trace-pure)
         atile = max(align, min(128, -(-g.num_vertices // align) * align))
@@ -1238,17 +1233,6 @@ def _plan_layer(g: Graph, index: int, kind: str, dims: Tuple[int, ...], *,
                      agg_op=agg_op, include_self=include_self, order=order,
                      backend=backend, fused=fused, tile_m=tile_m,
                      blocked=blocked, agg_layout=agg_layout)
-
-
-def _plan_interpret(interpret, backend: str) -> bool:
-    """Plan-level interpret flag: tier-aware for Pallas backends (compiled
-    only on the tier's native platform -- ``backend.interpret_for``),
-    platform default otherwise, explicit override always wins."""
-    if interpret is not None:
-        return bool(interpret)
-    if backend in (PALLAS_TPU, PALLAS_GPU):
-        return interpret_for(backend)
-    return resolve_interpret(None)
 
 
 def _mesh_key(mesh):
@@ -1359,8 +1343,8 @@ def build_plan(g: Graph, cfg, in_dim: int, num_classes: int, *,
         (fanout-regular sampled blocks), "none" on sparse full-graph
         layers where few destinations share a leading pair.
 
-    Dedup applies to the sum/mean aggregation paths (XLA, both Pallas
-    tiers, and the fused executor); distributed plans and ``max``
+    Dedup applies to the sum/mean aggregation paths (XLA, the Pallas
+    tier, and the fused executor); distributed plans and ``max``
     aggregation coerce it to "none".  The resolved mode is stored on the
     plan (``plan.dedup``), surfaced in ``describe()``, recorded by
     ``plan.instrument()`` (``dedup_pairs`` / ``dedup_flops_saved``), and
@@ -1563,13 +1547,11 @@ def build_plan(g: Graph, cfg, in_dim: int, num_classes: int, *,
                     num_pairs=pcap, num_edges2=ecap)
             if dd == "pairs":
                 if any(lp.fused and lp.blocked is not None for lp in layers) \
-                        or any(is_pallas(lp.backend) for lp in layers):
+                        or any(lp.backend == PALLAS_TPU for lp in layers):
                     tiles = [lp.blocked.tile_m for lp in layers
                              if lp.fused and lp.blocked is not None]
-                    align = 32 if layers[0].backend == PALLAS_GPU else 8
                     atile = tiles[0] if tiles else max(
-                        align, min(128, -(-g_exec.num_vertices // align)
-                                   * align))
+                        8, min(128, -(-g_exec.num_vertices // 8) * 8))
                     lay = attach_blocked(lay, atile)
                 dlayout = lay
 
@@ -1602,11 +1584,10 @@ def build_plan(g: Graph, cfg, in_dim: int, num_classes: int, *,
             #    one-chip aggregation (the layers' own backend stays XLA)
             if strategy == "ring" and resolve_backend(backend) == PALLAS_TPU:
                 from repro.core.distributed import hop_layouts
-                hops = hop_layouts(pg_nodes, lens, PALLAS_TPU)
+                hops = hop_layouts(pg_nodes, lens)
 
         return GraphExecutionPlan(
-            g_exec, layers, interpret=_plan_interpret(interpret,
-                                                      layers[0].backend),
+            g_exec, layers, interpret=resolve_interpret(interpret),
             mesh=mesh, partition=partition, strategy=strategy, axis=axis,
             axes=axes, machine=machine, reorder=decision, perm=perm,
             overlap=ov, dtype=dt, dedup=dd, dedup_layout=dlayout,
@@ -1653,7 +1634,7 @@ def plan_for_conv(conv, g: Graph, *, machine=None) -> GraphExecutionPlan:
                          ordering=conv.ordering, backend=backend,
                          fused=fused, machine=machine)
         return GraphExecutionPlan(g, [lp],
-                                  interpret=_plan_interpret(None, lp.backend),
+                                  interpret=default_interpret(),
                                   machine=machine)
 
     return _cached_plan(g, spec_key, builder)
@@ -1692,7 +1673,7 @@ def plan_for_phases(g: Graph, weights, *, order: Optional[str] = None,
                          ordering=order or AUTO, backend=backend,
                          fused=fused, machine=machine)
         return GraphExecutionPlan(g, [lp],
-                                  interpret=_plan_interpret(None, lp.backend),
+                                  interpret=default_interpret(),
                                   machine=machine)
 
     return _cached_plan(g, spec_key, builder)

@@ -31,7 +31,7 @@ hold its bitwise f32 contract with dedup enabled (tests/test_dedup.py).
 
 The layout is plan-owned and trace-pure: ``build_dedup_layout`` runs once
 at plan-build time (O(E) numpy), the arrays it emits are consumed by the
-XLA path and both Pallas tiers (``attach_blocked`` pre-blocks the level-2
+XLA path and the Pallas tier (``attach_blocked`` pre-blocks the level-2
 edge list for ``kernels.ops.seg_agg_planned``), and the padding helper
 (``pad_dedup_arrays``) extends a block's layout to a bucket's static
 shapes with sink no-ops so ONE compiled callable serves every
@@ -63,7 +63,7 @@ class DedupLayout(NamedTuple):
     Static python ints (``num_pairs``/``num_edges2``/``matched_edges``/
     ``naive_edges``/``num_vertices``) are compile-time shape facts;
     ``blocked`` is the optional plan-time level-2 ``BlockedGraph`` for the
-    Pallas tiers (``attach_blocked``).
+    Pallas tier (``attach_blocked``).
     """
 
     pair_left: jnp.ndarray      # (P,) int32 first member of each pair
@@ -155,7 +155,7 @@ def dedup_layout_for_graph(g, *, min_frequency: int = 2) -> DedupLayout:
 
 
 def attach_blocked(layout: DedupLayout, tile_m: int) -> DedupLayout:
-    """Pre-block the level-2 edge list for the Pallas tiers (plan time).
+    """Pre-block the level-2 edge list for the Pallas tier (plan time).
 
     The blocked layout's gather sources index the (V + P)-row virtual
     concatenation, so it must be built by ``core.dataflow

@@ -1,10 +1,23 @@
-"""Public jit'd wrappers for the Pallas kernels.
+"""The Pallas TPU tier: public jit'd entry points for the aggregation
+kernels.
 
 These own tile selection (VMEM-budget-aware, MXU-aligned), static-shape
-padding, and the host<->kernel layout glue so the rest of the framework calls
-plain functions.  Interpret mode is auto-detected per platform
-(``core.backend.default_interpret``: interpreted off-TPU, compiled on TPU;
-override with ``REPRO_PALLAS_INTERPRET=0/1``).
+padding, and the glue between a plan's blocked layout and the kernels'
+edge layout, so the planner and the ring hops call plain functions:
+
+  * ``kernel_tile_e`` -- the edge chunk a kernel streams per grid step;
+  * ``kernel_edges`` / ``gather_tail`` / ``layout_counts`` -- the edge
+    layout the kernels read, and what one aggregation over it moves;
+  * ``gather_seg_agg`` -- gather rows into that layout, then ``seg_agg``:
+    the one composition both ``seg_agg_planned`` (one chip) and the ring
+    hop (``core.distributed``) run;
+  * ``seg_agg_planned`` / ``fused_agg_combine`` -- aggregation over a
+    plan-owned ``BlockedGraph``.
+
+The planner has already chosen this tier when it calls them, so none takes
+a backend.  The kernels run compiled on a TPU and interpreted elsewhere
+(``core.backend.default_interpret``; ``REPRO_PALLAS_INTERPRET=0/1``
+overrides).
 """
 
 from __future__ import annotations
@@ -13,32 +26,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.backend import PALLAS_GPU, PALLAS_TPU
-from repro.core.backend import default_interpret as _interpret
-from repro.core.backend import interpret_for, resolve_backend
-from repro.kernels import ref as kref
-from repro.profile.machine import machine_for_backend
-from repro.kernels.flash_attention import flash_attention as _flash
+from repro.core.backend import PALLAS_TPU
 from repro.kernels.fused_agg_combine import fused_agg_combine_blocked
-from repro.kernels.gpu_agg import (fused_agg_combine_gpu_blocked,
-                                   seg_agg_gpu_blocked)
 from repro.kernels.seg_agg import seg_agg_blocked
+from repro.profile.machine import machine_for_backend
 
 
 def _round_up(x: int, m: int) -> int:
     return -(-x // m) * m
-
-
-#: ONE remediation text shared by the ``seg_agg`` tracing ValueError and
-#: the ``host-in-trace`` AST lint rule (repro.analysis.ast_lint), so the
-#: error a user hits and the finding a reviewer reads agree verbatim on
-#: the fix: route through the trace-pure planned entry points.
-SEG_AGG_REMEDIATION = (
-    "seg_agg regroups edges on the host and cannot run inside jit/grad; "
-    "dispatch the trace-pure seg_agg_planned instead -- via a plan from "
-    "build_plan, plan_for_conv, or plan_for_phases (each owns a blocked "
-    "layout), or call seg_agg_planned directly with a "
-    "core.dataflow.block_graph layout")
 
 
 # ---------------------------------------------------------------------------
@@ -50,10 +45,6 @@ SEG_AGG_REMEDIATION = (
 SEG_TILE_E_MAX = 512
 FUSED_TILE_E_MAX = 2048
 _LANE = 128
-
-#: GPU tier: the edge chunk shares the SM with ``A100.target_ctas`` peers,
-#: so it stays a small warp-aligned slab, not a VMEM-budgeted one.
-_GPU_TILE_E = 128
 
 
 def tpu_vmem_bytes(tile_m: int, tile_e: int, f_in: int, f_out: int = 0,
@@ -87,10 +78,10 @@ def tpu_vmem_bytes(tile_m: int, tile_e: int, f_in: int, f_out: int = 0,
     return total
 
 
-def tpu_vmem_budget(backend: str = PALLAS_TPU) -> int:
+def tpu_vmem_budget() -> int:
     """Scoped VMEM the TPU kernels request and their tiles are sized
     against: the device's ``Machine.tile_budget()``."""
-    return machine_for_backend(backend).tile_budget()
+    return machine_for_backend(PALLAS_TPU).tile_budget()
 
 
 def pick_tile_e(tile_m: int, f_in: int, f_out: int = 0, itemsize: int = 4,
@@ -123,18 +114,21 @@ def fit_fused_tile_m(tile_m: int, f_in: int, f_out: int, itemsize: int,
     return m
 
 
-def _tile_e(backend: str, tile_m: int, f_in: int, f_out: int,
-            itemsize: int, cap: int) -> int:
-    if backend == PALLAS_GPU:
-        return _GPU_TILE_E
-    tile_e = pick_tile_e(tile_m, f_in, f_out, itemsize,
-                         budget=tpu_vmem_budget(backend), cap=cap)
+def kernel_tile_e(tile_m: int, f_in: int, itemsize: int,
+                  f_out: int = 0) -> int:
+    """The edge chunk a kernel streams per grid step: ``seg_agg``'s
+    (``f_out=0``, at most ``SEG_TILE_E_MAX``) or the fused kernel's with a
+    ``(f_in, f_out)`` weight (at most ``FUSED_TILE_E_MAX``), the widest
+    whose working set fits ``tpu_vmem_budget()``.  Raises ValueError where
+    even 128 edges do not fit."""
+    budget = tpu_vmem_budget()
+    tile_e = pick_tile_e(tile_m, f_in, f_out, itemsize, budget=budget,
+                         cap=FUSED_TILE_E_MAX if f_out else SEG_TILE_E_MAX)
     if tile_e is None:
         raise ValueError(
             f"no TPU aggregation tile fits VMEM: tile_m={tile_m}, "
             f"F_in={f_in}, F_out={f_out or f_in}, {itemsize}-byte operands "
-            f"exceed the {tpu_vmem_budget(backend)}-byte budget even at "
-            f"tile_e={_LANE}")
+            f"exceed the {budget}-byte budget even at tile_e={_LANE}")
     return tile_e
 
 
@@ -145,14 +139,12 @@ def _tile_e(backend: str, tile_m: int, f_in: int, f_out: int,
 _GATHER_WINDOW, _GATHER_SLOW_ABOVE = 1024, 896
 
 
-def gather_tail(backend: str, nblocks: int, emax_p: int) -> int:
+def gather_tail(nblocks: int, emax_p: int) -> int:
     """Slots per block the TPU pre-gather writes past ``emax_p`` so that
     its index count ``nblocks·(emax_p + tail)`` leaves a remainder in
     ``[1, 896]`` modulo 1024.  The kernels' grid stops at ``emax_p``, so
     no step reads them.  A multiple of 8, which keeps the rows' block
-    view a bitcast; 0 off the TPU tier or where no such tail exists."""
-    if backend != PALLAS_TPU:
-        return 0
+    view a bitcast; 0 where no such tail exists."""
     for tail in range(0, _GATHER_WINDOW, 8):
         if 0 < nblocks * (emax_p + tail) % _GATHER_WINDOW \
                 <= _GATHER_SLOW_ABOVE:
@@ -160,8 +152,7 @@ def gather_tail(backend: str, nblocks: int, emax_p: int) -> int:
     return 0
 
 
-def kernel_edges(seg_local, mask, tile_e: int, *same_padding,
-                 backend=None):
+def kernel_edges(seg_local, mask, tile_e: int, *same_padding):
     """The edge layout every aggregation kernel reads.
 
     Pads the per-block edge axis of the ``(nblocks, emax)`` BlockedGraph
@@ -170,17 +161,15 @@ def kernel_edges(seg_local, mask, tile_e: int, *same_padding,
     then ends in (full dim, lane multiple), which Mosaic accepts; a
     ``(1, tile_e)`` block over ``(nblocks, emax)`` is refused.
     ``same_padding`` arrays get the same edge-axis padding and keep their
-    rank: on the planned paths the source ids (and edge indices) that the
-    rows are then gathered by (``_gather_slots``), with
-    ``gather_tail(backend, ...)`` more slots per block; rows the caller
-    grouped itself (``seg_agg_pregrouped``, no backend).  Leading axes
-    before ``nblocks`` are kept: the ring hops' ``(P, P, nb, emax)``
-    owner buckets (``graph.partition.block_buckets``) are padded once, one
-    ``(nb, emax_p + tail)`` gather a hop.
+    rank, with ``gather_tail`` more slots per block: the source ids (and
+    edge indices) that the rows are then gathered by (``gather_seg_agg``).
+    Leading axes before ``nblocks`` are kept: the ring hops' ``(P, P, nb,
+    emax)`` owner buckets (``graph.partition.block_buckets``) are padded
+    once, one ``(nb, emax_p + tail)`` gather a hop.
     """
     *lead, nblocks, emax = seg_local.shape
     emax_p = _round_up(emax, tile_e)
-    tail = gather_tail(backend, nblocks, emax_p)
+    tail = gather_tail(nblocks, emax_p)
 
     def pad(a, to):
         if to == emax:
@@ -195,8 +184,7 @@ def kernel_edges(seg_local, mask, tile_e: int, *same_padding,
                 *(pad(a, emax_p + tail) for a in same_padding))
 
 
-def layout_counts(bg, f_in: int, itemsize: int, backend: str,
-                  f_out: int = 0) -> dict:
+def layout_counts(bg, f_in: int, itemsize: int, f_out: int = 0) -> dict:
     """What one aggregation over the blocked layout ``bg`` moves, by the
     same tiling ``seg_agg_planned`` (``f_out=0``) and ``fused_agg_combine``
     (a ``(f_in, f_out)`` weight) apply:
@@ -207,12 +195,10 @@ def layout_counts(bg, f_in: int, itemsize: int, backend: str,
     * ``kernel_slots``: edge slots the kernel reads, ``nblocks·emax_p``;
     * ``gather_bytes``: ``gather_rows · f_in · itemsize``.
     """
-    backend = resolve_backend(backend)
-    tile_e = _tile_e(backend, bg.tile_m, f_in, f_out, itemsize,
-                     FUSED_TILE_E_MAX if f_out else SEG_TILE_E_MAX)
+    tile_e = kernel_tile_e(bg.tile_m, f_in, itemsize, f_out)
     emax_p = _round_up(bg.emax, tile_e)
     slots = bg.nblocks * emax_p
-    rows = bg.nblocks * (emax_p + gather_tail(backend, bg.nblocks, emax_p))
+    rows = bg.nblocks * (emax_p + gather_tail(bg.nblocks, emax_p))
     return {"edges": int(bg.num_edges), "gather_rows": rows,
             "kernel_slots": slots, "gather_bytes": rows * f_in * itemsize}
 
@@ -240,91 +226,32 @@ def _gather_slots(x, src, mask):
 
 
 # ---------------------------------------------------------------------------
-# Segmented aggregation over a destination-sorted edge list
+# Segmented aggregation over a destination-blocked edge layout
 # ---------------------------------------------------------------------------
 
 
-def _seg_agg_call(backend: str, rows, seg_local, mask, tile_m: int):
-    """Pad pre-grouped ``(nblocks, emax, F)`` rows and their edge arrays to
-    the kernel's edge layout, then run it (``_seg_agg_kernel``)."""
-    tile_e = _tile_e(backend, tile_m, rows.shape[-1], 0,
-                     jnp.dtype(rows.dtype).itemsize, SEG_TILE_E_MAX)
-    seg3, mask3, rows = kernel_edges(seg_local, mask, tile_e, rows)
-    return _seg_agg_kernel(backend, rows, seg3, mask3, tile_m, tile_e)
+def gather_seg_agg(x, src, seg3, mask3, *, tile_m: int, tile_e: int,
+                   weight=None) -> jnp.ndarray:
+    """Gather ``x`` rows into the kernel's slots, then run ``seg_agg``.
 
-
-def _seg_agg_kernel(backend: str, rows, seg3, mask3, tile_m: int,
-                    tile_e: int):
-    """Run the tier's blocked kernel (TPU sequential-grid vs GPU
-    row-owned) on operands already in the ``kernel_edges`` layout.
-    ``backend`` must already be resolved, so entry and interpret mode can
-    never disagree."""
-    if backend == PALLAS_GPU:
-        return seg_agg_gpu_blocked(rows, seg3, mask3, tile_m=tile_m,
-                                   tile_e=tile_e,
-                                   interpret=interpret_for(backend))
-    return seg_agg_blocked(rows, seg3, mask3, tile_m=tile_m, tile_e=tile_e,
-                           interpret=interpret_for(backend),
-                           vmem_limit_bytes=tpu_vmem_budget(backend))
-
-
-def seg_agg(rows: jnp.ndarray, seg_ids: jnp.ndarray, num_segments: int,
-            tile_m: int = 128, backend: str = PALLAS_TPU) -> jnp.ndarray:
-    """Drop-in segment_sum(rows, seg_ids) -- the SLOW ad-hoc fallback.
-
-    Requires ``seg_ids`` sorted (destination-sorted edges -- the framework
-    invariant).  This entry performs the O(E) block regrouping on the HOST
-    on *every call* (``device_get`` + numpy), so it cannot be traced
-    (``jax.jit`` / ``grad`` raise) and it re-pays the regrouping cost per
-    invocation.  It exists only for one-off calls on un-planned graphs.
-
-    Repeated-graph callers must go through the plan-owned blocked layout
-    instead: ``GraphExecutionPlan`` builds it once per (graph, tile_m)
-    (``core.plan._blocked_for``) and dispatches ``seg_agg_planned`` --
-    trace-pure, zero host transfers.  ``phases.aggregate(..., layout=...)``
-    is the phase-level door.  ``backend`` selects the kernel tier
-    ("pallas-tpu" | "pallas-gpu"; "pallas"/"auto" resolve per platform --
-    see core/backend.py).
+    ``src`` ``(nblocks, emax_p + tail)``, ``seg3`` and ``mask3``
+    ``(nblocks, 1, emax_p)``: one ``kernel_edges`` layout at ``tile_e``.
+    ``weight``, optional ``(nblocks, emax_p + tail)``, scales each slot's
+    row before the sum.  Returns the ``(nblocks * tile_m, F)`` block sums.
     """
-    backend = resolve_backend(backend)
-    e, f = rows.shape
-    if isinstance(seg_ids, jax.core.Tracer):
-        raise ValueError(SEG_AGG_REMEDIATION)
-    # documented host fallback -- the Tracer guard above is the contract
-    seg_np = np.asarray(jax.device_get(seg_ids))  # analysis: allow(host-in-trace)
-    nblocks = _round_up(num_segments, tile_m) // tile_m
-    blk = seg_np // tile_m
-    counts = np.bincount(blk, minlength=nblocks)
-    emax = max(int(counts.max()) if len(counts) else 1, 1)
-    seg_l = np.zeros((nblocks, emax), np.int32)
-    mask = np.zeros((nblocks, emax), np.float32)
-    from repro.core.dataflow import block_offsets
-    _, offs = block_offsets(blk, nblocks)
-    seg_l[blk, offs] = seg_np - blk * tile_m
-    mask[blk, offs] = 1.0
-    bs_rows = jnp.zeros((nblocks, emax, f), rows.dtype).at[
-        jnp.asarray(blk), jnp.asarray(offs)].set(rows)
-    out = _seg_agg_call(backend, bs_rows, jnp.asarray(seg_l),
-                        jnp.asarray(mask), tile_m)
-    return out[:num_segments]
+    rows = _gather_slots(x, src, mask3)
+    if weight is not None:
+        rows = rows * weight[..., None].astype(rows.dtype)
+    return seg_agg_blocked(rows, seg3, mask3, tile_m=tile_m, tile_e=tile_e,
+                           vmem_limit_bytes=tpu_vmem_budget())
 
 
-def seg_agg_pregrouped(rows_blocked, seg_local, mask, tile_m: int,
-                       backend: str = PALLAS_TPU) -> jnp.ndarray:
-    """Kernel entry for already block-grouped inputs (BlockedGraph layout:
-    ``(nblocks, emax[, F])``)."""
-    return _seg_agg_call(resolve_backend(backend), rows_blocked, seg_local,
-                         mask, tile_m)
-
-
-def seg_agg_planned(bg, x: jnp.ndarray, edge_weight=None, *,
-                    backend: str = PALLAS_TPU) -> jnp.ndarray:
+def seg_agg_planned(bg, x: jnp.ndarray, edge_weight=None) -> jnp.ndarray:
     """Trace-pure segmented aggregation over a plan-owned blocked layout.
 
     ``bg`` is a ``core.dataflow.BlockedGraph`` (with ``eidx``) built ONCE at
     plan time; everything here is jnp gathers and the Pallas kernel, so the
-    whole call traces under ``jax.jit``/``grad`` with zero host transfers --
-    the production replacement for the ad-hoc ``seg_agg`` regrouping.
+    whole call traces under ``jax.jit``/``grad`` with zero host transfers.
 
     x: (V, F) vertex features; ``edge_weight``: optional (E,) per-edge
     scalar, regrouped into the blocked layout via ``bg.eidx`` (one gather).
@@ -339,24 +266,20 @@ def seg_agg_planned(bg, x: jnp.ndarray, edge_weight=None, *,
 
     The ids are padded to the kernel's edge layout first (``kernel_edges``:
     pad slots take mask 0 and edge 0), and the rows are gathered straight
-    into it (``_gather_slots``), as ``fused_agg_combine`` does: each row is
-    written once, at ``(nblocks, emax_p + gather_tail, F)``.
+    into it (``gather_seg_agg``), as ``fused_agg_combine`` does: each row
+    is written once, at ``(nblocks, emax_p + gather_tail, F)``.
     """
-    backend = resolve_backend(backend)
     if edge_weight is not None and bg.eidx is None:
         raise ValueError("BlockedGraph built without eidx cannot "
                          "regroup edge weights; rebuild via block_graph")
-    tile_e = _tile_e(backend, bg.tile_m, x.shape[-1], 0,
-                     jnp.dtype(x.dtype).itemsize, SEG_TILE_E_MAX)
+    tile_e = kernel_tile_e(bg.tile_m, x.shape[-1],
+                           jnp.dtype(x.dtype).itemsize)
     ids = (bg.src,) if edge_weight is None else (bg.src, bg.eidx)
-    seg3, mask3, src, *eidx = kernel_edges(bg.dstl, bg.mask, tile_e, *ids,
-                                           backend=backend)
-    rows = _gather_slots(x, src, mask3)
-    if edge_weight is not None:
-        w_blk = jnp.take(edge_weight, eidx[0].reshape(-1),
-                         axis=0).reshape(src.shape)
-        rows = rows * w_blk[..., None].astype(rows.dtype)
-    out = _seg_agg_kernel(backend, rows, seg3, mask3, bg.tile_m, tile_e)
+    seg3, mask3, src, *eidx = kernel_edges(bg.dstl, bg.mask, tile_e, *ids)
+    weight = None if edge_weight is None else jnp.take(
+        edge_weight, eidx[0].reshape(-1), axis=0).reshape(src.shape)
+    out = gather_seg_agg(x, src, seg3, mask3, tile_m=bg.tile_m,
+                         tile_e=tile_e, weight=weight)
     return out[:bg.num_vertices]
 
 
@@ -365,47 +288,20 @@ def seg_agg_planned(bg, x: jnp.ndarray, edge_weight=None, *,
 # ---------------------------------------------------------------------------
 
 
-def fused_agg_combine(src, dst_local, mask, x, w, *, tile_m: int,
-                      backend: str = PALLAS_TPU) -> jnp.ndarray:
+def fused_agg_combine(src, dst_local, mask, x, w, *,
+                      tile_m: int) -> jnp.ndarray:
     """Gather x rows by ``src`` (XLA DMA gather), then fused reduce+GEMM.
 
     src/dst_local/mask: (nblocks, emax) BlockedGraph layout.
     x: (V, F_in); w: (F_in, F_out).  Returns (nblocks*tile_m, F_out).
-    ``backend`` selects the kernel tier: "pallas-tpu" (sequential edge-chunk
-    grid + VMEM scratch, ``tile_e`` sized by ``pick_tile_e`` against the
-    same budget passed to the compiler) or "pallas-gpu" (one CTA per block,
-    register accumulator -- kernels/gpu_agg.py); "pallas"/"auto" resolve
-    per platform.
+    The kernel walks a sequential edge-chunk grid with a VMEM scratch
+    accumulator; ``tile_e`` is sized by ``pick_tile_e`` against the same
+    budget passed to the compiler.
     """
-    backend = resolve_backend(backend)
     f_in, f_out = w.shape
-    tile_e = _tile_e(backend, tile_m, f_in, f_out,
-                     jnp.dtype(x.dtype).itemsize, FUSED_TILE_E_MAX)
-    seg3, mask3, src = kernel_edges(dst_local, mask, tile_e, src,
-                                    backend=backend)
+    tile_e = kernel_tile_e(tile_m, f_in, jnp.dtype(x.dtype).itemsize, f_out)
+    seg3, mask3, src = kernel_edges(dst_local, mask, tile_e, src)
     rows = _gather_slots(x, src, mask3)
-    if backend == PALLAS_GPU:
-        return fused_agg_combine_gpu_blocked(
-            rows, seg3, mask3, w, tile_m=tile_m, tile_e=tile_e,
-            interpret=interpret_for(backend))
     return fused_agg_combine_blocked(
         rows, seg3, mask3, w, tile_m=tile_m, tile_e=tile_e,
-        interpret=interpret_for(backend),
-        vmem_limit_bytes=tpu_vmem_budget(backend))
-
-
-# ---------------------------------------------------------------------------
-# Flash attention
-# ---------------------------------------------------------------------------
-
-
-def flash_attention(q, k, v, kv_len=None, *, causal: bool = True,
-                    window: int = 0, softcap: float = 0.0,
-                    tile_q: int = 128, tile_k: int = 128) -> jnp.ndarray:
-    return _flash(q, k, v, kv_len, causal=causal, window=window,
-                  softcap=softcap, tile_q=tile_q, tile_k=tile_k,
-                  interpret=_interpret())
-
-
-# Re-export oracles for convenience in tests/benchmarks.
-ref = kref
+        vmem_limit_bytes=tpu_vmem_budget())

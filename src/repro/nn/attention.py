@@ -289,10 +289,10 @@ def attention_block(params: Dict, x: jnp.ndarray, cfg: AttentionConfig, *,
                              cap=cfg.attn_logit_softcap)
     else:
         if impl == "pallas":
-            from repro.kernels import ops as kops
-            o = kops.flash_attention(q, k, v, causal=cfg.causal,
-                                     window=layer_window,
-                                     softcap=cfg.attn_logit_softcap)
+            from repro.kernels.flash_attention import flash_attention
+            o = flash_attention(q, k, v, causal=cfg.causal,
+                                window=layer_window,
+                                softcap=cfg.attn_logit_softcap)
         elif s <= 2048 or impl == "direct":
             o = direct_attention(q, k, v, causal=cfg.causal,
                                  window=layer_window,

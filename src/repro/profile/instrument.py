@@ -168,10 +168,10 @@ class _Probe:
         # stay f32 (mismatches() checks describe() against this rule)
         pd = getattr(self.plan, "dtype", "f32")
         rec_dtype = "f32" if (pd == "int8-agg" and name == "combine") else pd
-        # backend as the dispatch layer resolves it at call time (the same
-        # resolution phases.aggregate applies) -- NOT lp.backend verbatim,
-        # so a plan that regressed to storing an unresolved alias ("auto" /
-        # "pallas") is caught by mismatches() as describe-vs-dispatch drift
+        # backend as the dispatch layer resolves it at call time -- NOT
+        # lp.backend verbatim, so a plan that regressed to storing an
+        # unresolved "auto" is caught by mismatches() as
+        # describe-vs-dispatch drift
         self.records.append(PhaseRecord(
             layer=lp.index, phase=name, order=lp.order,
             backend=resolve_backend(lp.backend) if name != "combine"
@@ -661,7 +661,7 @@ class WorkloadReport:
         bias may legitimately fall back at call time -- that fallback is
         exactly the drift this reports; model-path plans must always come
         back clean), the call-time backend *resolution* (a plan
-        storing an unresolved "auto"/"pallas" alias disagrees with what
+        storing an unresolved "auto" disagrees with what
         dispatch resolves), whether the planned ``reorder`` permute
         actually ran at ingress (observed only by ``run_model`` -- the
         entry that owns ingress/egress), the storage ``dtype`` each phase

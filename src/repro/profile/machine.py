@@ -29,9 +29,9 @@ direction per hop; ``interconnect_total`` remains the aggregate all-links
 number for bisection-style accounting.  ``hop_time(nbytes)`` is the
 overlap model's per-hop wire term.
 
-``machine_for_backend`` maps a resolved backend tier (``core.backend``) to
-its natural preset so plan-level code can stay machine-implicit until a
-caller overrides it.
+``machine_for_backend`` gives a resolved backend tier (``core.backend``)
+the platform's preset so plan-level code can stay machine-implicit until
+a caller overrides it.
 
 ``choose_dtype``/``dtype_model`` price the execution dtype the same way
 ``choose_overlap``/``overlap_model`` price halo pipelining: per-phase byte
@@ -235,14 +235,11 @@ def tpu_machine(device_kind: str) -> Machine:
 def machine_for_backend(backend: Optional[str]) -> Machine:
     """Natural Machine preset for a resolved backend tier.
 
-    ``pallas-gpu`` -> A100 (GPU occupancy math must never mix TPU balance
-    points).  Every other tier on a real TPU -> that chip's preset by
-    ``device_kind`` (``tpu_machine``; an unknown kind raises); off-TPU ->
-    TPU_V5E, the repo's explicit modeling target.  Callers wanting the
-    paper's machine pass ``V100`` explicitly.
+    Both tiers (``xla``, ``pallas-tpu``) map alike: on a real TPU, that
+    chip's preset by ``device_kind`` (``tpu_machine``; an unknown kind
+    raises); off-TPU, TPU_V5E, the repo's explicit modeling target.
+    Callers wanting the paper's machine pass ``V100`` explicitly.
     """
-    if backend == "pallas-gpu":
-        return A100
     import jax
     device = jax.devices()[0]
     if device.platform == "tpu":

@@ -108,7 +108,7 @@ def small_setup():
 
 @pytest.mark.parametrize("backend,fused,dtype", [
     ("xla", False, "f32"), ("xla", False, "bf16"),
-    ("pallas-tpu", True, "bf16"), ("pallas-gpu", True, "int8-agg"),
+    ("pallas-tpu", True, "bf16"), ("pallas-tpu", True, "int8-agg"),
 ])
 def test_lint_plan_local_cells_clean(small_setup, backend, fused, dtype):
     from repro.core.plan import build_plan
@@ -151,28 +151,27 @@ def test_dynamic_edge_free_catches_baked_plan(small_setup):
         plan._check_dynamic_ok()
 
 
-def test_seg_agg_remediation_shared_with_ast_rule():
-    """Satellite 6: the error a user hits when tracing ``seg_agg`` and
-    the host-in-trace finding a reviewer reads agree VERBATIM on the fix
-    (seg_agg_planned via the plan entry points)."""
+def test_pallas_aggregate_without_layout_points_at_the_plans(small_setup):
+    """The Pallas tier aggregates over a plan-owned blocked layout alone:
+    ``phases.aggregate`` refuses it without one, and both that error and
+    the host-in-trace finding a reviewer reads name the trace-pure
+    planned entry points."""
     import jax.numpy as jnp
 
-    from repro.kernels.ops import SEG_AGG_REMEDIATION, seg_agg
-
-    assert "seg_agg_planned" in SEG_AGG_REMEDIATION
-    for entry in ("build_plan", "plan_for_conv", "plan_for_phases"):
-        assert entry in SEG_AGG_REMEDIATION
+    from repro.core import phases
+    _, g, _ = small_setup
     with pytest.raises(ValueError) as ei:
-        jax.jit(lambda r, s: seg_agg(r, s, 4))(
-            jnp.ones((6, 2)), jnp.zeros((6,), jnp.int32))
-    assert SEG_AGG_REMEDIATION in str(ei.value)
-    # the AST rule's remediation text is the SAME constant
+        phases.aggregate(g, jnp.ones((g.num_vertices, 4)), op="sum",
+                         backend="pallas-tpu")
     src = ("def f(x):\n"
            "    y = jnp.sum(x)\n"
            "    return float(jnp.max(y))\n")
     hits = [f for f in lint_source(src).findings
             if f.rule == "host-in-trace"]
-    assert hits and SEG_AGG_REMEDIATION in hits[0].detail
+    assert hits
+    for entry in ("seg_agg_planned", "build_plan", "plan_for_conv",
+                  "plan_for_phases"):
+        assert entry in str(ei.value) and entry in hits[0].detail
 
 
 # ---------------------------------------------------------------------------

@@ -24,7 +24,7 @@ from tolerance import assert_matches_reference
 
 SRC = str(Path(__file__).resolve().parents[1] / "src")
 
-BACKENDS = ("xla", "pallas-tpu", "pallas-gpu")
+BACKENDS = ("xla", "pallas-tpu")
 
 
 @pytest.fixture(scope="module")

@@ -115,7 +115,7 @@ def dedup_case(draw):
         v=draw(st.sampled_from([64, 128, 192])),
         hubs=draw(st.sampled_from([4, 6, 8])),
         f=draw(st.sampled_from([8, 24])),
-        backend=draw(st.sampled_from(["xla", "pallas-tpu", "pallas-gpu"])),
+        backend=draw(st.sampled_from(["xla", "pallas-tpu"])),
         ordering=draw(st.sampled_from(["combine_first", "aggregate_first",
                                        None])),
         fused=draw(st.sampled_from([False, True])),

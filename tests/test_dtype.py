@@ -48,7 +48,7 @@ def planner_case(draw):
         v=draw(st.integers(40, 160)),
         deg=draw(st.integers(2, 5)),
         f=draw(st.sampled_from([8, 24, 48])),
-        backend=draw(st.sampled_from(["xla", "pallas-tpu", "pallas-gpu"])),
+        backend=draw(st.sampled_from(["xla", "pallas-tpu"])),
         ordering=draw(st.sampled_from(["combine_first", "aggregate_first",
                                        None])),
         fused=draw(st.sampled_from([False, True, None])),

@@ -66,12 +66,22 @@ def test_seg_agg_dtypes(dtype):
                           scale=2.0 if dtype == jnp.bfloat16 else 1.0)
 
 
-def test_seg_agg_wrapper_sorted_ids():
-    e, f, v = 999, 48, 117
-    seg = np.sort(RNG.integers(0, v, e)).astype(np.int32)
-    rows = jnp.asarray(RNG.standard_normal((e, f)), jnp.float32)
-    out = ops.seg_agg(rows, jnp.asarray(seg), v)
-    ref = seg_agg_ref(rows, jnp.asarray(seg), jnp.ones(e), v)
+def test_seg_agg_planned_sorted_ids_uneven_blocks():
+    """Destination-sorted segment ids over a vertex count that is no
+    ``tile_m`` multiple, two thirds of the edges in the first block,
+    through the planned entry: the plain segment sum of the rows."""
+    from repro.core.dataflow import block_graph_arrays
+    e, f, v, tile_m = 999, 48, 117, 32
+    seg = np.sort(np.concatenate([RNG.integers(0, 20, 666),
+                                  RNG.integers(0, v, e - 666)]))
+    src = RNG.integers(0, v, e)
+    bg = block_graph_arrays(src, seg, v, tile_m)
+    counts = np.asarray(bg.mask).sum(1)
+    assert bg.nblocks == 4 and counts.max() > 4 * counts.min()
+    x = jnp.asarray(RNG.standard_normal((v, f)), jnp.float32)
+    out = ops.seg_agg_planned(bg, x)
+    ref = seg_agg_ref(jnp.take(x, jnp.asarray(src), axis=0),
+                      jnp.asarray(seg, jnp.int32), jnp.ones(e), v)
     assert_allclose_dtype(out, ref)
 
 
@@ -156,21 +166,24 @@ def _planned_case(source, f, seed=0):
 
 
 def _old_order(bg, x, edge_weight):
-    """The parent order: gather to ``emax``, then pad the gathered rows
-    (``seg_agg_pregrouped`` pads pre-grouped rows)."""
+    """The old order: gather to ``emax``, then pad the gathered rows to
+    the kernel's edge layout."""
     rows = jnp.take(x, bg.src.reshape(-1), axis=0).reshape(
         bg.nblocks, bg.emax, x.shape[-1])
     if edge_weight is not None:
         w_blk = jnp.take(edge_weight, bg.eidx.reshape(-1), axis=0)
         rows = rows * w_blk.reshape(bg.nblocks, bg.emax, 1)
-    out = ops.seg_agg_pregrouped(rows, bg.dstl, bg.mask, bg.tile_m,
-                                 backend="pallas-tpu")
+    tile_e = ops.kernel_tile_e(bg.tile_m, x.shape[-1], 4)
+    seg3, mask3 = ops.kernel_edges(bg.dstl, bg.mask, tile_e)
+    rows = jnp.pad(rows, ((0, 0), (0, seg3.shape[-1] - bg.emax), (0, 0)))
+    out = seg_mod.seg_agg_blocked(rows, seg3, mask3, tile_m=bg.tile_m,
+                                  tile_e=tile_e)
     return out[:bg.num_vertices]
 
 
 def _slot_shape(bg, f):
     """``(emax_p, tail)`` of ``seg_agg_planned`` on ``bg`` at width f."""
-    counts = ops.layout_counts(bg, f, 4, "pallas-tpu")
+    counts = ops.layout_counts(bg, f, 4)
     emax_p = counts["kernel_slots"] // bg.nblocks
     assert emax_p > bg.emax, "emax must not be a tile_e multiple here"
     return emax_p, counts["gather_rows"] // bg.nblocks - emax_p
@@ -201,7 +214,7 @@ def test_seg_agg_planned_equals_the_old_order(source, weighted, f):
     assert (tail > 0) == (source == "tail")
     w = jnp.asarray(np.random.default_rng(3).random(bg.num_edges),
                     jnp.float32) if weighted else None
-    new = ops.seg_agg_planned(bg, x, w, backend="pallas-tpu")
+    new = ops.seg_agg_planned(bg, x, w)
     np.testing.assert_array_equal(np.asarray(new),
                                   np.asarray(_old_order(bg, x, w)))
 
@@ -216,7 +229,7 @@ def test_seg_agg_planned_gathers_into_the_slot_layout(f, weighted, source):
     emax_p, tail = _slot_shape(bg, f)
     w = jnp.ones(bg.num_edges, jnp.float32) if weighted else None
     jaxpr = jax.make_jaxpr(lambda x, w: ops.seg_agg_planned(
-        bg, x, w, backend="pallas-tpu"))(x, w).jaxpr
+        bg, x, w))(x, w).jaxpr
     eqns = list(_eqns(jaxpr))
     gathers = [q.outvars[0].aval.shape for q in eqns
                if q.primitive.name == "gather"
@@ -237,8 +250,7 @@ def test_seg_agg_planned_gathers_into_the_slot_layout(f, weighted, source):
 ])
 def test_gather_tail_moves_the_index_count_off_the_slow_remainders(
         nblocks, emax_p, tail):
-    assert ops.gather_tail("pallas-tpu", nblocks, emax_p) == tail
-    assert ops.gather_tail("pallas-gpu", nblocks, emax_p) == 0
+    assert ops.gather_tail(nblocks, emax_p) == tail
     if tail:
         assert 0 < nblocks * (emax_p + tail) % 1024 <= 896
 
@@ -267,6 +279,37 @@ def test_planned_forward_pads_no_gathered_rows(model):
     for lp, d in zip(plan.layers, plan.describe()):
         assert d["agg_gather_rows"] == d["agg_kernel_slots"] \
             > lp.agg_layout.nblocks * lp.agg_layout.emax
+
+
+def test_no_module_outside_the_kernels_imports_private_ops_names():
+    """The kernel layer's seam is its public names: no module of
+    ``src/repro`` outside ``kernels/`` imports an underscore name of
+    ``repro.kernels.ops`` or reads one off the module."""
+    import ast
+    from pathlib import Path
+    root = Path(ops.__file__).resolve().parents[1]
+    offenders = []
+    for path in sorted(root.rglob("*.py")):
+        if path.parent.name == "kernels":
+            continue
+        tree = ast.parse(path.read_text())
+        aliases = set()
+        for node in ast.walk(tree):
+            if isinstance(node, ast.ImportFrom) and \
+                    node.module == "repro.kernels.ops":
+                offenders += [f"{path.name}: {a.name}" for a in node.names
+                              if a.name.startswith("_")]
+            elif isinstance(node, ast.ImportFrom) and \
+                    node.module == "repro.kernels":
+                aliases |= {a.asname or a.name for a in node.names
+                            if a.name == "ops"}
+        offenders += [f"{path.name}: {node.value.id}.{node.attr}"
+                      for node in ast.walk(tree)
+                      if isinstance(node, ast.Attribute)
+                      and isinstance(node.value, ast.Name)
+                      and node.value.id in aliases
+                      and node.attr.startswith("_")]
+    assert not offenders, offenders
 
 
 # --------------------------------------------------------- flash attention

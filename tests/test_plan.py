@@ -12,16 +12,15 @@ from hypothesis import given, settings, strategies as st
 from repro.config import CORA, GraphSpec, reduced_graph
 from repro.core import backend as backend_mod
 from repro.core import phases
-from repro.core.backend import (default_interpret, interpret_for,
-                                resolve_backend)
+from repro.core.backend import default_interpret, resolve_backend
 from repro.core.plan import (build_plan, clear_plan_cache, plan_for_conv,
                              plan_for_phases)
 from repro.core.scheduler import AGGREGATE_FIRST, COMBINE_FIRST
 from repro.graph.datasets import make_features, make_synthetic_graph
 from repro.models.gcn import PAPER_MODELS, make_paper_model
 
-# non-native tiers run in interpret mode off their platform
-BACKENDS = ("xla", "pallas-tpu", "pallas-gpu")
+# the Pallas tier runs in interpret mode off a TPU
+BACKENDS = ("xla", "pallas-tpu")
 ORDERINGS = (COMBINE_FIRST, AGGREGATE_FIRST)  # both legal for GCN (mean, 1-mlp)
 
 
@@ -167,44 +166,50 @@ def test_interpret_autodetect(monkeypatch):
 def test_backend_auto_resolution():
     assert resolve_backend("xla") == "xla"
     assert resolve_backend("pallas-tpu") == "pallas-tpu"
-    assert resolve_backend("pallas-gpu") == "pallas-gpu"
     plat = jax.default_backend()
-    expected = {"tpu": "pallas-tpu", "gpu": "pallas-gpu"}.get(plat, "xla")
-    assert resolve_backend("auto") == expected
-    # legacy alias: the platform's native Pallas tier
-    assert resolve_backend("pallas") == (
-        "pallas-gpu" if plat == "gpu" else "pallas-tpu")
+    assert resolve_backend("auto") == (
+        "pallas-tpu" if plat == "tpu" else "xla")
+    assert resolve_backend() == resolve_backend("auto")
     with pytest.raises(ValueError):
         resolve_backend("cuda")
 
 
-@pytest.mark.parametrize("plat,auto,alias", [
-    ("cpu", "xla", "pallas-tpu"),
-    ("gpu", "pallas-gpu", "pallas-gpu"),
-    ("tpu", "pallas-tpu", "pallas-tpu"),
+@pytest.mark.parametrize("requested", ["pallas-gpu", "pallas"])
+def test_resolve_backend_refuses_removed_tiers(requested):
+    """Only the TPU tier and XLA exist: the GPU tier and the "pallas"
+    alias that resolved to a platform's tier are unknown backends."""
+    with pytest.raises(ValueError, match="unknown backend"):
+        resolve_backend(requested)
+
+
+@pytest.mark.parametrize("plat,auto", [
+    ("cpu", "xla"),
+    ("gpu", "xla"),
+    ("tpu", "pallas-tpu"),
 ])
-def test_backend_resolution_mocked_platforms(monkeypatch, plat, auto, alias):
-    """resolve_backend picks the platform's tier (paper F3 per platform);
-    every tier is a distinct string so plans record WHICH kernel family ran."""
+def test_backend_resolution_mocked_platforms(monkeypatch, plat, auto):
+    """resolve_backend picks the Pallas tier on a TPU alone (paper F3 per
+    platform): a GPU runs XLA's segmented reduction.  Each tier is a
+    distinct string so plans record WHICH kernel family ran."""
     monkeypatch.setattr(backend_mod, "platform", lambda: plat)
     assert resolve_backend("auto") == auto
-    assert resolve_backend("pallas") == alias
     # explicit tiers are never rewritten, even cross-platform
-    assert resolve_backend("pallas-gpu") == "pallas-gpu"
     assert resolve_backend("pallas-tpu") == "pallas-tpu"
     assert resolve_backend("xla") == "xla"
 
 
 @pytest.mark.parametrize("plat", ["cpu", "gpu", "tpu"])
 def test_interpret_per_tier_mocked_platforms(monkeypatch, plat):
-    """A Pallas tier compiles only on its native platform; anywhere else it
-    interprets (so a CPU container still validates GPU/TPU kernel numerics)."""
+    """The Pallas tier compiles only on a TPU; anywhere else it interprets
+    (so a CPU container still validates the kernel numerics), and
+    REPRO_PALLAS_INTERPRET overrides either way."""
     monkeypatch.delenv("REPRO_PALLAS_INTERPRET", raising=False)
     monkeypatch.setattr(backend_mod, "platform", lambda: plat)
-    assert interpret_for("pallas-tpu") == (plat != "tpu")
-    assert interpret_for("pallas-gpu") == (plat != "gpu")
+    assert default_interpret() == (plat != "tpu")
     monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "1")
-    assert interpret_for("pallas-tpu") and interpret_for("pallas-gpu")
+    assert default_interpret() is True
+    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")
+    assert default_interpret() is False
 
 
 def test_no_raw_impl_blocked_flags():
@@ -228,16 +233,6 @@ def test_describe_reports_decisions(data):
                 "agg_bytes"} <= set(row)
     # layer 2 shrinks 128->7: the cost model must pick combine_first
     assert d[-1]["order"] == COMBINE_FIRST
-
-
-def test_gpu_tile_picker_is_occupancy_aware():
-    """The GPU tier's suggested tile is warp-aligned and small enough to
-    keep several CTAs resident per SM; the TPU tier fills half of VMEM."""
-    from repro.core.dataflow import suggest_tile_m
-    tpu = suggest_tile_m(128, 128, 8.0)
-    gpu = suggest_tile_m(128, 128, 8.0, backend="pallas-gpu")
-    assert gpu % 32 == 0 and 32 <= gpu <= 256
-    assert tpu > gpu  # one giant sequential tile vs many resident CTAs
 
 
 def test_partition_2d_structure(data):

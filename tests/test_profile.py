@@ -71,12 +71,12 @@ def test_machine_presets_and_registry():
 
 
 def test_machine_for_backend_mapping():
-    assert machine_for_backend("pallas-gpu") is A100
+    # off a TPU both tiers model the repo's target chip
     assert machine_for_backend("pallas-tpu") is TPU_V5E
     assert machine_for_backend("xla") is TPU_V5E
     # default_machine resolves the tier first (CPU container: auto -> xla)
-    assert default_machine("auto") in (TPU_V5E, A100)
-    assert default_machine("pallas-gpu") is A100
+    assert default_machine("auto") is TPU_V5E
+    assert default_machine("pallas-tpu") is TPU_V5E
 
 
 def test_tpu_machine_keyed_by_device_kind():
@@ -99,23 +99,17 @@ def test_deprecated_characterize_shims_removed():
 
 
 def test_suggest_tile_m_is_machine_parameterized():
-    """Satellite: GPU occupancy math comes from the A100 Machine, not from
-    TPU constants; a smaller-SMEM machine (V100) can only shrink the tile."""
-    default_gpu = suggest_tile_m(128, 128, 8.0, backend="pallas-gpu")
-    a100_gpu = suggest_tile_m(128, 128, 8.0, backend="pallas-gpu",
-                              machine=A100)
-    v100_gpu = suggest_tile_m(128, 128, 8.0, backend="pallas-gpu",
-                              machine=V100)
-    assert default_gpu == a100_gpu          # A100 is the GPU-tier default
-    assert v100_gpu <= a100_gpu             # 128K carveout vs 192K
-    assert v100_gpu % V100.row_align == 0
-    # the occupancy model follows machine.kind, not the backend string: a
-    # GPU machine with a non-GPU backend must use the GPU per-CTA model
-    # (never "GPU budget minus the whole W" -- the reverse mixing bug)
-    assert suggest_tile_m(602, 128, 50.0, backend="xla",
-                          machine=A100) == \
-        suggest_tile_m(602, 128, 50.0, backend="pallas-gpu", machine=A100)
-    # TPU path budget follows the machine's VMEM, not a hardcoded constant
+    """The fused tile is sized from the Machine, not from constants: the
+    default is the platform's preset (TPU_V5E off a TPU), every tile is a
+    multiple of the machine's row alignment, and a larger VMEM can only
+    grow the tile."""
+    default = suggest_tile_m(128, 128, 8.0)
+    assert default == suggest_tile_m(128, 128, 8.0, machine=TPU_V5E)
+    assert suggest_tile_m(128, 128, 8.0, backend="xla") == default
+    for m in (TPU_V5E, TPU_V5P):
+        tile = suggest_tile_m(602, 128, 50.0, machine=m)
+        assert tile >= m.row_align and tile % m.row_align == 0
+    # the budget follows the machine's VMEM, not a hardcoded constant
     big = Machine(name="tpu-big", kind="tpu", peak_flops=197e12,
                   hbm_bw=819e9, interconnect_bw=50e9, interconnect_links=4,
                   on_chip_bytes=4 * TPU_V5E.on_chip_bytes)
@@ -245,7 +239,7 @@ def test_describe_matches_dispatch(data, model):
     p = m.init(jax.random.PRNGKey(1))
     orderings = (None,) if model == "gin" else (None, COMBINE_FIRST,
                                                 AGGREGATE_FIRST)
-    for backend in ("xla", "pallas-tpu", "pallas-gpu"):
+    for backend in ("xla", "pallas-tpu"):
         for fused in (False, True):
             for order in orderings:
                 plan = build_plan(g, m.cfg, spec.feature_len,
@@ -274,7 +268,7 @@ def test_runtime_fusion_fallback_is_reported(data):
 
 def test_unresolved_backend_alias_is_reported(data):
     """The backend drift check observes call-time resolution: a plan that
-    regressed to storing the legacy 'pallas' alias (instead of a resolved
+    regressed to storing the unresolved 'auto' (instead of a resolved
     tier) must be flagged -- proves the guard is not vacuous."""
     from dataclasses import replace
 
@@ -284,7 +278,7 @@ def test_unresolved_backend_alias_is_reported(data):
     w = jnp.asarray(rng.standard_normal((x.shape[1], 8)) * 0.3, jnp.float32)
     good = plan_for_phases(g, [(w, None)], order=COMBINE_FIRST,
                            agg_op="mean", backend="pallas-tpu")
-    bad_lp = replace(good.layers[0], backend="pallas")  # unresolved alias
+    bad_lp = replace(good.layers[0], backend="auto")  # unresolved
     bad = GraphExecutionPlan(g, [bad_lp], interpret=True)
     report = bad.instrument(machine=TPU_V5E).run_phases(
         x, [(w, None)], activation="none")

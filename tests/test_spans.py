@@ -197,7 +197,7 @@ def test_layout_counts_match_the_traced_kernel_operands(fused):
         bg = lp.blocked if fused else lp.agg_layout
         emax_p = d["agg_kernel_slots"] // bg.nblocks
         assert d["agg_gather_rows"] == bg.nblocks * (
-            emax_p + gather_tail(lp.backend, bg.nblocks, emax_p))
+            emax_p + gather_tail(bg.nblocks, emax_p))
         width = lp.din if fused or lp.order == "aggregate_first" \
             else lp.dout
         assert d["agg_gather_bytes"] == d["agg_gather_rows"] * width * 4

@@ -169,10 +169,10 @@ def test_gather_tail_keeps_the_tpu_gather_wide(one_chip, monkeypatch):
     v, dests, e = 232965, 192, 1800
     bg = block_graph_arrays(rng.integers(0, v, e),
                             np.sort(rng.integers(0, dests, e)), dests, 64)
-    emax_p = ops.layout_counts(bg, 128, 4, "pallas-tpu")["kernel_slots"] \
+    emax_p = ops.layout_counts(bg, 128, 4)["kernel_slots"] \
         // bg.nblocks
     assert (bg.nblocks, emax_p) == (3, 1024)
-    assert ops.gather_tail("pallas-tpu", bg.nblocks, emax_p) == 8
+    assert ops.gather_tail(bg.nblocks, emax_p) == 8
     x = _shape((v, 128), jnp.float32, one_chip)
 
     def entry(fn):
@@ -182,8 +182,7 @@ def test_gather_tail_keeps_the_tpu_gather_wide(one_chip, monkeypatch):
     def in_flight(text):
         return re.findall(r'"integer_config":\{"integer":"(\d+)"', text)
 
-    planned = entry(lambda x: ops.seg_agg_planned(bg, x,
-                                                  backend="pallas-tpu"))
+    planned = entry(lambda x: ops.seg_agg_planned(bg, x))
     assert in_flight(planned) == ["256"], planned
     assert re.search(r"= f32\[3096,128\]\S* fusion\(", planned), planned
     assert not re.search(r"= f32\[[\d,]+\]\S* (pad|copy)\(", planned), \
