@@ -55,11 +55,13 @@ def tpu_vmem_bytes(tile_m: int, tile_e: int, f_in: int, f_out: int = 0,
     fused kernel with a ``(f_in, f_out)`` weight.  Counts what Mosaic
     allocates: two buffers per blocked operand (edge slab, seg/mask padded
     to 8 sublanes, W, output), the f32 accumulator scratch, and the
-    chunk's in-kernel temporaries (four ``(tile_m, tile_e)`` arrays for
-    iota, compare, one-hot and its cast; the f32 product; the f32 image
-    of a reduced edge slab).  Feature widths are padded to the 128-lane
-    tile.  Checked against the smallest ``vmem_limit_bytes`` a v5e compile
-    accepts (tests/test_tpu_compile.py): the model errs high.
+    chunk's in-kernel temporaries: three 4-byte ``(tile_m, tile_e)``
+    arrays (iota, compare, select) and the bf16 one-hot; the chunk's f32
+    products, three for an f32 slab, which is also split into three bf16
+    terms through one f32 residual (``seg_agg._split_bf16x3``); the f32
+    image of a reduced edge slab.  Feature widths are padded to the
+    128-lane tile.  Checked against the smallest ``vmem_limit_bytes`` a
+    v5e compile accepts (tests/test_tpu_compile.py): the model errs high.
     """
     fi = _round_up(f_in, _LANE)
     fo = _round_up(f_out, _LANE) if f_out else fi
@@ -68,9 +70,13 @@ def tpu_vmem_bytes(tile_m: int, tile_e: int, f_in: int, f_out: int = 0,
     total += 2 * 2 * 8 * tile_e * 4            # seg ids + mask blocks
     total += 2 * tile_m * fo * itemsize        # output block
     total += tile_m * fi * 4                   # accumulator scratch
-    total += 4 * tile_m * tile_e * 4           # one-hot temporaries
+    total += 3 * tile_m * tile_e * 4           # iota, compare, select
+    total += tile_m * tile_e * 2               # bf16 one-hot
     total += tile_m * fi * 4                   # chunk product
-    if itemsize != 4:
+    if itemsize == 4:
+        total += 3 * slab * 2 + slab * 4       # bf16 terms, f32 residual
+        total += 2 * tile_m * fi * 4           # two more chunk products
+    else:
         total += slab * 4                      # f32 image of the slab
     if f_out:
         total += 2 * fi * fo * itemsize        # W, pinned

@@ -16,6 +16,13 @@ restructure the reduction so collisions cannot exist:
     this is the "vectorized atomic": 128x128 row-updates per MXU pass,
     serialization-free by construction.
 
+Precision: the 0/1 one-hot is exact in bf16, so a bf16 slab takes one
+single-pass MXU product.  An f32 slab is split in VMEM into three bf16
+terms that sum to it exactly, and takes three single-pass products into
+the f32 accumulator; ``Precision.HIGHEST`` would emulate the f32 product
+in six passes and split the one-hot as well.  Each product term is exact,
+so the f32 accumulation is the only rounding either way.
+
 Inputs are pre-gathered edge rows (the ``indexSelect`` product).  The gather
 itself is XLA's native dynamic-gather (DMA-based on TPU); what the paper's
 scatter kernel loses to atomics, this kernel recovers with dense MXU math.
@@ -46,23 +53,60 @@ def _dot(a, b, acc_dtype):
                        preferred_element_type=acc_dtype)
 
 
+def _high_bits(x):
+    """``x`` (f32) truncated to its sign, exponent and top 7 mantissa
+    bits: a value bf16 holds exactly."""
+    bits = jax.lax.bitcast_convert_type(x, jnp.uint32)
+    return jax.lax.bitcast_convert_type(bits & jnp.uint32(0xFFFF0000),
+                                        jnp.float32)
+
+
+def _split_bf16x3(x):
+    """Three bf16 terms whose sum is exactly the f32 ``x``.
+
+    Each term takes the top 8 significant bits of what the terms before
+    it left; both residuals are exact in f32, and the last holds at most
+    8 significant bits.  Exact for finite normal ``x`` whose residuals
+    stay normal; a subnormal residual flushes to zero on the TPU.  The
+    split truncates, so it never rounds a finite ``x`` up to inf."""
+    hi = _high_bits(x)
+    rest = x - hi
+    mid = _high_bits(rest)
+    return (hi.astype(jnp.bfloat16), mid.astype(jnp.bfloat16),
+            (rest - mid).astype(jnp.bfloat16))
+
+
 def _reduce_chunk(onehot, rows, acc_dtype):
-    """The segmented reduce of one edge chunk on the MXU.  A bf16 slab
-    takes one exact bf16 pass (the 0/1 one-hot is exact in bf16); an f32
-    slab needs full precision."""
+    """The segmented reduce of one edge chunk on the MXU, from the bf16
+    ``onehot`` (0/1, exact in bf16).
+
+    A bf16 slab takes one single-pass product.  An f32 slab is split into
+    three bf16 terms that sum to it exactly (``_split_bf16x3``) and takes
+    three single-pass products: every product term is exact, and the f32
+    accumulation is the only rounding, as with ``Precision.HIGHEST``
+    (whose f32 emulation takes six passes and splits the one-hot too).
+    Other dtypes multiply at ``HIGHEST`` (``_dot``)."""
     if rows.dtype == jnp.bfloat16:
-        return jax.lax.dot(onehot.astype(jnp.bfloat16), rows,
-                           preferred_element_type=acc_dtype)
+        return jax.lax.dot(onehot, rows, preferred_element_type=acc_dtype)
+    if rows.dtype == jnp.float32:
+        hi, mid, lo = (jax.lax.dot(onehot, term,
+                                   preferred_element_type=acc_dtype)
+                       for term in _split_bf16x3(rows))
+        return hi + (mid + lo)
     return _dot(onehot, rows, acc_dtype)
 
 
 def _onehot(seg_ref, mask_ref, tile_m: int, tile_e: int):
-    """(tile_m, tile_e) one-hot of the chunk's local destination rows;
-    masked (padding) edges contribute nothing."""
+    """(tile_m, tile_e) bf16 one-hot of the chunk's local destination
+    rows.  ``mask`` is validity alone: an edge with mask > 0 puts a 1 in
+    its row, a padding edge (mask 0) a 0, so it adds an exact 0 for any
+    finite row.  Per-edge weights scale the rows before the kernel
+    (``ops.gather_seg_agg``'s ``weight``), never the mask."""
     seg = seg_ref[0]              # (1, tile_e) int32, block-local row ids
     mask = mask_ref[0]            # (1, tile_e) float32
     row_ids = jax.lax.broadcasted_iota(jnp.int32, (tile_m, tile_e), 0)
-    return jnp.where(row_ids == seg, mask, 0.0)
+    return jnp.where((row_ids == seg) & (mask > 0), 1.0,
+                     0.0).astype(jnp.bfloat16)
 
 
 def _seg_agg_kernel(seg_ref, mask_ref, rows_ref, out_ref, acc_ref, *,
@@ -104,7 +148,9 @@ def seg_agg_blocked(rows: jnp.ndarray, seg_local: jnp.ndarray,
                  (``kernels.ops.gather_tail``).
       seg_local: (nblocks, 1, emax) int32 destination row id LOCAL to the
                  block (the ``kernels.ops`` edge layout).
-      mask:      (nblocks, 1, emax) 1/0 edge validity.
+      mask:      (nblocks, 1, emax) edge validity: an edge with mask > 0
+                 adds its row, one with mask 0 adds nothing.  Not a
+                 weight: scale the rows before the kernel.
       tile_m:    output rows per block (static).
       tile_e:    edge chunk per grid step (static; a multiple of 128 that
                  divides emax).

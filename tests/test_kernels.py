@@ -1,5 +1,6 @@
 """Pallas kernels vs pure-jnp oracles: shape/dtype sweeps (interpret mode)."""
 
+import functools
 import itertools
 
 import jax
@@ -105,6 +106,138 @@ def test_seg_agg_mass_conservation():
     lhs = np.asarray(out).sum(0)
     rhs = np.asarray(rows * mask[..., None]).sum((0, 1))
     assert_allclose_dtype(lhs, rhs, scale=10)
+
+
+# ---------------------------------- seg_agg f32: three exact bf16 passes
+def _f32_values(sign, lo_exp, hi_exp, n=4096, seed=0):
+    """f32 values of one sign with full 24-bit significands, exponents
+    drawn from [lo_exp, hi_exp]."""
+    rng = np.random.default_rng(seed)
+    sig = rng.integers(1 << 23, 1 << 24, n).astype(np.float64) / (1 << 23)
+    exp = rng.integers(lo_exp, hi_exp + 1, n)
+    return (sign * np.ldexp(sig, exp)).astype(np.float32)
+
+
+@pytest.mark.parametrize("sign", [1.0, -1.0])
+@pytest.mark.parametrize("lo_exp,hi_exp", [
+    (0, 0),           # one binade
+    (-60, 60),        # exponents far apart
+    (-100, -80),      # small, residuals still normal
+    (100, 127),       # up to the f32 maximum: the split never rounds up
+])
+def test_split_bf16x3_rebuilds_f32_bitwise(sign, lo_exp, hi_exp):
+    """hi + mid + lo is the f32 value, bit for bit, and every term is a
+    bf16.  Subnormal residuals are out of scope (the TPU flushes them)."""
+    x = _f32_values(sign, lo_exp, hi_exp)
+    x[:3] = [0.0, sign * np.finfo(np.float32).max,
+             sign * np.finfo(np.float32).tiny * 2.0 ** 24]
+    terms = jax.jit(seg_mod._split_bf16x3)(jnp.asarray(x))
+    assert all(t.dtype == jnp.bfloat16 for t in terms)
+    hi, mid, lo = (np.asarray(t, np.float64) for t in terms)
+    rebuilt = ((hi + mid) + lo).astype(np.float32)
+    assert np.isfinite(hi).all()
+    np.testing.assert_array_equal(rebuilt.view(np.uint32), x.view(np.uint32))
+
+
+def _segment_sum_f64(rows, seg, mask, tile_m):
+    """Float64 block sums of (nblocks, emax, F) rows: the exact answer
+    the f32 accumulation rounds."""
+    nblocks, _, f = rows.shape
+    out = np.zeros((nblocks, tile_m, f))
+    r, s, m = (np.asarray(a, np.float64) for a in (rows, seg, mask))
+    for b in range(nblocks):
+        np.add.at(out[b], s[b].astype(int), r[b] * m[b][:, None])
+    return out.reshape(nblocks * tile_m, f)
+
+
+@pytest.mark.parametrize("f", [41, 128, 500, 602])
+def test_seg_agg_f32_matches_a_float64_segment_sum(f):
+    """At the cells' widths and the planner's tile, the f32 kernel is the
+    float64 segment sum within the f32 accumulation band."""
+    tile_m, tile_e = 128, ops.kernel_tile_e(128, f, 4)
+    rows, seg, mask = _blocked_inputs(2, 2 * tile_e, f, tile_m, jnp.float32)
+    out = seg_agg_blocked(rows, seg, mask, tile_m=tile_m, tile_e=tile_e)
+    assert_allclose_dtype(out, _segment_sum_f64(rows, seg, mask, tile_m))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_seg_agg_masked_slot_with_a_large_row_adds_exactly_zero(dtype):
+    """A masked slot is excluded by its 0 in the one-hot, not by rounding:
+    its row may hold a huge finite value and adds an exact 0."""
+    rows, seg, mask = _blocked_inputs(2, 256, 64, 32, dtype)
+    masked = np.asarray(mask) == 0
+    assert masked.any()
+    big = jnp.where(jnp.asarray(masked)[..., None],
+                    jnp.asarray(3e38, dtype), rows)
+    zero = jnp.where(jnp.asarray(masked)[..., None],
+                     jnp.asarray(0, dtype), rows)
+    got = seg_agg_blocked(big, seg, mask, tile_m=32, tile_e=128)
+    assert np.isfinite(np.asarray(got, np.float32)).all()
+    np.testing.assert_array_equal(
+        np.asarray(got, np.float32),
+        np.asarray(seg_agg_blocked(zero, seg, mask, tile_m=32, tile_e=128),
+                   np.float32))
+
+
+def test_seg_agg_bf16_keeps_its_one_pass_result():
+    """A bf16 slab takes one bf16 product a chunk, accumulated in f32 and
+    rounded once: bitwise that sum computed outside the kernel."""
+    nblocks, emax, f, tile_m, tile_e = 2, 256, 64, 32, 128
+    rows, seg, mask = _blocked_inputs(nblocks, emax, f, tile_m,
+                                      jnp.bfloat16)
+    out = seg_agg_blocked(rows, seg, mask, tile_m=tile_m, tile_e=tile_e)
+    onehot = jnp.where((jnp.arange(tile_m)[None, :, None] == seg[:, None])
+                       & (mask[:, None] > 0), 1.0, 0.0).astype(jnp.bfloat16)
+    acc = jnp.zeros((nblocks, tile_m, f), jnp.float32)
+    for c in range(0, emax, tile_e):
+        acc = acc + jnp.einsum("bme,bef->bmf", onehot[..., c:c + tile_e],
+                               rows[:, c:c + tile_e],
+                               preferred_element_type=jnp.float32)
+    np.testing.assert_array_equal(
+        np.asarray(out, np.float32),
+        np.asarray(acc.astype(jnp.bfloat16).reshape(-1, f), np.float32))
+
+
+def _kernel_dots(fn, *args):
+    """The ``dot_general`` equations inside the ``pallas_call`` of
+    ``fn(*args)``'s jaxpr."""
+    eqns = list(_eqns(jax.make_jaxpr(fn)(*args).jaxpr))
+    calls = [q for q in eqns if q.primitive.name == "pallas_call"]
+    assert len(calls) == 1
+    return [q for q in _eqns(calls[0].params["jaxpr"])
+            if q.primitive.name == "dot_general"]
+
+
+def _asks_highest(eqn):
+    precision = eqn.params.get("precision")
+    return jax.lax.Precision.HIGHEST in (
+        precision if isinstance(precision, tuple) else (precision,))
+
+
+def _single_pass_bf16(eqn):
+    return (all(v.aval.dtype == jnp.bfloat16 for v in eqn.invars)
+            and eqn.params["preferred_element_type"] == jnp.float32
+            and not _asks_highest(eqn))
+
+
+def test_f32_seg_agg_multiplies_bf16_terms_in_single_passes():
+    """Every MXU product of the f32 kernel takes bf16 operands into an f32
+    accumulator, and none asks for ``Precision.HIGHEST``; the fused
+    kernel's reduce does the same, and its weight product, whose W is not
+    exact in bf16, keeps ``HIGHEST``."""
+    rows, seg, mask = _blocked_inputs(2, 256, 64, 32, jnp.float32)
+    seg3, mask3 = ops.kernel_edges(seg, mask, 128)
+    dots = _kernel_dots(functools.partial(
+        seg_mod.seg_agg_blocked, tile_m=32, tile_e=128), rows, seg3, mask3)
+    assert len(dots) == 3 and all(map(_single_pass_bf16, dots)), \
+        [q.params for q in dots]
+    w = jnp.ones((64, 16), jnp.float32)
+    dots = _kernel_dots(functools.partial(
+        fused_mod.fused_agg_combine_blocked, tile_m=32, tile_e=128),
+        rows, seg3, mask3, w)
+    assert sum(map(_single_pass_bf16, dots)) == 3
+    assert [q.invars[1].aval.shape for q in dots if _asks_highest(q)] \
+        == [(64, 16)]
 
 
 # ------------------------------------------------------- fused agg+combine

@@ -103,6 +103,27 @@ def test_seg_agg_compiles_for_v5e(one_chip, f, dtype):
     assert "tpu_custom_call" in _compile_seg(f, dtype, one_chip).as_text()
 
 
+@pytest.mark.parametrize("f", (41, 128, 500, 602))
+def test_f32_seg_agg_keeps_its_tile_and_compiles_for_v5e(one_chip, f):
+    """At the cells' widths the f32 kernel's working set, bf16 terms of
+    the slab included, leaves the planner's edge chunk at 512 (so the
+    slot layouts stay as they are), and the kernel compiles at that tile
+    under the limit the planner passes, ``tpu_vmem_budget()``."""
+    tile_e = ops.kernel_tile_e(SEG_TILE_M, f, 4)
+    assert tile_e == 512
+    nblocks, emax = 2, 2 * tile_e
+
+    def fn(rows, seg, mask):
+        return seg_agg_blocked(rows, seg, mask, tile_m=SEG_TILE_M,
+                               tile_e=tile_e, interpret=False,
+                               vmem_limit_bytes=ops.tpu_vmem_budget())
+
+    compiled = jax.jit(fn).lower(
+        _shape((nblocks, emax, f), jnp.float32, one_chip),
+        *_edges(nblocks, emax, one_chip)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
 @pytest.mark.parametrize("dtype", DTYPES, ids=("f32", "bf16"))
 @pytest.mark.parametrize("f", WIDTHS)
 def test_fused_agg_combine_compiles_for_v5e(one_chip, f, dtype):
